@@ -53,7 +53,7 @@ import time
 
 import numpy as np
 
-from repro.compat import has_ragged_all_to_all
+from repro.compat import native_ragged
 from repro.core.drm import DRConfig
 from repro.core.streaming import StreamingJob
 from repro.data.generators import drifting_zipf, hotspot_flip, sawtooth_skew, zipf_keys
@@ -163,7 +163,7 @@ def run(batches: int = 6, batch_size: int = 16_384):
             rows.append((f"fig6/migration_rows_fraction/exp={exp}",
                          mig_rows / reparts / full,
                          f"{reparts} repartitions, full-state a2a = 1"))
-    if has_ragged_all_to_all():
+    if native_ragged(job.mesh):
         # with the native collective the wall-clock must follow the rows:
         # ragged no slower than dense across the skewed profiles (aggregated
         # over all exponents; 25% headroom absorbs shared-CI timer noise)
@@ -558,12 +558,15 @@ _TOPOLOGY_SCRIPT = textwrap.dedent(
 
 def _topology(batches: int, batch_size: int):
     """Two-host locality profile: flat dense vs. the hierarchical two-tier
-    transport on 8 real shards (subprocess: the device count must be fixed
-    before jax initializes).  Emits per-class shipped rows + exchange wall
-    per backend and gates on strictly fewer inter-host rows under the
-    hierarchical transport; the decision-flip comparison runs in-process
-    (host-side plan pricing needs no collective)."""
-    env = dict(os.environ, PYTHONPATH="src")
+    transport on 8 virtual CPU shards.  The child process is pinned to the
+    CPU (``JAX_PLATFORMS=cpu``): the device count must be fixed before jax
+    initializes, and a parent that holds an accelerator would keep it from
+    the child — its walls are CPU simulations, not device times.  Emits
+    per-class shipped rows + exchange wall per backend and gates on
+    strictly fewer inter-host rows under the hierarchical transport; the
+    decision-flip comparison runs in-process (host-side plan pricing needs
+    no collective)."""
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-c", _TOPOLOGY_SCRIPT, str(batches), str(batch_size)],
@@ -589,9 +592,10 @@ def _topology(batches: int, batch_size: int):
                      f"rows crossing the host boundary over {batches} batches "
                      f"(fraction {r['inter_host_fraction']:.3f})",
                      be, tuple(r["by_class"])))
-        rows.append((f"fig6/topology_exchange_step_wall_ms/{be}",
+        rows.append((f"fig6/cpu_sim/topology_exchange_step_wall_ms/{be}",
                      r["step_wall_ms"],
-                     "mean exchange-path wall per batch (two-host profile)",
+                     "mean exchange-path wall per batch (two-host profile, "
+                     "8 virtual CPU devices: not a device time)",
                      be, tuple(r["by_class"])))
     # the CI gate: the two-tier exchange concentrates cross-host traffic
     # into the counted inter hop — strictly fewer inter-host rows than the
@@ -715,12 +719,13 @@ def _failure(batches: int, batch_size: int):
     """Kill-a-worker scenario (Fig 6 failure domain): 8 real shards, hard
     loss of lane 5 mid-stream, zero-loss recovery through the safe-point
     protocol — restore the auto-snapshot, replay the gap, resume on the
-    shrunk 7-worker topology.  Subprocess: the device count must be fixed
-    before jax initializes.  Emits the recovery wall and the row-loss
+    shrunk 7-worker topology.  The child process is pinned to the CPU
+    (``JAX_PLATFORMS=cpu``) with 8 virtual devices, as in :func:`_topology`:
+    its recovery wall is a CPU simulation, not a device time.  Emits the recovery wall and the row-loss
     count; the CI smoke gate greps for ``fig6/rows_lost`` being exactly
     zero."""
     n = max(batches, 6)  # the kill tick needs stream to outlive it
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-c", _FAILURE_SCRIPT, str(n), str(batch_size)],
@@ -741,9 +746,10 @@ def _failure(batches: int, batch_size: int):
         ("fig6/rows_lost", out["rows_lost"],
          f"rows lost across a hard loss of lane {out['lane']} "
          f"(protocol contract: exactly 0)"),
-        ("fig6/recovery_wall_ms", out["recovery_wall_ms"],
+        ("fig6/cpu_sim/recovery_wall_ms", out["recovery_wall_ms"],
          f"restore + replay of {out['replayed']} gap batch(es) + retry "
-         f"onto {out['workers_after']} surviving workers"),
+         f"onto {out['workers_after']} surviving workers "
+         f"(8 virtual CPU devices: not a device time)"),
     ]
 
 

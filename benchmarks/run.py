@@ -52,6 +52,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="also write the CSV rows to this file")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     lines: list[str] = []
 
     def emit(line: str) -> None:

@@ -1,33 +1,22 @@
-"""Version-tolerance shims for jax APIs that moved between releases.
+"""Runtime seams shared by the streaming driver and the exchange plane.
 
-Every module that needs ``shard_map`` imports it from here instead of from
-jax directly, so the repo tracks exactly one spelling of each API:
-
-* ``shard_map``  — ``jax.shard_map`` (new) vs ``jax.experimental.shard_map``
-  (<= 0.4.x), absorbing the ``check_rep`` -> ``check_vma`` rename and the
-  ``auto`` -> ``axis_names`` inversion (old jax names the *auto* axes, new
-  jax names the *manual* ones).
-* ``set_mesh``   — ``jax.set_mesh`` (new) vs entering the ``Mesh`` context
-  manager (old); both forms support ``with set_mesh(mesh): ...``.
-* ``ragged_all_to_all`` — ``jax.lax.ragged_all_to_all`` (>= 0.5), the real
-  ragged collective: each shard sends ``send_sizes[i]`` rows to shard ``i``
-  instead of the full capacity pad.  On jax 0.4.x the fallback rides the
-  dense tiled all-to-all with the receive buffer masked to ``recv_sizes`` —
-  bit-identical output, dense wall-clock.  The fallback supports the
-  *lane-major regular layout only* (``input_offsets[i] == i * capacity``,
+* ``ragged_all_to_all`` — the ragged row phase's collective, chosen by the
+  platform of the mesh it runs on: ``jax.lax.ragged_all_to_all`` on TPU
+  (each shard sends ``send_sizes[i]`` rows to shard ``i`` instead of the
+  full capacity pad), and a masked-dense equivalent elsewhere — XLA:CPU
+  does not implement the ragged op.  The masked-dense form rides the dense
+  tiled all-to-all with the receive buffer masked to ``recv_sizes``:
+  bit-identical output, dense traffic.  It supports the *lane-major regular
+  layout only* (``input_offsets[i] == i * capacity``,
   ``output_offsets[i] == axis_index * capacity``), which is the one layout
   the exchange plane uses: ``bucketize`` packs each lane's rows
   contiguously from slot 0, so lane ``i``'s live rows start at row
   ``i * capacity`` of the flattened send buffer.
 
-Call sites use the modern spellings (``check_vma=``, ``axis_names=``); the
-shim rewrites them for whatever jax is installed.
-
-Runtime escape hatches (environment variables) also live here, next to the
-version shims they mirror:
+Runtime escape hatches (environment variables):
 
 * ``REPRO_DISABLE_NATIVE_RAGGED=1`` — force the masked-dense ragged
-  fallback even on jax >= 0.5 (see :func:`has_ragged_all_to_all`).
+  transport even on TPU (see :func:`native_ragged`).
 * ``REPRO_DISABLE_OVERLAP=1`` — force the streaming driver's serial
   exchange path even when ``DRConfig.overlap_exchange`` is on (see
   :func:`overlap_enabled`): batch N+1's route/count phase no longer issues
@@ -45,27 +34,15 @@ transfers between safe points" contract.
 from __future__ import annotations
 
 import contextlib
-import inspect
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = frozenset(inspect.signature(_shard_map).parameters)
-
-_NATIVE_RAGGED = hasattr(jax.lax, "ragged_all_to_all")
-
 __all__ = [
-    "shard_map",
-    "set_mesh",
     "ragged_all_to_all",
-    "has_ragged_all_to_all",
+    "native_ragged",
     "overlap_enabled",
     "host_fetch",
     "host_sync_count",
@@ -131,16 +108,20 @@ def overlap_enabled() -> bool:
     return disabled.lower() in ("", "0", "false")
 
 
-def has_ragged_all_to_all() -> bool:
-    """True when the installed jax provides the native ragged collective.
+def native_ragged(mesh=None) -> bool:
+    """True when the ragged row phase runs the native collective on
+    ``mesh`` (default: the mesh being traced, inside a ``shard_map``).
 
-    ``REPRO_DISABLE_NATIVE_RAGGED=1`` forces the masked-dense fallback even
-    on jax >= 0.5 — the escape hatch benches use to measure the fallback,
-    and tests use to compare the two paths bit-for-bit on one build.
-    (``0``/``false``/unset leave the native path on.)
+    Native on TPU meshes, masked-dense everywhere else.
+    ``REPRO_DISABLE_NATIVE_RAGGED=1`` forces masked-dense on TPU too — the
+    lever benches use to measure it, and tests use to compare the two
+    transports bit-for-bit.  (``0``/``false``/unset leave native on.)
     """
+    abstract = jax.sharding.get_abstract_mesh() if mesh is None else mesh.abstract_mesh
+    device = abstract.abstract_device
+    on_tpu = device is not None and device.device_kind.lower().startswith("tpu")
     disabled = os.environ.get("REPRO_DISABLE_NATIVE_RAGGED", "")
-    return _NATIVE_RAGGED and disabled.lower() in ("", "0", "false")
+    return on_tpu and disabled.lower() in ("", "0", "false")
 
 
 def ragged_all_to_all(
@@ -153,25 +134,25 @@ def ragged_all_to_all(
     *,
     axis_name: str,
 ):
-    """``jax.lax.ragged_all_to_all`` with a jax 0.4.x fallback.
+    """The ragged all-to-all, native on TPU and masked-dense elsewhere.
 
-    Native (jax >= 0.5): shard ``j`` receives ``send_sizes[j]`` rows read
-    from ``operand[input_offsets[j]:]`` and writes them at
-    ``output_offsets[j]`` of *its* ``output``; regions of ``output`` that
-    receive nothing keep their initial values.  Only the measured rows cross
-    the interconnect — the wall-clock follows the row counts.
+    Native: shard ``j`` receives ``send_sizes[j]`` rows read from
+    ``operand[input_offsets[j]:]`` and writes them at ``output_offsets[j]``
+    of *its* ``output``; regions of ``output`` that receive nothing keep
+    their initial values.  Only the measured rows cross the interconnect —
+    the wall-clock follows the row counts.
 
-    Fallback (jax 0.4.x): the dense tiled all-to-all ships the whole padded
-    buffer and the receive side is masked to ``recv_sizes``, with unfilled
-    rows taken from ``output`` — bit-identical results, padded traffic.
+    Masked-dense: the dense tiled all-to-all ships the whole padded buffer
+    and the receive side is masked to ``recv_sizes``, with unfilled rows
+    taken from ``output`` — bit-identical results, padded traffic.
     Requires the lane-major regular layout (see module doc); offsets are
     trusted, not checked, because they are static under that layout.  For
     buffers whose pad rows already equal ``output``'s values (the exchange
     plane's bucketize-packed buffers) the mask selects identical bits — the
-    cost of keeping one uniform shim contract is one fused select XLA folds
-    into the all-to-all's consumer.
+    cost of keeping one uniform contract is one fused select XLA folds into
+    the all-to-all's consumer.
     """
-    if has_ragged_all_to_all():
+    if native_ragged():
         return jax.lax.ragged_all_to_all(
             operand, output, input_offsets, send_sizes, output_offsets,
             recv_sizes, axis_name=axis_name,
@@ -183,41 +164,3 @@ def ragged_all_to_all(
     live = jnp.arange(capacity, dtype=jnp.int32)[None, :] < recv_sizes[:, None]
     live = live.reshape((num_lanes * capacity,) + (1,) * (operand.ndim - 1))
     return jnp.where(live, recvd.reshape(operand.shape), output)
-
-
-def shard_map(
-    f,
-    *,
-    mesh,
-    in_specs,
-    out_specs,
-    check_vma: bool | None = None,
-    check_rep: bool | None = None,
-    axis_names=None,
-    auto=None,
-):
-    """``shard_map`` with one signature across jax versions."""
-    check = check_vma if check_vma is not None else check_rep
-    kwargs = {}
-    if "check_vma" in _PARAMS:  # new-style jax
-        if check is not None:
-            kwargs["check_vma"] = check
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        elif auto is not None:
-            kwargs["axis_names"] = set(mesh.axis_names) - set(auto)
-    else:  # old-style: check_rep + auto (complement of the manual axes)
-        if check is not None:
-            kwargs["check_rep"] = check
-        if auto is not None:
-            kwargs["auto"] = frozenset(auto)
-        elif axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # older jax: Mesh is itself a context manager

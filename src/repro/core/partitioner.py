@@ -350,17 +350,17 @@ def resize_partitioner(
 
 def heavy_capacity_for(lam: float, num_partitions: int, *, floor: int = 0) -> int:
     """Heavy-table width for tracking ``lam`` keys per partition, rounded up
-    to the route kernels' tile width (``KEY_LANES``).
+    to the route kernels' tile width (``LANES``).
 
     The one shared rounding rule for every sizing site (streaming driver,
     serve scheduler, elastic replan, repartition policy) — previously each
     hand-inlined ``ceil(.../128)*128``.  ``floor`` lower-bounds the result
     before rounding (e.g. the current table width, to keep jit signatures
     stable)."""
-    from repro.kernels.partition_apply import KEY_LANES
+    from repro.kernels.partition_apply import LANES
 
     want = max(int(np.ceil(lam * num_partitions)), int(floor), 1)
-    return int(-(-want // KEY_LANES) * KEY_LANES)
+    return int(-(-want // LANES) * LANES)
 
 
 def split_replica_rows(

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.hashing import KEY_SENTINEL
 from repro.core.histogram import local_topk_histogram
 from repro.core.partitioner import PartitionerTables

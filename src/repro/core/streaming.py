@@ -98,9 +98,10 @@ from typing import Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import host_fetch, overlap_enabled, safe_point
+from repro.compat import host_fetch, native_ragged, overlap_enabled, safe_point
 from repro.control import (
     Evict,
     NoOp,
@@ -134,10 +135,13 @@ from repro.exchange import (
     ExchangeStats,
     ExchangeTopology,
     FaultyBackend,
+    TransientExchangeError,
     WorkerLostError,
     resolve_backend,
 )
+from repro.exchange.plane import route_path
 from repro.exchange.spec import DISTANCE_CLASSES
+from repro.launch.mesh import make_mesh
 
 __all__ = ["StreamingJob", "BatchMetrics", "RecoveryStats"]
 
@@ -176,6 +180,12 @@ class BatchMetrics:
                                 # inter-host, per worker); zeros on flat jobs
     lanes: int = 0              # live workers after this batch (a health
                                 # action or a loss shrinks this mid-stream)
+    transport: str = ""         # collective the shuffle rode: the backend,
+                                # and for ragged which row phase ran
+                                # ("ragged/native" on TPU meshes,
+                                # "ragged/masked-dense" elsewhere)
+    route_path: str = ""        # route -> bucketize implementation
+                                # (exchange.plane.route_path)
 
 
 @dataclasses.dataclass
@@ -194,8 +204,22 @@ class RecoveryStats:
 
 
 def _default_mesh(axis: str = "data") -> Mesh:
-    n = len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return make_mesh((len(jax.devices()),), (axis,))
+
+
+def _make_merge(mesh: Mesh):
+    """Jitted stateful reduce over the mesh: each worker folds the rows it
+    received into its own ``[S]`` state table, on its own device — the
+    stacked ``[W, ...]`` state and the received rows are both sharded over
+    ``data``, so nothing leaves its chip."""
+
+    def local(sk, sv, bk, bv, bva):
+        k, v, _ = merge_into(sk[0], sv[0], bk[0], bv[0], bva[0])
+        return k[None], v[None]
+
+    spec = P("data")
+    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec,) * 5,
+                             out_specs=(spec, spec)))
 
 
 class StreamingJob:
@@ -255,10 +279,11 @@ class StreamingJob:
         self._shuffle_spec: ExchangeSpec | None = None  # for exchange-row accounting
         self._migrate_steps: dict[int, object] = {}  # lane capacity -> jitted step
         self._pending_resize: int | None = None
-        # per-worker keyed state, stacked [W, S] / [W, S, D]
+        # per-worker keyed state, stacked [W, S] / [W, S, D] and sharded
+        # over ``data``: one worker's table per device
         sk, sv = empty_state(state_capacity, payload_dim)
-        self._sk = jnp.tile(sk[None], (self.num_workers, 1))
-        self._sv = jnp.tile(sv[None], (self.num_workers, 1, 1))
+        self._sk = self._shard(jnp.tile(sk[None], (self.num_workers, 1)))
+        self._sv = self._shard(jnp.tile(sv[None], (self.num_workers, 1, 1)))
         # split-phase overlap: the previous batch's in-flight finish+merge
         # (a callable that enqueues it), the host wall start of the section
         # a pending ship is hiding behind, and the state-row count as of the
@@ -287,7 +312,12 @@ class StreamingJob:
         self._replay: list[tuple[np.ndarray, np.ndarray | None]] = []
         self.recoveries: list[RecoveryStats] = []
         self.metrics: list[BatchMetrics] = []
-        self._merge = jax.jit(jax.vmap(lambda sk, sv, bk, bv, bva: merge_into(sk, sv, bk, bv, bva)))
+        self._merge = _make_merge(self.mesh)
+
+    def _shard(self, x) -> jax.Array:
+        """Place ``x`` split over the workers along its first axis (stacked
+        ``[W, ...]`` state, or a batch's ``[W * n]`` records)."""
+        return jax.device_put(x, NamedSharding(self.mesh, P("data")))
 
     # -- keyed state access (drains any in-flight exchange first) ----------
     @property
@@ -363,8 +393,8 @@ class StreamingJob:
         v = np.ones((len(k), self.payload_dim), np.float32)
         shuffle = self._shuffle
         pending, res = shuffle.start(
-            self.drm.partitioner.tables(), jnp.asarray(k),
-            jnp.asarray(v, jnp.float32), jnp.asarray(k != KEY_SENTINEL),
+            self.drm.partitioner.tables(), self._shard(k), self._shard(v),
+            self._shard(k != KEY_SENTINEL),
             self._part_loads,
         )
         self._staged = (raw, self.drm.partitioner, shuffle, pending, res)
@@ -391,9 +421,7 @@ class StreamingJob:
             hidden_wall_s=hidden,
         ))
         with safe_point():  # a drain IS a safe point: the fetch is sanctioned
-            self._last_state_rows = int(host_fetch(
-                jax.vmap(lambda k: jnp.sum(k != KEY_SENTINEL))(self._sk)
-            ).sum())
+            self._last_state_rows = int(host_fetch(jnp.sum(self._sk != KEY_SENTINEL)))
 
     # ------------------------------------------------------------------
     def _build(self, local_n: int):
@@ -405,6 +433,7 @@ class StreamingJob:
         if self._shuffle is not None and sig == self._shuffle_sig:
             return
         self._shuffle_sig = sig
+        self._route_path = route_path(self.num_workers, cap, self.payload_dim)
         self._shuffle_spec = ExchangeSpec(
             num_lanes=self.num_workers, capacity=cap, axis="data",
             topology=self.exchange_topology,
@@ -522,7 +551,8 @@ class StreamingJob:
         if values is None:
             values = np.ones((len(keys), self.payload_dim), np.float32)
         else:
-            values = np.concatenate([values, np.zeros((pad,) + values.shape[1:], np.float32)])
+            values = np.concatenate([values, np.zeros((pad,) + values.shape[1:], np.float32)],
+                                    dtype=np.float32)
         valid = keys != KEY_SENTINEL
         self._build(local_n * w)
         batch_backend = self.exchange_backend.name  # the transport this batch rode
@@ -544,15 +574,15 @@ class StreamingJob:
                 pipelined = True
             else:
                 pending, res = shuffle.start(
-                    self.drm.partitioner.tables(), jnp.asarray(keys),
-                    jnp.asarray(values, jnp.float32), jnp.asarray(valid),
+                    self.drm.partitioner.tables(), self._shard(keys),
+                    self._shard(values), self._shard(valid),
                     self._part_loads,
                 )
             self._consume_inflight()
 
             def _fin_shuffle(fin=shuffle.finish, pending=pending):
                 rk, rv, rva, _rp = fin(pending)
-                self._sk, self._sv, _ = self._merge(self._sk, self._sv, rk, rv, rva)
+                self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
 
             self._inflight = _fin_shuffle
             with safe_point():
@@ -564,12 +594,12 @@ class StreamingJob:
             if self._inflight is not None:
                 self._drain_inflight()
             res = self._shuffle(
-                self.drm.partitioner.tables(), jnp.asarray(keys),
-                jnp.asarray(values, jnp.float32), jnp.asarray(valid),
+                self.drm.partitioner.tables(), self._shard(keys),
+                self._shard(values), self._shard(valid),
                 self._part_loads,
             )
             # stateful reduce: fold received records into per-worker state
-            self._sk, self._sv, _ = self._merge(
+            self._sk, self._sv = self._merge(
                 self._sk, self._sv, res.keys, res.values, res.valid
             )
             with safe_point():
@@ -752,6 +782,10 @@ class StreamingJob:
             split_keys=len(self.drm.split_keys),
             shipped_rows_by_class=tuple(int(x) for x in by_class),
             lanes=self.num_workers,
+            transport=(batch_backend if batch_backend != "ragged" else
+                       "ragged/native" if native_ragged(self.mesh)
+                       else "ragged/masked-dense"),
+            route_path=self._route_path,
         )
         # the host wall since the count sync ran under this batch's (or the
         # migration's) in-flight ship — that's the latency the overlap hid.
@@ -769,9 +803,7 @@ class StreamingJob:
         """Live keyed-state rows across all workers (the migration scale).
         Drains any in-flight exchange (via the ``state_keys`` property)."""
         with safe_point():
-            self._last_state_rows = int(host_fetch(
-                jax.vmap(lambda k: jnp.sum(k != KEY_SENTINEL))(self.state_keys)
-            ).sum())
+            self._last_state_rows = int(host_fetch(jnp.sum(self.state_keys != KEY_SENTINEL)))
         return self._last_state_rows
 
     # -- elastic resize -------------------------------------------------
@@ -818,6 +850,7 @@ class StreamingJob:
         new worker count through the modulo placement."""
         self.mesh = Mesh(np.asarray(devices), ("data",))
         self.num_workers = len(devices)
+        self._merge = _make_merge(self.mesh)
         self._shuffle = None
         self._shuffle_sig = None
         self._migrate_steps.clear()
@@ -890,8 +923,8 @@ class StreamingJob:
                 rows = rows[:cap]
             new_k[worker, : len(rows)] = uniq[rows]
             new_v[worker, : len(rows)] = acc[rows]
-        self._sk = jnp.asarray(new_k)
-        self._sv = jnp.asarray(new_v)
+        self._sk = self._shard(new_k)
+        self._sv = self._shard(new_v)
         self._last_state_rows = int((new_k != KEY_SENTINEL).sum())
         if overflow:
             self.telemetry.record_overflow(migration=overflow)
@@ -905,7 +938,10 @@ class StreamingJob:
         caller replays the gap and retries the lost batch."""
         try:
             self._drain_inflight()  # quiesce survivors (state is discarded
-        except Exception:           # below, but the device queue must empty)
+            #                         below, but the device queue must empty)
+        except (WorkerLostError, TransientExchangeError):
+            # only the fault seam's own errors mean "that stage is gone";
+            # a compile or runtime error of the device work propagates
             self._inflight = None
             self._hidden_since = None
         self._discard_staged()
@@ -1003,7 +1039,7 @@ class StreamingJob:
 
             def _fin_migrate(fin=migrate.finish, pending=pending):
                 rk, rv, rva = fin(pending)
-                self._sk, self._sv, _ = self._merge(self._sk, self._sv, rk, rv, rva)
+                self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
 
             self._inflight = _fin_migrate
         else:
@@ -1011,7 +1047,7 @@ class StreamingJob:
             (kk, vv, kv_valid, rk, rv, rva, moved, total,
              mig_ov, mig_lane_ov, mig_shipped, mig_by) = out
             kept_keys = jnp.where(kv_valid, kk, KEY_SENTINEL)
-            self._sk, self._sv, _ = self._merge(kept_keys, vv, rk, rv, rva)
+            self._sk, self._sv = self._merge(kept_keys, vv, rk, rv, rva)
         # every control output below left the migrate start phase; fetching
         # them at this safe point blocks on work already forced (the ship
         # itself stays in flight on the overlap path)
@@ -1083,8 +1119,8 @@ class StreamingJob:
             # layout instead of adopting the stale stacking
             self._adopt_state(snap_keys, np.asarray(snap["state_vals"]))
         else:
-            self.state_keys = jnp.asarray(snap_keys)
-            self.state_vals = jnp.asarray(snap["state_vals"])
+            self.state_keys = self._shard(snap_keys)
+            self.state_vals = self._shard(np.asarray(snap["state_vals"]))
         if "exchange_backend" in drm_snap:
             # the snapshot's *active* transport wins: a BackendPolicy switch
             # taken before the snapshot survives the restore, whatever

@@ -22,12 +22,12 @@ ship:
   row-compacted lanes sized by the measured occupancy, so traffic tracks
   real rows instead of padding (Partial Key Grouping's bounded per-worker
   load, AutoFlow's load-adapted routing).  The row phase rides
-  :func:`repro.compat.ragged_all_to_all`: on jax >= 0.5 that is the native
-  ragged collective — only the measured rows cross the interconnect, so the
-  wall-clock follows the row counts — and on jax 0.4.x the bit-identical
-  fallback that ships the dense pad with the receive buffer masked to the
-  exchanged counts (``shipped_rows`` reports the ragged traffic either
-  way).  The same counts make the *return* trip ragged for free: a
+  :func:`repro.compat.ragged_all_to_all`: on a TPU mesh that is the
+  native ragged collective — only the measured rows cross the
+  interconnect, so the wall-clock follows the row counts — and elsewhere
+  the bit-identical masked-dense form that ships the dense pad with the
+  receive buffer masked to the exchanged counts (``shipped_rows`` reports
+  the ragged traffic either way).  The same counts make the *return* trip ragged for free: a
   ``backhaul`` handed the forward hop's counts ships compacted response
   rows with no second count phase.
 * :class:`LocalBackend` — the ``axis=None`` single-host fast path: pure
@@ -275,7 +275,7 @@ def _ragged_ship(
 ) -> tuple[jax.Array, ...]:
     """Move lane-major ``[L, capacity, ...]`` buffers as compacted rows
     through :func:`repro.compat.ragged_all_to_all` (native collective on
-    jax >= 0.5, masked dense fallback on 0.4.x).
+    TPU meshes, masked dense elsewhere).
 
     ``bucketize`` packs each lane's rows contiguously from slot 0, so the
     flattened buffer is already in the shim's lane-major regular layout:
@@ -373,8 +373,8 @@ class RaggedBackend:
     def _ship(self, spec: ExchangeSpec, buffers: ExchangeResult,
               recv_counts: jax.Array) -> ExchangeResult:
         """Phase 2: move the rows through :func:`repro.compat
-        .ragged_all_to_all` — native on jax >= 0.5 (only the counted rows
-        cross the interconnect), the masked dense collective on 0.4.x.
+        .ragged_all_to_all` — native on TPU meshes (only the counted rows
+        cross the interconnect), the masked dense collective elsewhere.
         ``bucketize`` packs each lane's rows contiguously from slot 0, so
         the flattened ``[L * capacity]`` buffer is already in the shim's
         lane-major regular layout: send offsets are ``lane * capacity``,
@@ -515,7 +515,7 @@ class HierarchicalBackend:
     the flat backends exactly; only the *measured traffic* differs —
     ``shipped_rows_by_class`` prices the intra tier dense (the hop-1 pad)
     and the inter tier by real row counts, the same semantic-traffic
-    convention the ragged fallback uses on jax 0.4.x.
+    convention the masked-dense ragged transport uses.
 
     Without a usable topology (no topology on the spec, lanes not divisible
     by ``lanes_per_host``, a single host, or a mesh whose axis size differs

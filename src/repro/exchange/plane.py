@@ -26,18 +26,23 @@ exchange and overlap the row ship with the next batch's routing and with
 host-side policy decisions (see ``repro.core.streaming``).
 
 All functions are pure jnp and run inside ``jit`` / ``shard_map``.  The
-routing hot path has a fused Pallas kernel
-(``repro.kernels.lookup_dispatch``, extended through bucketize by
-``repro.kernels.route_bucketize``) with a bit-identical jnp twin; the twin
-is the default off-TPU.
+routing hot path has Pallas kernels with bit-identical jnp twins; the twin
+is the default off-TPU.  On a TPU, :func:`route_path` picks the kernel by a
+static size rule: the fused route -> bucketize kernel
+(``repro.kernels.route_bucketize``) keeps the ``[L, capacity]`` send
+buffers resident in VMEM, so it runs only while they fit
+(``route_bucketize.fits``: L <= 16, capacity <= 2048, payload width <= 8);
+larger exchanges — every streaming batch at production size — run the
+two-pass path, the ``lookup_dispatch`` kernel followed by the plane's
+scatter.
 """
 from __future__ import annotations
 
 from typing import NamedTuple, Sequence
 
 import jax
-
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.hashing import KEY_SENTINEL
 from repro.core.partitioner import PartitionerTables
@@ -65,6 +70,7 @@ __all__ = [
     "make_exchange",
     "route_dispatch",
     "route_bucketize",
+    "route_path",
     "take_from",
 ]
 
@@ -86,6 +92,27 @@ class PendingExchange(NamedTuple):
         """Telemetry record from the control phase (all control-plane fields
         are final at ``start``; see :meth:`ExchangeResult.stats`)."""
         return self.buffers.stats(spec, **kw)
+
+
+def route_path(num_lanes: int, capacity: int, payload_dim: int, *,
+               use_pallas: bool | None = None, least_load: bool = False) -> str:
+    """Which route -> bucketize implementation :func:`route_bucketize` runs
+    for an exchange of this static shape: ``"fused kernel"``,
+    ``"two-pass kernel"`` or ``"jnp twin"``.
+
+    The Pallas kernels run on TPU (``use_pallas=None``) unless the
+    least-load replica pick is on, which only the jnp twin implements.
+    Among the kernels the choice is the static size rule
+    ``route_bucketize.fits``: the fused kernel while its resident send
+    buffers fit VMEM, else the ``lookup_dispatch`` kernel plus the jnp
+    scatter."""
+    from repro.kernels.route_bucketize import fits
+
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu" and not least_load
+    if not use_pallas:
+        return "jnp twin"
+    return "fused kernel" if fits(num_lanes, capacity, payload_dim) else "two-pass kernel"
 
 
 def route_dispatch(
@@ -169,9 +196,10 @@ def route_bucketize(
     collective.  On TPU the whole key -> partition -> lane -> slot ->
     send-buffer chain runs in one Pallas kernel
     (``repro.kernels.route_bucketize``) so the routed block never leaves
-    VMEM between the route and the scatter; elsewhere it is
-    :func:`route_dispatch` + ``bucketize`` — bit-identical by the kernel's
-    ref-twin contract.
+    VMEM between the route and the scatter — while the send buffers fit,
+    see :func:`route_path`; otherwise it is :func:`route_dispatch` (the
+    ``lookup_dispatch`` kernel on TPU, the jnp twin elsewhere) +
+    ``bucketize`` — bit-identical by the kernels' ref-twin contract.
 
     ``buffers`` is the double-buffer reuse seam (see
     :meth:`Exchange.bucketize`): a recycled ``(valid_buf, payload_bufs)``
@@ -182,13 +210,14 @@ def route_bucketize(
     :func:`route_dispatch`).
     """
     spec = exchange.spec
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" and part_loads is None
-    if use_pallas:
+    path = route_path(spec.num_lanes, spec.capacity, int(np.prod(vals.shape[1:])),
+                      use_pallas=use_pallas, least_load=part_loads is not None)
+    if path != "jnp twin":
         assert part_loads is None, (
             "least-load replica pick requires the jnp route path "
             "(use_pallas=False)"
         )
+    if path == "fused kernel":
         from repro.kernels import ops
 
         part, slot, counts, buf_valid, bk, bv, bp = ops.route_bucketize(
@@ -215,7 +244,7 @@ def route_bucketize(
         part, slot, counts = route_dispatch(
             tables, keys, valid, num_hosts=num_hosts, seed=seed,
             num_lanes=spec.num_lanes, num_partitions=num_partitions,
-            use_pallas=False, part_loads=part_loads,
+            use_pallas=path == "two-pass kernel", part_loads=part_loads,
         )
         dest = jnp.where(valid, part, 0)
         buffers = exchange.bucketize(

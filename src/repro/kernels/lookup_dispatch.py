@@ -1,12 +1,12 @@
 """Pallas TPU kernel: fused partition lookup + send-slot assignment.
 
-The exchange plane's hot path runs two kernels back to back on the same
+The exchange plane's hot path runs two stages back to back on the same
 records: ``partition_apply`` (key -> partition) and ``dispatch_count``
-(destination -> stable send slot).  Fusing them keeps the one-hot
-destination matrix in VMEM between the two stages — the [blk, L] one-hot
-built for the slot ranking is derived directly from the partition ids the
-lookup just produced, so the records make one trip through VMEM instead of
-two round trips to HBM.
+(destination -> stable send slot).  Fusing them keeps each row of records
+in VMEM between the two stages — the ``[L, 128]`` lane one-hot for the slot
+ranking is built directly from the partition ids the lookup just produced,
+so the records make one trip through VMEM instead of two round trips to
+HBM.
 
 Per record ``i`` with key ``k``::
 
@@ -16,13 +16,12 @@ Per record ``i`` with key ``k``::
     slot[i] = #{ j < i : lane[j] == lane[i], valid[j] }  (stable rank)
     counts[l] = total valid records on lane l
 
-The rank uses the strictly-lower-triangular matmul trick (MXU) with the
-running per-lane counts carried across the sequential grid in a VMEM
-accumulator, exactly as in ``dispatch_count``.
+Both stages are the shared row helpers (``partition_apply.route_row``,
+``dispatch_count.rank_row``) over the ``(8, 128)`` record tile.
 
-VMEM budget per grid step (block = 256, H = 4096, B <= 1024, L <= 1024):
-  host one-hot 256*4096*4B = 4.0 MiB; heavy one-hot 256*1024*4B = 1.0 MiB;
-  tri 256^2*4B = 0.25 MiB; lane one-hot 256*1024*4B = 1.0 MiB  => ~6.3 MiB.
+VMEM budget per grid step (H = 4096, B <= 1024, L <= 1024): the route
+stage's ~8 MiB (see ``partition_apply``) plus the rank stage's ~1.5 MiB
+=> ~9.5 MiB < the 16 MiB scoped VMEM default.
 """
 from __future__ import annotations
 
@@ -32,101 +31,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-LANES = 128
-ROWS = 2  # 256 records per grid step
-BLK = LANES * ROWS
+from repro.kernels.dispatch_count import padded_parts, rank_row
+from repro.kernels.partition_apply import (
+    BLK,
+    ROWS,
+    column_spec,
+    heavy_columns,
+    lane_iota,
+    route_row,
+    row_spec,
+    table_column,
+    tile_records,
+)
 
 
-def _fmix32(x):
-    x = x.astype(jnp.uint32)
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> jnp.uint32(13))
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> jnp.uint32(16))
-    return x
-
-
-def _kernel(
-    keys_ref, valid_ref, heavy_keys_ref, heavy_parts_ref, host_ref,
-    *rest, seed: int, num_hosts: int, num_lanes: int, num_partitions: int = 0,
-):
-    # with splitting active (num_partitions > 0) the heavy-replica table
+def _kernel(keys_ref, valid_ref, hk_ref, hp_ref, host_ref, *rest, seed: int,
+            num_hosts: int, num_lanes: int, num_partitions: int = 0):
+    # with splitting active (num_partitions > 0) the heavy-replica column
     # rides along as a sixth input, ahead of the output refs
-    if num_partitions > 0:
-        heavy_repl_ref, part_ref, slot_ref, counts_ref = rest
-    else:
-        part_ref, slot_ref, counts_ref = rest
-    keys = keys_ref[...].reshape(BLK)
-    valid = valid_ref[...].reshape(BLK).astype(jnp.float32)
+    hr_ref = rest[0] if num_partitions > 0 else None
+    part_ref, slot_ref, counts_ref = rest[-3:]
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    # ---- stage 1: key -> partition (one-hot matmul lookup) ----
-    mixed = _fmix32(keys.astype(jnp.uint32) ^ jnp.uint32((seed * 0x9E3779B9) & 0xFFFFFFFF))
-    host = (mixed & jnp.uint32(num_hosts - 1)).astype(jnp.int32)
-    host_iota = jax.lax.broadcasted_iota(jnp.int32, (BLK, num_hosts), 1)
-    onehot_host = (host[:, None] == host_iota).astype(jnp.float32)
-    table = host_ref[...].reshape(num_hosts).astype(jnp.float32)
-    part_tail = jax.lax.dot_general(
-        onehot_host, table[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-
-    hk = heavy_keys_ref[...].reshape(-1)
-    hp = heavy_parts_ref[...].reshape(-1).astype(jnp.float32)
-    eq = (keys[:, None] == hk[None, :]).astype(jnp.float32)
-    hit = jnp.sum(eq, axis=1) > 0.0
-    part_heavy = jax.lax.dot_general(
-        eq, hp[:, None], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )[:, 0]
-    if num_partitions > 0:
-        # ---- split-key replica pick (fused next to the heavy lookup) ----
-        # replicas per record via the same eq matmul (exactly one live match
-        # per key; sentinel records sum pad rows' 0 -> clamp to 1 -> offset 0)
-        hr = heavy_repl_ref[...].reshape(-1).astype(jnp.float32)
-        d = jax.lax.dot_general(
-            eq, hr[:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:, 0]
-        d = jnp.maximum(d.astype(jnp.int32), 1)
-        # the record's shard-local index, from two 2-D iotas (row-major over
-        # the [ROWS, LANES] block layout, matching the keys reshape)
-        gi = pl.program_id(0) * BLK + (
-            jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 0) * LANES
-            + jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1)
-        ).reshape(BLK)
-        h = _fmix32(gi.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) ^ mixed)
-        offset = jax.lax.rem((h & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32), d)
-        split_part = jax.lax.rem(
-            part_heavy.astype(jnp.int32) + offset, jnp.int32(num_partitions)
+    running = counts_ref[...]
+    for r in range(ROWS):
+        part = route_row(
+            keys_ref[r:r + 1, :], hk_ref, hp_ref, host_ref, hr_ref,
+            seed=seed, num_hosts=num_hosts, num_partitions=num_partitions,
+            record_index=pl.program_id(0) * BLK + lane_iota(r),
         )
-        part = jnp.where(hit, split_part, part_tail.astype(jnp.int32)).astype(jnp.int32)
-    else:
-        part = jnp.where(hit, part_heavy, part_tail).astype(jnp.int32)
-    part_ref[...] = part.reshape(ROWS, LANES)
-
-    # ---- stage 2: lane rank (triangular prefix matmul, fused in VMEM) ----
-    lane = jax.lax.rem(part, jnp.int32(num_lanes))
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (BLK, num_lanes), 1)
-    onehot = (lane[:, None] == lane_iota).astype(jnp.float32) * valid[:, None]
-
-    r = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 1)
-    tri = (c < r).astype(jnp.float32)  # strictly lower triangular
-    prefix = jax.lax.dot_general(
-        tri, onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    running = counts_ref[...]  # [1, L] counts from earlier blocks
-    base = jnp.sum(onehot * running, axis=1)
-    rank = jnp.sum(onehot * prefix, axis=1)
-    slot = (base + rank).astype(jnp.int32)
-    slot = jnp.where(valid > 0, slot, -1)
-    slot_ref[...] = slot.reshape(ROWS, LANES)
-    counts_ref[...] = running + jnp.sum(onehot, axis=0, keepdims=True)
+        part_ref[r:r + 1, :] = part
+        slot, _, running = rank_row(
+            jax.lax.rem(part, jnp.int32(num_lanes)), valid_ref[r:r + 1, :] > 0,
+            running, num_lanes)
+        slot_ref[r:r + 1, :] = slot
+    counts_ref[...] = running
 
 
 @functools.partial(
@@ -134,7 +76,7 @@ def _kernel(
     static_argnames=("seed", "num_hosts", "num_lanes", "num_partitions", "interpret"),
 )
 def lookup_dispatch(
-    keys: jax.Array,  # int32[n], n % 256 == 0
+    keys: jax.Array,  # int32[n]
     valid: jax.Array,  # bool[n]
     heavy_keys: jax.Array,  # int32[B] sorted, sentinel padded
     heavy_parts: jax.Array,  # int32[B]
@@ -156,41 +98,27 @@ def lookup_dispatch(
     ``num_partitions == 0`` (the default) the traced program is exactly the
     pre-split one."""
     n = keys.shape[0]
-    assert n % BLK == 0, f"pad records to a multiple of {BLK}"
     assert num_hosts & (num_hosts - 1) == 0, "H must be a power of two"
-    b = heavy_keys.shape[0]
-    keys2d = keys.reshape(n // LANES, LANES)
-    valid2d = valid.astype(jnp.int32).reshape(n // LANES, LANES)
-
-    in_specs = [
-        pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-        pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-        pl.BlockSpec((1, b), lambda i: (0, 0)),
-        pl.BlockSpec((1, b), lambda i: (0, 0)),
-        pl.BlockSpec((1, host_to_part.shape[0]), lambda i: (0, 0)),
-    ]
-    inputs = [keys2d, valid2d, heavy_keys[None, :], heavy_parts[None, :],
-              host_to_part[None, :]]
+    keys2d = tile_records(keys.astype(jnp.int32))
+    valid2d = tile_records(valid.astype(jnp.int32))
     if num_partitions > 0:
         assert heavy_repl is not None, "splitting needs the replica table"
-        in_specs.append(pl.BlockSpec((1, b), lambda i: (0, 0)))
-        inputs.append(heavy_repl[None, :])
+    tables = heavy_columns(heavy_keys, heavy_parts,
+                           heavy_repl if num_partitions > 0 else None)
+    tables.insert(2, table_column(host_to_part.astype(jnp.int32)))
+    lp = padded_parts(num_lanes)
 
     part, slot, counts = pl.pallas_call(
         functools.partial(_kernel, seed=seed, num_hosts=num_hosts,
                           num_lanes=num_lanes, num_partitions=num_partitions),
-        grid=(n // BLK,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_lanes), lambda i: (0, 0)),
-        ],
+        grid=(keys2d.shape[0] // ROWS,),
+        in_specs=[row_spec(), row_spec()] + [column_spec(t) for t in tables],
+        out_specs=[row_spec(), row_spec(), pl.BlockSpec((lp, 1), lambda i: (0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_lanes), jnp.float32),
+            jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
+            jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
+            jax.ShapeDtypeStruct((lp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(*inputs)
-    return part.reshape(n), slot.reshape(n), counts[0].astype(jnp.int32)
+    )(keys2d, valid2d, *tables)
+    return part.reshape(-1)[:n], slot.reshape(-1)[:n], counts[:num_lanes, 0]

@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels.
 
-The wrappers pad inputs to kernel block multiples, pick interpret mode
-automatically (Pallas interprets on CPU; compiled on TPU), and expose
+The kernels pad records to whole tiles themselves; the wrappers pick the
+execution mode (compiled on TPU, interpreted elsewhere) and expose
 numpy-friendly signatures used by the shuffle/runtime layers.
 """
 from __future__ import annotations
@@ -9,48 +9,30 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch_count import BLK as DISPATCH_BLK, dispatch_count
-from repro.kernels.lookup_dispatch import BLK as ROUTE_BLK, lookup_dispatch
-from repro.kernels.partition_apply import KEY_LANES, KEY_ROWS, partition_apply
+from repro.kernels.dispatch_count import dispatch_count
+from repro.kernels.lookup_dispatch import lookup_dispatch
+from repro.kernels.partition_apply import partition_apply
 from repro.kernels.route_bucketize import route_bucketize as _route_bucketize_kernel
 from repro.kernels.sketch_update import sketch_update
-
-_PART_BLK = KEY_LANES * KEY_ROWS
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pad_to(x: jax.Array, mult: int, fill=0):
-    n = x.shape[0]
-    pad = (-n) % mult
-    if pad:
-        x = jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
-    return x, n
-
-
 def apply_partitioner(keys: jax.Array, tables, *, num_hosts: int, seed: int = 0) -> jax.Array:
     """Partition ids for ``keys`` using PartitionerTables (Pallas hot path)."""
-    padded, n = _pad_to(keys.astype(jnp.int32), _PART_BLK)
-    b = tables.heavy_keys.shape[0]
-    bpad = (-b) % KEY_LANES
-    hk = jnp.concatenate([tables.heavy_keys, jnp.full(bpad, 2**31 - 1, jnp.int32)]) if bpad else tables.heavy_keys
-    hp = jnp.concatenate([tables.heavy_parts, jnp.zeros(bpad, jnp.int32)]) if bpad else tables.heavy_parts
-    out = partition_apply(
-        padded, hk, hp, tables.host_to_part,
+    return partition_apply(
+        keys, tables.heavy_keys, tables.heavy_parts, tables.host_to_part,
         seed=seed, num_hosts=num_hosts, interpret=_interpret(),
     )
-    return out[:n]
 
 
 def count_sketch(keys: jax.Array, valid: jax.Array | None = None, *, depth: int = 4, width: int = 2048) -> jax.Array:
     """float32[depth, width] CMS of the batch (Pallas hot path)."""
     if valid is None:
         valid = jnp.ones(keys.shape[0], bool)
-    k, n = _pad_to(keys.astype(jnp.int32), _PART_BLK)
-    v, _ = _pad_to(valid.astype(jnp.int32), _PART_BLK)
-    return sketch_update(k, v.astype(bool), depth=depth, width=width, interpret=_interpret())
+    return sketch_update(keys, valid, depth=depth, width=width, interpret=_interpret())
 
 
 def route_slots(keys: jax.Array, valid: jax.Array, tables, *, num_hosts: int,
@@ -67,22 +49,12 @@ def route_slots(keys: jax.Array, valid: jax.Array, tables, *, num_hosts: int,
     statically (``use_pallas=False`` in the exchange plane), never per
     batch, so kernel and twin cannot diverge at runtime.
     """
-    k, n = _pad_to(keys.astype(jnp.int32), ROUTE_BLK)
-    v, _ = _pad_to(valid.astype(jnp.int32), ROUTE_BLK)
-    b = tables.heavy_keys.shape[0]
-    bpad = (-b) % KEY_LANES
-    hk = jnp.concatenate([tables.heavy_keys, jnp.full(bpad, 2**31 - 1, jnp.int32)]) if bpad else tables.heavy_keys
-    hp = jnp.concatenate([tables.heavy_parts, jnp.zeros(bpad, jnp.int32)]) if bpad else tables.heavy_parts
-    hr = None
-    if num_partitions > 0:
-        # pad replica rows with 0: sentinel matches sum to 0 -> clamp to 1
-        hr = jnp.concatenate([tables.heavy_repl, jnp.zeros(bpad, jnp.int32)]) if bpad else tables.heavy_repl
-    part, slot, counts = lookup_dispatch(
-        k, v.astype(bool), hk, hp, tables.host_to_part, hr,
+    return lookup_dispatch(
+        keys, valid, tables.heavy_keys, tables.heavy_parts, tables.host_to_part,
+        tables.heavy_repl if num_partitions > 0 else None,
         seed=seed, num_hosts=num_hosts, num_lanes=num_lanes,
         num_partitions=num_partitions, interpret=_interpret(),
     )
-    return part[:n], slot[:n], counts
 
 
 def route_bucketize(keys: jax.Array, valid: jax.Array, tables, vals: jax.Array, *,
@@ -96,30 +68,16 @@ def route_bucketize(keys: jax.Array, valid: jax.Array, tables, vals: jax.Array, 
     int32)`` — the shuffle's three send buffers built in one kernel pass,
     bit-identical to ``route_slots`` + the plane's scatter.  The kernel
     emits raw f32 channels (int32 split into 16-bit halves for f32-matmul
-    exactness); this wrapper recombines them and applies the fills.
+    exactness) at a lane-tile-aligned width; this wrapper recombines them,
+    slices ``capacity`` columns (overflow slots the ref drops land in the
+    pad) and applies the fills.
     """
     if interpret is None:
         interpret = _interpret()
-    k, n = _pad_to(keys.astype(jnp.int32), ROUTE_BLK)
-    v, _ = _pad_to(valid.astype(jnp.int32), ROUTE_BLK)
-    w, _ = _pad_to(vals.astype(jnp.float32), ROUTE_BLK)
-    b = tables.heavy_keys.shape[0]
-    # an empty heavy table still needs one tile of (sentinel) rows for the
-    # kernel's fixed block shape; sentinel keys only match invalid records,
-    # whose part is masked by every consumer
-    bpad = KEY_LANES if b == 0 else (-b) % KEY_LANES
-    hk = jnp.concatenate([tables.heavy_keys, jnp.full(bpad, 2**31 - 1, jnp.int32)]) if bpad else tables.heavy_keys
-    hp = jnp.concatenate([tables.heavy_parts, jnp.zeros(bpad, jnp.int32)]) if bpad else tables.heavy_parts
-    hr = None
-    if num_partitions > 0:
-        # pad replica rows with 0: sentinel matches sum to 0 -> clamp to 1
-        hr = jnp.concatenate([tables.heavy_repl, jnp.zeros(bpad, jnp.int32)]) if bpad else tables.heavy_repl
-    # scatter into a lane-tile-aligned buffer; the overflow columns the ref
-    # drops land in the pad and are sliced away below
-    cap_p = int(-(-capacity // 128) * 128)
     part, slot, counts, bvalid, bkhi, bklo, bphi, bplo, bvals = _route_bucketize_kernel(
-        k, v.astype(bool), w, hk, hp, tables.host_to_part, hr,
-        seed=seed, num_hosts=num_hosts, num_lanes=num_lanes, capacity=cap_p,
+        keys, valid, vals, tables.heavy_keys, tables.heavy_parts, tables.host_to_part,
+        tables.heavy_repl if num_partitions > 0 else None,
+        seed=seed, num_hosts=num_hosts, num_lanes=num_lanes, capacity=capacity,
         num_partitions=num_partitions, interpret=interpret,
     )
     buf_valid = bvalid[:, :capacity] > 0.0
@@ -133,14 +91,11 @@ def route_bucketize(keys: jax.Array, valid: jax.Array, tables, vals: jax.Array, 
     buf_part = jnp.where(buf_valid, _combine(bphi, bplo), 0)
     buf_vals = jnp.where(buf_valid[:, :, None],
                          jnp.moveaxis(bvals, 0, -1)[:, :capacity], 0.0)
-    return part[:n], slot[:n], counts, buf_valid, buf_keys, buf_vals, buf_part
+    return part, slot, counts, buf_valid, buf_keys, buf_vals, buf_part
 
 
 def dispatch_slots(dest: jax.Array, valid: jax.Array | None = None, *, num_parts: int):
     """(slot[n], counts[num_parts]) for building the all-to-all send buffer."""
     if valid is None:
         valid = jnp.ones(dest.shape[0], bool)
-    d, n = _pad_to(dest.astype(jnp.int32), DISPATCH_BLK)
-    v, _ = _pad_to(valid.astype(jnp.int32), DISPATCH_BLK)
-    slot, counts = dispatch_count(d, v.astype(bool), num_parts=num_parts, interpret=_interpret())
-    return slot[:n], counts
+    return dispatch_count(dest, valid, num_parts=num_parts, interpret=_interpret())

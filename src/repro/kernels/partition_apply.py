@@ -7,14 +7,24 @@ For every key the partitioner computes::
          = host_to_part[host]        otherwise
 
 TPU adaptation (vs. the JVM per-record hash-map of the paper): the heavy
-table (B <= 1024 keys) and the host routing table (H = 4096) are pinned in
-VMEM for the whole kernel; lookups are expressed as one-hot matmuls so they
-lower to MXU/VPU ops instead of dynamic gathers.
+table (B keys) and the host routing table (H entries) are pinned in VMEM as
+sublane columns ``[B, 1]`` / ``[H, 1]`` for the whole kernel, and lookups
+are one-hot selects reduced over sublanes — VPU work with exact int32
+arithmetic, no dynamic gathers and no MXU rounding.
 
-VMEM budget per grid step (block = 256 keys, H = 4096, B = 1024):
-  host one-hot  256*4096*4B = 4.0 MiB
-  heavy one-hot 256*1024*4B = 1.0 MiB
-  tables        (B*2 + H)*4B ~ 24 KiB          => ~5.1 MiB < 16 MiB VMEM.
+Layout shared by every route kernel (this module also hosts the helpers
+``lookup_dispatch``, ``route_bucketize`` and ``sketch_update`` build on):
+records arrive lane-dense as ``[n / 128, 128]`` int32 and each grid step
+takes one ``(8, 128)`` tile — 1024 records, the TPU's native 32-bit tile,
+so the block satisfies the ``(8, 128)`` rule.  Inside a step the kernel
+walks the tile's 8 rows; every per-record vector is a ``[1, 128]`` row and
+every per-table quantity a ``[T, 128]`` one-hot with records on lanes, so no
+record ever changes layout.
+
+VMEM budget per grid step (H = 4096, B <= 1024): host table column
+4096*128*4B = 2 MiB, double-buffered 4 MiB; host one-hot of one row
+4096*128*4B = 2 MiB; heavy columns + one-hot 3 * 1024*128*4B = 1.5 MiB
+=> ~8 MiB < the 16 MiB scoped VMEM default.
 """
 from __future__ import annotations
 
@@ -24,9 +34,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# keys are processed in [KEY_ROWS, 128] tiles (lane dim = 128, TPU-native).
-KEY_LANES = 128
-KEY_ROWS = 2  # 256 keys per grid step
+# records are processed in (ROWS, LANES) int32 tiles: one native TPU tile
+LANES = 128
+ROWS = 8
+BLK = ROWS * LANES  # 1024 records per grid step
+
+# heavy-table pad key: only invalid (sentinel) records can match it, and
+# every consumer masks their partition
+_PAD_KEY = 2**31 - 1
 
 
 def _fmix32(x):
@@ -39,39 +54,88 @@ def _fmix32(x):
     return x
 
 
-def _kernel(keys_ref, heavy_keys_ref, heavy_parts_ref, host_ref, out_ref, *, seed: int, num_hosts: int):
-    keys2d = keys_ref[...]  # [KEY_ROWS, 128] int32
-    blk = KEY_ROWS * KEY_LANES
-    keys = keys2d.reshape(blk)
+def tile_records(x: jax.Array, fill=0) -> jax.Array:
+    """``[n] -> [ceil(n / BLK) * 8, 128]``: pad a record vector to whole
+    tiles (with ``fill``) and lay it out lane-dense."""
+    n = x.shape[0]
+    pad = (-n) % BLK
+    if pad:
+        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+    return x.reshape(-1, LANES)
 
-    # ---- weighted hash: key -> host -> partition ----
+
+def table_column(x: jax.Array, fill=0) -> jax.Array:
+    """``[T] -> [T', 1]`` with ``T'`` the next multiple of 8 (at least 8):
+    a lookup table as a VMEM sublane column, padded with ``fill``."""
+    pad = max((-x.shape[0]) % 8, 8 - x.shape[0])
+    if pad:
+        x = jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+    return x[:, None]
+
+
+def heavy_columns(heavy_keys, heavy_parts, heavy_repl=None):
+    """The heavy table as kernel inputs: sentinel-padded sublane columns
+    (pad rows carry part 0 and replica count 0)."""
+    cols = [table_column(heavy_keys.astype(jnp.int32), _PAD_KEY),
+            table_column(heavy_parts.astype(jnp.int32), 0)]
+    if heavy_repl is not None:
+        cols.append(table_column(heavy_repl.astype(jnp.int32), 0))
+    return cols
+
+
+def column_spec(col: jax.Array) -> pl.BlockSpec:
+    """Whole-array block for a table column, resident across the grid."""
+    return pl.BlockSpec(col.shape, lambda i: (0, 0))
+
+
+def row_spec() -> pl.BlockSpec:
+    """One ``(8, 128)`` record tile per grid step."""
+    return pl.BlockSpec((ROWS, LANES), lambda i: (i, 0))
+
+
+def lane_iota(r: int):
+    """``[1, 128]`` tile-local record index of row ``r``'s records."""
+    return r * LANES + jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+
+def route_row(keys, hk_ref, hp_ref, host_ref, hr_ref=None, *, seed: int,
+              num_hosts: int, num_partitions: int = 0, record_index=None):
+    """Partition ids ``[1, 128]`` of one row of keys ``[1, 128]``.
+
+    With ``num_partitions > 0`` (and the replica column ``hr_ref``) a split
+    heavy key with ``d`` replicas lands on ``(home + offset) % N`` where
+    ``offset = fmix32(i * golden ^ mix) mod d`` hashes the record's
+    shard-local index ``record_index``."""
     mixed = _fmix32(keys.astype(jnp.uint32) ^ jnp.uint32((seed * 0x9E3779B9) & 0xFFFFFFFF))
     host = (mixed & jnp.uint32(num_hosts - 1)).astype(jnp.int32)
-    host_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, num_hosts), 1)
-    onehot_host = (host[:, None] == host_iota).astype(jnp.float32)  # [blk, H]
-    table = host_ref[...].reshape(num_hosts).astype(jnp.float32)
-    part_tail = jax.lax.dot_general(
-        onehot_host, table[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
+    table = host_ref[...]  # [H, 1]
+    host_iota = jax.lax.broadcasted_iota(jnp.int32, (table.shape[0], LANES), 0)
+    part_tail = jnp.sum(jnp.where(host_iota == host, table, 0), axis=0, keepdims=True)
 
-    # ---- explicit heavy-key routing ----
-    hk = heavy_keys_ref[...].reshape(-1)  # [B] sorted, sentinel padded
-    hp = heavy_parts_ref[...].reshape(-1).astype(jnp.float32)
-    eq = (keys[:, None] == hk[None, :]).astype(jnp.float32)  # [blk, B]
-    hit = jnp.sum(eq, axis=1) > 0.0
-    part_heavy = jax.lax.dot_general(
-        eq, hp[:, None], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )[:, 0]
+    eq = hk_ref[...] == keys  # [B, 128]: one live match per heavy key
+    hit = jnp.max(eq.astype(jnp.int32), axis=0, keepdims=True) > 0
+    part_heavy = jnp.sum(jnp.where(eq, hp_ref[...], 0), axis=0, keepdims=True)
+    if num_partitions > 0:
+        # replicas per record (sentinel records sum pad rows' 0 -> clamp 1)
+        d = jnp.maximum(jnp.sum(jnp.where(eq, hr_ref[...], 0), axis=0, keepdims=True), 1)
+        h = _fmix32(record_index.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) ^ mixed)
+        offset = jax.lax.rem((h & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32), d)
+        part_heavy = jax.lax.rem(part_heavy + offset, jnp.int32(num_partitions))
+    return jnp.where(hit, part_heavy, part_tail)
 
-    part = jnp.where(hit, part_heavy, part_tail).astype(jnp.int32)
-    out_ref[...] = part.reshape(KEY_ROWS, KEY_LANES)
+
+def _kernel(keys_ref, hk_ref, hp_ref, host_ref, out_ref, *, seed: int, num_hosts: int):
+    for r in range(ROWS):
+        out_ref[r:r + 1, :] = route_row(
+            keys_ref[r:r + 1, :], hk_ref, hp_ref, host_ref,
+            seed=seed, num_hosts=num_hosts,
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "num_hosts", "interpret"))
 def partition_apply(
-    keys: jax.Array,  # int32[n], n % 256 == 0
-    heavy_keys: jax.Array,  # int32[B] sorted, sentinel padded; B % 128 == 0
+    keys: jax.Array,  # int32[n]
+    heavy_keys: jax.Array,  # int32[B] sorted, sentinel padded
     heavy_parts: jax.Array,  # int32[B]
     host_to_part: jax.Array,  # int32[H]
     *,
@@ -80,24 +144,16 @@ def partition_apply(
     interpret: bool = True,
 ) -> jax.Array:
     n = keys.shape[0]
-    blk = KEY_ROWS * KEY_LANES
-    assert n % blk == 0, f"pad keys to a multiple of {blk}"
     assert num_hosts & (num_hosts - 1) == 0, "H must be a power of two"
-    b = heavy_keys.shape[0]
-    keys2d = keys.reshape(n // KEY_LANES, KEY_LANES)
-
-    grid = (n // blk,)
+    keys2d = tile_records(keys.astype(jnp.int32))
+    hk, hp = heavy_columns(heavy_keys, heavy_parts)
+    host = table_column(host_to_part.astype(jnp.int32))
     out = pl.pallas_call(
         functools.partial(_kernel, seed=seed, num_hosts=num_hosts),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((KEY_ROWS, KEY_LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, b), lambda i: (0, 0)),
-            pl.BlockSpec((1, b), lambda i: (0, 0)),
-            pl.BlockSpec((1, host_to_part.shape[0]), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((KEY_ROWS, KEY_LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // KEY_LANES, KEY_LANES), jnp.int32),
+        grid=(keys2d.shape[0] // ROWS,),
+        in_specs=[row_spec(), column_spec(hk), column_spec(hp), column_spec(host)],
+        out_specs=row_spec(),
+        out_shape=jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
         interpret=interpret,
-    )(keys2d, heavy_keys[None, :], heavy_parts[None, :], host_to_part[None, :])
-    return out.reshape(n)
+    )(keys2d, hk, hp, host)
+    return out.reshape(-1)[:n]

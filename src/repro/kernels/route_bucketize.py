@@ -8,31 +8,37 @@ key -> partition -> lane -> slot -> send-buffer path never leaves VMEM, so
 the records make one trip instead of a materialize + re-read of the whole
 batch between the route kernel and ``_bucketize``.
 
-The scatter itself is a matmul (MXU, no serial stores): for a block of
-``blk`` records with one-hot lane matrix ``O_lane [blk, L]`` (valid-masked)
-and one-hot slot matrix ``O_slot [blk, cap]``, each scalar channel ``w``
+The scatter itself is a matmul (MXU, no serial stores): for one row of 128
+records with valid-masked lane one-hot ``O_lane [L, 128]`` and slot one-hot
+``O_slot [cap, 128]`` (records on lanes in both), each scalar channel ``w``
 lands as::
 
-    buffer[l, c] += sum_r  O_lane[r, l] * w[r] * O_slot[r, c]
-                 =  ((O_lane * w[:, None]).T @ O_slot)[l, c]
+    buffer[l, c] += sum_r  O_lane[l, r] * w[r] * O_slot[c, r]
+                 =  ((O_lane * w[None, :]) @ O_slot.T)[l, c]
 
 Slot ranks are globally unique within a lane (``dispatch_count``'s
 invariant), so every ``(l, c)`` entry receives at most one nonzero term
 across the whole grid — the f32 accumulation is exact, and rows whose slot
 falls outside ``[0, cap)`` (capacity overflow, invalid records) match no
-one-hot column and drop out, exactly like the jnp scatter's
-``mode="drop"``.
+one-hot row and drop out, exactly like the jnp scatter's ``mode="drop"``.
 
 int32 channels (keys, partition ids) cannot ride f32 matmuls directly
 (f32 is exact only to 2**24), so they are split into 16-bit halves
 (``x >> 16`` / ``x & 0xFFFF``, each < 65536, exact in f32) and recombined
 outside the kernel.  Payload values are f32 and ride as-is: the product
-``w * 1.0`` and the single-term sum are exact.
+``w * 1.0`` and the single-term sum are exact.  The matmuls run at
+``Precision.HIGHEST`` so the MXU keeps every channel at full f32.
 
-VMEM budget per grid step (block = 256, H = 4096, B <= 1024, L <= 16,
-capP <= 2048): route stages ~6.3 MiB (as ``lookup_dispatch``); slot one-hot
-256*2048*4B = 2.0 MiB; per-channel accumulators 5 * 16*2048*4B = 0.6 MiB
-=> ~9 MiB.
+The ``[L, cap]`` send buffers stay resident in VMEM for the whole grid, so
+the kernel only fits small exchanges: :func:`fits` is the static size rule
+the exchange plane checks before choosing it (larger exchanges run
+``lookup_dispatch`` + the plane's scatter).
+
+VMEM budget per grid step (H = 4096, B <= 1024, L <= MAX_LANES = 16,
+capP <= MAX_CAPACITY = 2048, D <= MAX_PAYLOAD = 8): route + rank stages
+~9.5 MiB (as ``lookup_dispatch``); slot one-hot 2048*128*4B = 1 MiB;
+(5 + D) resident f32 buffers, double-buffered, 2 * 13 * 16*2048*4B =
+3.3 MiB => ~14 MiB.
 """
 from __future__ import annotations
 
@@ -42,118 +48,100 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.lookup_dispatch import BLK, LANES, ROWS, _fmix32
+from repro.kernels.dispatch_count import padded_parts, rank_row
+from repro.kernels.partition_apply import (
+    BLK,
+    LANES,
+    ROWS,
+    column_spec,
+    heavy_columns,
+    lane_iota,
+    route_row,
+    row_spec,
+    table_column,
+    tile_records,
+)
+
+# the static size rule (see module doc): the largest exchange whose send
+# buffers the kernel keeps resident in VMEM
+MAX_LANES = 16
+MAX_CAPACITY = 2048
+MAX_PAYLOAD = 8
+
+
+def padded_capacity(capacity: int) -> int:
+    """Buffer width the kernel scatters into: lane-tile aligned."""
+    return int(-(-capacity // LANES) * LANES)
+
+
+def fits(num_lanes: int, capacity: int, payload_dim: int) -> bool:
+    """True when one fused pass can hold the ``[L, capacity]`` send buffers."""
+    return (num_lanes <= MAX_LANES and padded_capacity(capacity) <= MAX_CAPACITY
+            and payload_dim <= MAX_PAYLOAD)
+
+
+def _halves(x):
+    """int32 ``[1, 128]`` -> its high and low 16 bits as exact f32 rows."""
+    return (jax.lax.shift_right_logical(x, 16).astype(jnp.float32),
+            (x & 0xFFFF).astype(jnp.float32))
 
 
 def _kernel(
-    keys_ref, valid_ref, vals_ref, heavy_keys_ref, heavy_parts_ref, host_ref,
-    *rest, seed: int, num_hosts: int, num_lanes: int, capacity: int,
+    keys_ref, valid_ref, vals_ref, hk_ref, hp_ref, host_ref, *rest,
+    seed: int, num_hosts: int, num_lanes: int, capacity: int,
     num_partitions: int = 0,
 ):
-    # with splitting active (num_partitions > 0) the heavy-replica table
+    # with splitting active (num_partitions > 0) the heavy-replica column
     # rides along as a seventh input, ahead of the output refs
-    if num_partitions > 0:
-        heavy_repl_ref, *rest = rest
+    hr_ref = rest[0] if num_partitions > 0 else None
     (part_ref, slot_ref, counts_ref,
-     bvalid_ref, bkhi_ref, bklo_ref, bphi_ref, bplo_ref, bvals_ref) = rest
-    keys = keys_ref[...].reshape(BLK)
-    valid = valid_ref[...].reshape(BLK).astype(jnp.float32)
+     bvalid_ref, bkhi_ref, bklo_ref, bphi_ref, bplo_ref, bvals_ref) = rest[-9:]
+    bufs = (bvalid_ref, bkhi_ref, bklo_ref, bphi_ref, bplo_ref)
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
-        bvalid_ref[...] = jnp.zeros_like(bvalid_ref)
-        bkhi_ref[...] = jnp.zeros_like(bkhi_ref)
-        bklo_ref[...] = jnp.zeros_like(bklo_ref)
-        bphi_ref[...] = jnp.zeros_like(bphi_ref)
-        bplo_ref[...] = jnp.zeros_like(bplo_ref)
-        bvals_ref[...] = jnp.zeros_like(bvals_ref)
+        for b in bufs + (bvals_ref,):
+            b[...] = jnp.zeros_like(b)
 
-    # ---- stage 1: key -> partition (one-hot matmul lookup) ----
-    mixed = _fmix32(keys.astype(jnp.uint32) ^ jnp.uint32((seed * 0x9E3779B9) & 0xFFFFFFFF))
-    host = (mixed & jnp.uint32(num_hosts - 1)).astype(jnp.int32)
-    host_iota = jax.lax.broadcasted_iota(jnp.int32, (BLK, num_hosts), 1)
-    onehot_host = (host[:, None] == host_iota).astype(jnp.float32)
-    table = host_ref[...].reshape(num_hosts).astype(jnp.float32)
-    part_tail = jax.lax.dot_general(
-        onehot_host, table[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0]
-
-    hk = heavy_keys_ref[...].reshape(-1)
-    hp = heavy_parts_ref[...].reshape(-1).astype(jnp.float32)
-    eq = (keys[:, None] == hk[None, :]).astype(jnp.float32)
-    hit = jnp.sum(eq, axis=1) > 0.0
-    part_heavy = jax.lax.dot_general(
-        eq, hp[:, None], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )[:, 0]
-    if num_partitions > 0:
-        # ---- split-key replica pick (same formula as lookup_dispatch) ----
-        hr = heavy_repl_ref[...].reshape(-1).astype(jnp.float32)
-        d = jax.lax.dot_general(
-            eq, hr[:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )[:, 0]
-        d = jnp.maximum(d.astype(jnp.int32), 1)
-        gi = pl.program_id(0) * BLK + (
-            jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 0) * LANES
-            + jax.lax.broadcasted_iota(jnp.int32, (ROWS, LANES), 1)
-        ).reshape(BLK)
-        h = _fmix32(gi.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) ^ mixed)
-        offset = jax.lax.rem((h & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32), d)
-        split_part = jax.lax.rem(
-            part_heavy.astype(jnp.int32) + offset, jnp.int32(num_partitions)
+    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (capacity, LANES), 0)
+    running = counts_ref[...]
+    for r in range(ROWS):
+        keys = keys_ref[r:r + 1, :]
+        valid = valid_ref[r:r + 1, :] > 0
+        # ---- stage 1 + 2: route and lane rank (shared row helpers) ----
+        part = route_row(
+            keys, hk_ref, hp_ref, host_ref, hr_ref,
+            seed=seed, num_hosts=num_hosts, num_partitions=num_partitions,
+            record_index=pl.program_id(0) * BLK + lane_iota(r),
         )
-        part = jnp.where(hit, split_part, part_tail.astype(jnp.int32)).astype(jnp.int32)
-    else:
-        part = jnp.where(hit, part_heavy, part_tail).astype(jnp.int32)
-    part_ref[...] = part.reshape(ROWS, LANES)
+        part_ref[r:r + 1, :] = part
+        slot, onehot, running = rank_row(
+            jax.lax.rem(part, jnp.int32(num_lanes)), valid, running, num_lanes)
+        slot_ref[r:r + 1, :] = slot
 
-    # ---- stage 2: lane rank (triangular prefix matmul, fused in VMEM) ----
-    lane = jax.lax.rem(part, jnp.int32(num_lanes))
-    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (BLK, num_lanes), 1)
-    onehot = (lane[:, None] == lane_iota).astype(jnp.float32) * valid[:, None]
+        # ---- stage 3: scatter into the send buffers (matmul, in VMEM) ----
+        onehot_slot = (slot_iota == slot).astype(jnp.float32)  # [cap, 128]
 
-    r = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (BLK, BLK), 1)
-    tri = (c < r).astype(jnp.float32)  # strictly lower triangular
-    prefix = jax.lax.dot_general(
-        tri, onehot, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        def scat(w):  # [1, 128] channel -> [Lp, cap] contribution of this row
+            return jax.lax.dot_general(
+                jnp.where(onehot, w, 0.0), onehot_slot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
-    running = counts_ref[...]  # [1, L] counts from earlier blocks
-    base = jnp.sum(onehot * running, axis=1)
-    rank = jnp.sum(onehot * prefix, axis=1)
-    slot = (base + rank).astype(jnp.int32)
-    slot = jnp.where(valid > 0, slot, -1)
-    slot_ref[...] = slot.reshape(ROWS, LANES)
-    counts_ref[...] = running + jnp.sum(onehot, axis=0, keepdims=True)
-
-    # ---- stage 3: scatter into the send buffers (matmul, still in VMEM) --
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (BLK, capacity), 1)
-    onehot_slot = (slot[:, None] == slot_iota).astype(jnp.float32)
-
-    def scat(w):  # [blk] channel -> [L, cap] contribution of this block
-        return jax.lax.dot_general(
-            onehot * w[:, None], onehot_slot, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    keys_u = keys.astype(jnp.uint32)
-    part_u = part.astype(jnp.uint32)
-    bvalid_ref[...] += scat(jnp.ones(BLK, jnp.float32))
-    bkhi_ref[...] += scat((keys_u >> jnp.uint32(16)).astype(jnp.float32))
-    bklo_ref[...] += scat((keys_u & jnp.uint32(0xFFFF)).astype(jnp.float32))
-    bphi_ref[...] += scat((part_u >> jnp.uint32(16)).astype(jnp.float32))
-    bplo_ref[...] += scat((part_u & jnp.uint32(0xFFFF)).astype(jnp.float32))
-    for d in range(vals_ref.shape[1]):
-        bvals_ref[d] += scat(vals_ref[:, d])
+        channels = (jnp.ones((1, LANES), jnp.float32), *_halves(keys), *_halves(part))
+        for b, w in zip(bufs, channels):
+            b[...] += scat(w)
+        for d in range(vals_ref.shape[0]):
+            bvals_ref[d] += scat(vals_ref[d, r:r + 1, :])
+    counts_ref[...] = running
 
 
 @functools.partial(jax.jit, static_argnames=(
     "seed", "num_hosts", "num_lanes", "capacity", "num_partitions", "interpret"))
 def route_bucketize(
-    keys: jax.Array,  # int32[n], n % 256 == 0
+    keys: jax.Array,  # int32[n]
     valid: jax.Array,  # bool[n]
     vals: jax.Array,  # f32[n, D]
     heavy_keys: jax.Array,  # int32[B] sorted, sentinel padded
@@ -168,64 +156,48 @@ def route_bucketize(
     num_partitions: int = 0,
     interpret: bool = True,
 ):
-    """Returns ``(part[n], slot[n], counts[L], bvalid[L, cap],
-    bkhi/bklo/bphi/bplo [L, cap], bvals[D, L, cap])`` — raw f32 channel
-    buffers; ``repro.kernels.ops.route_bucketize`` recombines the 16-bit
-    halves and applies fills.  ``num_partitions > 0`` enables the split-key
-    replica pick (see ``lookup_dispatch``); 0 traces the pre-split program."""
-    n = keys.shape[0]
-    assert n % BLK == 0, f"pad records to a multiple of {BLK}"
+    """Returns ``(part[n], slot[n], counts[L], bvalid[L, capP],
+    bkhi/bklo/bphi/bplo [L, capP], bvals[D, L, capP])`` — raw f32 channel
+    buffers at the lane-tile-aligned width ``capP``;
+    ``repro.kernels.ops.route_bucketize`` recombines the 16-bit halves,
+    slices ``capacity`` columns and applies fills.  ``num_partitions > 0``
+    enables the split-key replica pick (see ``lookup_dispatch``); 0 traces
+    the pre-split program."""
+    n, d = vals.shape
     assert num_hosts & (num_hosts - 1) == 0, "H must be a power of two"
-    b = heavy_keys.shape[0]
-    d = vals.shape[1]
-    keys2d = keys.reshape(n // LANES, LANES)
-    valid2d = valid.astype(jnp.int32).reshape(n // LANES, LANES)
-
-    in_specs = [
-        pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-        pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-        pl.BlockSpec((BLK, d), lambda i: (i, 0)),
-        pl.BlockSpec((1, b), lambda i: (0, 0)),
-        pl.BlockSpec((1, b), lambda i: (0, 0)),
-        pl.BlockSpec((1, host_to_part.shape[0]), lambda i: (0, 0)),
-    ]
-    inputs = [keys2d, valid2d, vals, heavy_keys[None, :], heavy_parts[None, :],
-              host_to_part[None, :]]
+    assert fits(num_lanes, capacity, d), (num_lanes, capacity, d)
+    cap_p = padded_capacity(capacity)
+    keys2d = tile_records(keys.astype(jnp.int32))
+    valid2d = tile_records(valid.astype(jnp.int32))
+    # payload channels lane-dense like the keys: [D, n / 128, 128]
+    vals3d = jnp.stack([tile_records(vals[:, i].astype(jnp.float32)) for i in range(d)])
     if num_partitions > 0:
         assert heavy_repl is not None, "splitting needs the replica table"
-        in_specs.append(pl.BlockSpec((1, b), lambda i: (0, 0)))
-        inputs.append(heavy_repl[None, :])
+    tables = heavy_columns(heavy_keys, heavy_parts,
+                           heavy_repl if num_partitions > 0 else None)
+    tables.insert(2, table_column(host_to_part.astype(jnp.int32)))
+    lp = padded_parts(num_lanes)
+    buf = jax.ShapeDtypeStruct((lp, cap_p), jnp.float32)
+    buf_spec = pl.BlockSpec((lp, cap_p), lambda i: (0, 0))
 
     out = pl.pallas_call(
         functools.partial(_kernel, seed=seed, num_hosts=num_hosts,
-                          num_lanes=num_lanes, capacity=capacity,
+                          num_lanes=num_lanes, capacity=cap_p,
                           num_partitions=num_partitions),
-        grid=(n // BLK,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, num_lanes), lambda i: (0, 0)),
-            pl.BlockSpec((num_lanes, capacity), lambda i: (0, 0)),
-            pl.BlockSpec((num_lanes, capacity), lambda i: (0, 0)),
-            pl.BlockSpec((num_lanes, capacity), lambda i: (0, 0)),
-            pl.BlockSpec((num_lanes, capacity), lambda i: (0, 0)),
-            pl.BlockSpec((num_lanes, capacity), lambda i: (0, 0)),
-            pl.BlockSpec((d, num_lanes, capacity), lambda i: (0, 0, 0)),
-        ],
+        grid=(keys2d.shape[0] // ROWS,),
+        in_specs=[row_spec(), row_spec(),
+                  pl.BlockSpec((d, ROWS, LANES), lambda i: (0, i, 0))]
+                 + [column_spec(t) for t in tables],
+        out_specs=[row_spec(), row_spec(), pl.BlockSpec((lp, 1), lambda i: (0, 0))]
+                  + [buf_spec] * 5
+                  + [pl.BlockSpec((d, lp, cap_p), lambda i: (0, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((n // LANES, LANES), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_lanes), jnp.float32),
-            jax.ShapeDtypeStruct((num_lanes, capacity), jnp.float32),
-            jax.ShapeDtypeStruct((num_lanes, capacity), jnp.float32),
-            jax.ShapeDtypeStruct((num_lanes, capacity), jnp.float32),
-            jax.ShapeDtypeStruct((num_lanes, capacity), jnp.float32),
-            jax.ShapeDtypeStruct((num_lanes, capacity), jnp.float32),
-            jax.ShapeDtypeStruct((d, num_lanes, capacity), jnp.float32),
-        ],
+            jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
+            jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
+            jax.ShapeDtypeStruct((lp, 1), jnp.int32),
+        ] + [buf] * 5 + [jax.ShapeDtypeStruct((d, lp, cap_p), jnp.float32)],
         interpret=interpret,
-    )(*inputs)
-    part, slot, counts, bvalid, bkhi, bklo, bphi, bplo, bvals = out
-    return (part.reshape(n), slot.reshape(n), counts[0].astype(jnp.int32),
-            bvalid, bkhi, bklo, bphi, bplo, bvals)
+    )(keys2d, valid2d, vals3d, *tables)
+    part, slot, counts, *bufs = out
+    bufs = [b[:num_lanes] for b in bufs[:5]] + [bufs[5][:, :num_lanes]]
+    return (part.reshape(-1)[:n], slot.reshape(-1)[:n], counts[:num_lanes, 0], *bufs)

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
+from jax import set_mesh
 from repro.configs.base import SHAPES, cells_for
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch.mesh import dp_axes_of, make_production_mesh

@@ -3,8 +3,17 @@ importing this module must not touch jax device state)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.exchange.spec import ExchangeTopology
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types: the sharding mode this
+    repo's GSPMD-style code (``with_sharding_constraint`` rules, MoE and
+    pipeline bodies) is written for.  jax's own default is ``Explicit``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,7 +21,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods = 512 chips (2, 16, 16) over ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes_of(mesh) -> tuple[str, ...]:

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import ArchConfig
 from repro.models import transformer
 from repro.models.attention import head_layout

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import MoESpec
 from repro.exchange import ExchangeSpec, Payload, make_exchange, take_from
 from repro.models.modules import Array, Policy, act_fn, init_ffn, no_shard, normal

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = ["init_error_feedback", "compressed_grad_sync"]
 

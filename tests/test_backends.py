@@ -16,7 +16,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.migration import exchange_lane_cost, plan_migration
 from repro.core.partitioner import uniform_partitioner
 from repro.exchange import (
@@ -47,7 +47,8 @@ def _random_input(rng, n, num_lanes, payload_dim=3):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=10)
+# every drawn shape compiles afresh: time is jit, not the property
+@settings(max_examples=10, deadline=None)
 @given(
     n=st.integers(min_value=1, max_value=512),
     num_lanes=st.integers(min_value=1, max_value=16),
@@ -82,7 +83,8 @@ def test_bucketize_bit_identical_across_backends(n, num_lanes, capacity, seed):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=be)
 
 
-@settings(max_examples=10)
+# every drawn shape compiles afresh: time is jit, not the property
+@settings(max_examples=10, deadline=None)
 @given(
     n=st.integers(min_value=8, max_value=512),
     num_lanes=st.integers(min_value=1, max_value=8),
@@ -247,7 +249,7 @@ def test_compat_ragged_all_to_all_shim_contract():
     """The shim itself, called directly: exactly ``send_sizes`` rows per
     lane move, and the unreceived region of the output keeps its initial
     values — the same contract whichever branch the installed jax takes
-    (native collective on >= 0.5, masked dense fallback on 0.4.x)."""
+    (native collective on a TPU mesh, masked dense elsewhere)."""
     from repro.compat import ragged_all_to_all
 
     mesh = jax.make_mesh((1,), ("data",))

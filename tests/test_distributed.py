@@ -109,6 +109,14 @@ RESIZE_SCRIPT = textwrap.dedent(
         if len(keys_w):
             assert np.all(part.lookup_np(keys_w.astype(np.int32)) % 4 == w)
 
+    # ... and that shard lives on its own device: the stacked state is
+    # sharded over `data`, one worker's table per device, not replicated
+    for arr in (job.state_keys, job.state_vals):
+        shards = sorted(arr.addressable_shards, key=lambda s: s.index[0].start)
+        assert len(shards) == 4, len(shards)
+        assert [s.data.shape[0] for s in shards] == [1] * 4
+        assert len({s.device for s in shards}) == 4
+
     print("RESIZE-DISTRIBUTED-OK")
     """
 )
@@ -137,8 +145,8 @@ BACKEND_SCRIPT = textwrap.dedent(
     batches = list(drifting_zipf(5, 8192, num_keys=2000, exponent=1.5,
                                  drift_every=2, drift_fraction=0.4, seed=3))
     # three transports: dense, ragged (native ragged_all_to_all on
-    # jax >= 0.5, masked dense on 0.4.x), and ragged with the native
-    # collective force-disabled — on jax >= 0.5 that makes the run a real
+    # TPU meshes, masked dense elsewhere), and ragged with the native
+    # collective force-disabled — on a TPU mesh that makes the run a real
     # native-vs-fallback bit-identity check across an 8-way all_to_all
     jobs = {}
     for be, force_fallback in (("dense", False), ("ragged", False),
@@ -327,7 +335,7 @@ HIERARCHICAL_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, numpy as np, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.drm import DRConfig
     from repro.core.streaming import StreamingJob
     from repro.data.generators import drifting_zipf
@@ -423,9 +431,10 @@ MOE_BACKHAUL_SCRIPT = textwrap.dedent(
     from repro.configs.base import MoESpec
     from repro.models.modules import Policy
     from repro.moe.layer import init_moe, moe_ref, moe_apply
-    from repro.compat import set_mesh
+    from jax import set_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MoESpec(num_experts=8, top_k=2, d_ff_expert=32, shared_expert=False,
                    capacity_factor=8.0)  # generous: nothing drops
     d = 16
@@ -448,7 +457,7 @@ MOE_BACKHAUL_SCRIPT = textwrap.dedent(
             )(ps, xs)
 
     # bit-identity across a real 4-way dispatch + backhaul: the ragged
-    # combine (count-reusing return trip, native collective on jax >= 0.5)
+    # combine (count-reusing return trip, native collective on TPU meshes)
     # must match the dense pad exactly, and both match the oracle
     np.testing.assert_array_equal(np.asarray(got["dense"].y),
                                   np.asarray(got["ragged"].y))

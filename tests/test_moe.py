@@ -92,9 +92,10 @@ DISPATCH_SCRIPT = textwrap.dedent(
     from repro.configs.base import MoESpec
     from repro.models.modules import Policy
     from repro.moe.layer import init_moe, moe_ref, moe_apply
-    from repro.compat import set_mesh
+    from jax import set_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MoESpec(num_experts=8, top_k=2, d_ff_expert=32, shared_expert=True,
                    capacity_factor=8.0)  # generous: nothing drops
     d = 16

@@ -15,7 +15,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import model
     from repro.models.modules import Policy
     from repro.launch.pipeline import make_pp_loss, stack_stage_params
-    from repro.compat import set_mesh
+    from jax import set_mesh
+    from repro.launch.mesh import make_mesh
     import dataclasses
 
     cfg = reduce_for_smoke(get_config("stablelm-1.6b"))
@@ -31,7 +32,7 @@ SCRIPT = textwrap.dedent("""
     }
     want, _ = model.loss_fn(params, batch, cfg, pol)
 
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = make_mesh((2,), ("pod",))
     stacked = stack_stage_params(cfg, params, 2)
     with set_mesh(mesh):
         pp_loss = make_pp_loss(cfg, pol, mesh, microbatches=2)

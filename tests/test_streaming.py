@@ -116,6 +116,18 @@ def test_wordcount_exact():
         assert job.state_count(int(key)) == float((stream == key).sum())
 
 
+@pytest.mark.parametrize("backend,transport", [("dense", "dense"),
+                                               ("ragged", "ragged/masked-dense")])
+def test_batch_metrics_name_route_path_and_transport(backend, transport):
+    """Off-TPU the route runs the jnp twin and the ragged row phase the
+    masked-dense collective (XLA:CPU has no native ragged all-to-all); the
+    metrics say so."""
+    job = StreamingJob(mesh=_mesh1(), state_capacity=1024, exchange_backend=backend)
+    m = job.process_batch(np.arange(512))
+    assert m.route_path == "jnp twin"
+    assert m.transport == transport
+
+
 def test_dr_triggers_and_improves_on_skew():
     job = StreamingJob(
         num_partitions=8,

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.control import Telemetry
 from repro.core.drm import DRConfig, DRMaster
 from repro.core.migration import MigrationPlan, exchange_lane_cost
