@@ -29,7 +29,8 @@ Host-sync instrumentation (``host_fetch`` / ``safe_point`` /
 device->host conversions through :func:`host_fetch`, which counts fetches
 of device arrays performed outside a ``with safe_point():`` region.  The
 counter is how benches prove the depth-2 pipeline's "zero blocking
-transfers between safe points" contract.
+transfers between safe points" contract.  ``host_fetch_bytes`` adds up the
+bytes every fetch moved, inside safe points or not.
 """
 from __future__ import annotations
 
@@ -45,6 +46,7 @@ __all__ = [
     "native_ragged",
     "overlap_enabled",
     "host_fetch",
+    "host_fetch_bytes",
     "host_sync_count",
     "reset_host_sync_count",
     "safe_point",
@@ -61,12 +63,18 @@ __all__ = [
 # depth-2 pipeline performs zero blocking transfers between safe points —
 # a nonzero delta on a no-action batch pinpoints a leaked sync.
 
-_sync_state = {"count": 0, "depth": 0}
+_sync_state = {"count": 0, "depth": 0, "bytes": 0}
 
 
 def host_sync_count() -> int:
     """Device->host fetches observed *outside* safe-point regions."""
     return _sync_state["count"]
+
+
+def host_fetch_bytes() -> int:
+    """Bytes of device arrays fetched to the host so far, inside safe points
+    or not (a running total: callers take differences)."""
+    return _sync_state["bytes"]
 
 
 def reset_host_sync_count() -> None:
@@ -89,9 +97,12 @@ def host_fetch(x):
 
     Fetching a ``jax.Array`` outside a :func:`safe_point` region counts as a
     blocking sync; host values (ints, floats, numpy) pass through uncounted.
+    Every fetched array's bytes are added to :func:`host_fetch_bytes`.
     """
-    if isinstance(x, jax.Array) and _sync_state["depth"] == 0:
-        _sync_state["count"] += 1
+    if isinstance(x, jax.Array):
+        _sync_state["bytes"] += x.nbytes
+        if _sync_state["depth"] == 0:
+            _sync_state["count"] += 1
     return np.asarray(x)
 
 

@@ -233,20 +233,22 @@ def make_shuffle_step(
                              shipped, by_class)
         return _pack_pending(started), start
 
-    def _start_local(tables, keys, vals, valid, bufs, part_loads):
+    # the mapped functions' names become the programs' names in a device
+    # trace: jit_shuffle_start, jit_shuffle_finish, jit_shuffle_step
+    def shuffle_start(tables, keys, vals, valid, bufs, part_loads):
         return _start_core(tables, keys, vals, valid, bufs, part_loads)
 
-    def _finish_local(pending):
+    def shuffle_finish(pending):
         res = ex.finish(PendingExchange(_unpack_pending(pending, fills)))
         rva, (rk, rv, rp) = res.unpack()
         return rk[None], rv[None], rva[None], rp[None]
 
-    def _local(tables, keys, vals, valid, part_loads):
+    def shuffle_step(tables, keys, vals, valid, part_loads):
         # the fused serial step's send buffers never cross the jit boundary,
         # so there is nothing to recycle — fresh transient buffers (bufs
         # None) keep the trace identical to the pre-reuse step
         pending, start = _start_core(tables, keys, vals, valid, None, part_loads)
-        rk, rv, rva, rp = _finish_local(pending)
+        rk, rv, rva, rp = shuffle_finish(pending)
         return (rk, rv, rva, rp, start.loads, start.hist_keys, start.hist_counts,
                 start.overflow, start.lane_overflow, start.shipped_rows,
                 start.shipped_rows_by_class)
@@ -259,18 +261,18 @@ def make_shuffle_step(
     )
     bufs_spec = (P(axis), (P(axis), P(axis), P(axis)))
     mapped = shard_map(
-        _local, mesh=mesh, in_specs=in_specs + (P(),),
+        shuffle_step, mesh=mesh, in_specs=in_specs + (P(),),
         out_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(axis), P(axis),
                    P(), P(), P(), P()),
         check_vma=False,
     )
     start_mapped = shard_map(
-        _start_local, mesh=mesh, in_specs=in_specs + (bufs_spec, P()),
+        shuffle_start, mesh=mesh, in_specs=in_specs + (bufs_spec, P()),
         out_specs=(P(axis), ShuffleStart(P(), P(axis), P(axis), P(), P(), P(), P())),
         check_vma=False,
     )
     finish_mapped = shard_map(
-        _finish_local, mesh=mesh, in_specs=(P(axis),),
+        shuffle_finish, mesh=mesh, in_specs=(P(axis),),
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
         check_vma=False,
     )
@@ -436,35 +438,37 @@ def make_migrate_step(
             by_class,
         )
 
-    def _start_local(new_tables, state_keys, state_vals, bufs):
+    # programs jit_migrate_start, jit_migrate_finish, jit_migrate_step in a
+    # device trace, apart from the shuffle's
+    def migrate_start(new_tables, state_keys, state_vals, bufs):
         return _start_core(new_tables, state_keys, state_vals, bufs)
 
-    def _finish_local(pending):
+    def migrate_finish(pending):
         res = ex.finish(PendingExchange(_unpack_pending(pending, fills)))
         rva, (rk, rv) = res.unpack()
         return rk[None], rv[None], rva[None]
 
-    def _local(new_tables, state_keys, state_vals):
+    def migrate_step(new_tables, state_keys, state_vals):
         pending, kk, vv, kva, moved, total, ov, lov, shipped, by = _start_core(
             new_tables, state_keys, state_vals, None
         )
-        rk, rv, rva = _finish_local(pending)
+        rk, rv, rva = migrate_finish(pending)
         return kk, vv, kva, rk, rv, rva, moved, total, ov, lov, shipped, by
 
     in_specs = ((P(), P(), P(), P()), P(axis), P(axis))
     bufs_spec = (P(axis), (P(axis), P(axis)))
     mapped = shard_map(
-        _local, mesh=mesh, in_specs=in_specs,
+        migrate_step, mesh=mesh, in_specs=in_specs,
         out_specs=(P(axis),) * 6 + (P(), P(), P(), P(), P(), P()),
         check_vma=False,
     )
     start_mapped = shard_map(
-        _start_local, mesh=mesh, in_specs=in_specs + (bufs_spec,),
+        migrate_start, mesh=mesh, in_specs=in_specs + (bufs_spec,),
         out_specs=(P(axis),) * 4 + (P(), P(), P(), P(), P(), P()),
         check_vma=False,
     )
     finish_mapped = shard_map(
-        _finish_local, mesh=mesh, in_specs=(P(axis),),
+        migrate_finish, mesh=mesh, in_specs=(P(axis),),
         out_specs=(P(axis), P(axis), P(axis)),
         check_vma=False,
     )

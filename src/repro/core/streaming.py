@@ -88,6 +88,34 @@ through :func:`repro.compat.host_fetch` inside a
 decision section it feeds.  Between safe points the driver performs no
 blocking transfers; ``compat.host_sync_count()`` stays flat across
 steady-state batches (the bench gate ``fig6/host_syncs_per_batch``).
+
+**Spans**: each batch and each phase of it is a named host span
+(``jax.profiler.TraceAnnotation``, names in :data:`SPANS`).  While no
+profiler trace runs a span costs about a microsecond of host CPU; under a
+trace, the spans land on the host plane on the device ops' clock, so every
+stretch in which the device waits on the host belongs to one phase.  ``stream.batch`` covers a
+batch and carries its index; its children cover it with no holes:
+
+* ``stream.feed`` — pad and cast the batch, build the step, put the keys,
+  values and valid flags, and enqueue the start (or take the staged one);
+  also the depth-2 lookahead's puts and start;
+* ``stream.count_sync`` — wait for the start phase's loads;
+* ``dr.observe`` — exchange stats, telemetry records, the DRW histogram
+  fetch and ``DRMaster.observe``;
+* ``dr.decide`` — ``Telemetry.snapshot`` and ``DRMaster.evaluate`` (KIP
+  re-plan and the policy stack);
+* ``stream.drain`` — complete the in-flight finish + merge, wherever that
+  happens;
+* ``dr.migrate`` — a migration, split into ``dr.migrate.fetch`` (the full
+  state to the host), ``dr.migrate.plan`` (``plan_migration`` and the lane
+  size) and ``dr.migrate.start`` (the step, its enqueue, the control
+  fetches);
+* ``stream.account`` — the rest: migration telemetry and ``BatchMetrics``.
+
+``dr.resize``, ``dr.switch``, ``dr.lane`` (quarantine, evict) and
+``dr.recover`` (re-admission, and recovery from a lost worker) each wrap
+their action.  ``BatchMetrics.put_bytes`` / ``fetch_bytes`` count the bytes
+each batch moved host->device and device->host, from shapes alone.
 """
 from __future__ import annotations
 
@@ -101,7 +129,15 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import host_fetch, native_ragged, overlap_enabled, safe_point
+from jax.profiler import TraceAnnotation
+
+from repro.compat import (
+    host_fetch,
+    host_fetch_bytes,
+    native_ragged,
+    overlap_enabled,
+    safe_point,
+)
 from repro.control import (
     Evict,
     NoOp,
@@ -143,7 +179,31 @@ from repro.exchange.plane import route_path
 from repro.exchange.spec import DISTANCE_CLASSES
 from repro.launch.mesh import make_mesh
 
-__all__ = ["StreamingJob", "BatchMetrics", "RecoveryStats"]
+__all__ = ["StreamingJob", "BatchMetrics", "RecoveryStats", "SPANS"]
+
+#: every host span the job writes (see the module docstring)
+SPANS = (
+    "stream.batch",
+    "stream.feed",
+    "stream.count_sync",
+    "dr.observe",
+    "dr.decide",
+    "stream.drain",
+    "dr.migrate",
+    "dr.migrate.fetch",
+    "dr.migrate.plan",
+    "dr.migrate.start",
+    "stream.account",
+    "dr.resize",
+    "dr.switch",
+    "dr.lane",
+    "dr.recover",
+)
+
+
+def _span(name: str, **args) -> TraceAnnotation:
+    assert name in SPANS, name
+    return TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass
@@ -186,6 +246,9 @@ class BatchMetrics:
                                 # "ragged/masked-dense" elsewhere)
     route_path: str = ""        # route -> bucketize implementation
                                 # (exchange.plane.route_path)
+    put_bytes: int = 0          # bytes this batch put host -> device
+                                # (keys, values, valid flags, re-laid state)
+    fetch_bytes: int = 0        # bytes this batch fetched device -> host
 
 
 @dataclasses.dataclass
@@ -274,6 +337,7 @@ class StreamingJob:
         self.drm = DRMaster(part, cfg, exchange_backend=self.exchange_backend,
                             exchange_topology=topology)
         self.telemetry = Telemetry("stream")
+        self._put_bytes = 0  # running total of host -> device bytes (_shard)
         self._shuffle = None
         self._shuffle_sig = None  # (capacity, num_partitions) the step was built for
         self._shuffle_spec: ExchangeSpec | None = None  # for exchange-row accounting
@@ -316,7 +380,10 @@ class StreamingJob:
 
     def _shard(self, x) -> jax.Array:
         """Place ``x`` split over the workers along its first axis (stacked
-        ``[W, ...]`` state, or a batch's ``[W * n]`` records)."""
+        ``[W, ...]`` state, or a batch's ``[W * n]`` records); a host array's
+        bytes count as put."""
+        if not isinstance(x, jax.Array):
+            self._put_bytes += np.asarray(x).nbytes
         return jax.device_put(x, NamedSharding(self.mesh, P("data")))
 
     # -- keyed state access (drains any in-flight exchange first) ----------
@@ -410,18 +477,20 @@ class StreamingJob:
         un-hidden ship wall (plus whatever host wall it did hide)."""
         if self._inflight is None:
             return
-        t = time.perf_counter()
-        hidden = None if self._hidden_since is None else t - self._hidden_since
-        self._hidden_since = None
-        self._consume_inflight()
-        jax.block_until_ready(self._sk)
-        self.telemetry.record_exchange(ExchangeStats(
-            rows=0,
-            ship_wall_s=time.perf_counter() - t,
-            hidden_wall_s=hidden,
-        ))
-        with safe_point():  # a drain IS a safe point: the fetch is sanctioned
-            self._last_state_rows = int(host_fetch(jnp.sum(self._sk != KEY_SENTINEL)))
+        with _span("stream.drain"):
+            t = time.perf_counter()
+            hidden = None if self._hidden_since is None else t - self._hidden_since
+            self._hidden_since = None
+            self._consume_inflight()
+            jax.block_until_ready(self._sk)
+            self.telemetry.record_exchange(ExchangeStats(
+                rows=0,
+                ship_wall_s=time.perf_counter() - t,
+                hidden_wall_s=hidden,
+            ))
+            with safe_point():  # a drain IS a safe point: the fetch is sanctioned
+                self._last_state_rows = int(
+                    host_fetch(jnp.sum(self._sk != KEY_SENTINEL)))
 
     # ------------------------------------------------------------------
     def _build(self, local_n: int):
@@ -540,149 +609,164 @@ class StreamingJob:
 
     def _process_batch_inner(self, keys: np.ndarray,
                              values: np.ndarray | None = None) -> BatchMetrics:
-        t0 = time.perf_counter()
-        raw_keys = keys
-        has_values = values is not None
-        n = len(keys)
-        w = self.num_workers
-        local_n = int(np.ceil(n / w))
-        pad = local_n * w - n
-        keys = np.concatenate([keys, np.full(pad, KEY_SENTINEL, np.int64)]).astype(np.int32)
-        if values is None:
-            values = np.ones((len(keys), self.payload_dim), np.float32)
-        else:
-            values = np.concatenate([values, np.zeros((pad,) + values.shape[1:], np.float32)],
-                                    dtype=np.float32)
-        valid = keys != KEY_SENTINEL
-        self._build(local_n * w)
-        batch_backend = self.exchange_backend.name  # the transport this batch rode
-        overlap = self._overlap_active()
-        pipelined = False
+        put0, fetch0 = self._put_bytes, host_fetch_bytes()
+        with _span("stream.batch", batch=len(self.metrics)):
+            m = self._batch(keys, values)
+            m.put_bytes = self._put_bytes - put0
+            m.fetch_bytes = host_fetch_bytes() - fetch0
+        return m
 
-        t_ex = time.perf_counter()
-        if overlap:
-            # split-phase pipeline: enqueue this batch's start (unless the
-            # depth-2 lookahead already staged it last batch), then the
-            # previous batch's ship + merge behind it, and block only on the
-            # start outputs — devices drain their queue in order, so the
-            # loads sync below waits for the count phase, not the ship,
-            # which runs while the host works through the decision section
-            shuffle = self._shuffle
-            staged = self._take_staged(raw_keys, has_values)
-            if staged is not None:
-                pending, res = staged
-                pipelined = True
+    def _batch(self, keys: np.ndarray, values: np.ndarray | None) -> BatchMetrics:
+        t0 = time.perf_counter()
+        w = self.num_workers
+        overlap = self._overlap_active()
+        with _span("stream.feed"):
+            raw_keys = keys
+            has_values = values is not None
+            n = len(keys)
+            local_n = int(np.ceil(n / w))
+            pad = local_n * w - n
+            keys = np.concatenate([keys, np.full(pad, KEY_SENTINEL, np.int64)]).astype(np.int32)
+            if values is None:
+                values = np.ones((len(keys), self.payload_dim), np.float32)
             else:
-                pending, res = shuffle.start(
+                values = np.concatenate(
+                    [values, np.zeros((pad,) + values.shape[1:], np.float32)],
+                    dtype=np.float32)
+            valid = keys != KEY_SENTINEL
+            self._build(local_n * w)
+            batch_backend = self.exchange_backend.name  # the transport this batch rode
+            pipelined = False
+
+            t_ex = time.perf_counter()
+            if overlap:
+                # split-phase pipeline: enqueue this batch's start (unless the
+                # depth-2 lookahead already staged it last batch), then the
+                # previous batch's ship + merge behind it, and block only on
+                # the start outputs — devices drain their queue in order, so
+                # the loads sync below waits for the count phase, not the
+                # ship, which runs while the host works through the decision
+                # section
+                shuffle = self._shuffle
+                staged = self._take_staged(raw_keys, has_values)
+                if staged is not None:
+                    pending, res = staged
+                    pipelined = True
+                else:
+                    pending, res = shuffle.start(
+                        self.drm.partitioner.tables(), self._shard(keys),
+                        self._shard(values), self._shard(valid),
+                        self._part_loads,
+                    )
+                self._consume_inflight()
+
+                def _fin_shuffle(fin=shuffle.finish, pending=pending):
+                    rk, rv, rva, _rp = fin(pending)
+                    self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
+
+                self._inflight = _fin_shuffle
+            else:
+                self._discard_staged()  # overlap turned off mid-stream: re-route
+                self._drain_inflight()
+                res = self._shuffle(
                     self.drm.partitioner.tables(), self._shard(keys),
                     self._shard(values), self._shard(valid),
                     self._part_loads,
                 )
-            self._consume_inflight()
-
-            def _fin_shuffle(fin=shuffle.finish, pending=pending):
-                rk, rv, rva, _rp = fin(pending)
-                self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
-
-            self._inflight = _fin_shuffle
+                # stateful reduce: fold received records into per-worker state
+                self._sk, self._sv = self._merge(
+                    self._sk, self._sv, res.keys, res.values, res.valid
+                )
+        with _span("stream.count_sync"):
+            # overlapped, this forces the start phase only; serially, the
+            # batch's whole device work
             with safe_point():
-                loads = host_fetch(res.loads)  # forces the start phase only
+                loads = host_fetch(res.loads)
             exchange_wall = time.perf_counter() - t_ex
-            count_wall = exchange_wall
-        else:
-            self._discard_staged()  # overlap turned off mid-stream: re-route
-            if self._inflight is not None:
-                self._drain_inflight()
-            res = self._shuffle(
-                self.drm.partitioner.tables(), self._shard(keys),
-                self._shard(values), self._shard(valid),
-                self._part_loads,
-            )
-            # stateful reduce: fold received records into per-worker state
-            self._sk, self._sv = self._merge(
-                self._sk, self._sv, res.keys, res.values, res.valid
-            )
-            with safe_point():
-                loads = host_fetch(res.loads)  # forces the batch's device work
-            exchange_wall = time.perf_counter() - t_ex
-            count_wall = None
-        # the route reads the *previous* batch's measured loads (identical
-        # in serial / depth-1 / depth-2: all route batch N+1 on batch N's
-        # vector, set here before any lookahead stages)
-        if self.drm.config.split_least_load:
-            self._part_loads = jnp.asarray(loads, jnp.float32)
+            count_wall = exchange_wall if overlap else None
+            # the route reads the *previous* batch's measured loads (identical
+            # in serial / depth-1 / depth-2: all route batch N+1 on batch N's
+            # vector, set here before any lookahead stages)
+            if self.drm.config.split_least_load:
+                self._part_loads = jnp.asarray(loads, jnp.float32)
         # depth-2: enqueue the lookahead batch's start now, behind this
         # batch's in-flight ship — its route + bucketize + count phase run
         # on the device while the host works through the decision section
         if self._next_batch is not None and self._depth2_active():
-            self._stage_next(self._next_batch)
-        # everything the decision section reads below comes out of the
-        # start phase (res is ShuffleStart when overlapped, ShuffleResult
-        # serially — the control fields are shared)
-        self._hidden_since = time.perf_counter() if overlap else None
+            with _span("stream.feed"):
+                self._stage_next(self._next_batch)
 
-        # telemetry: signals gathered during normal work (no extra passes).
-        # shipped is the backend's measured traffic (per worker, averaged),
-        # padded what the spec provisioned, occupied the rows actually live
-        # in the lanes (backend-independent — the BackendPolicy's signal;
-        # under dense shipped == padded while occupied tracks the real load).
-        with safe_point():
-            stats = shuffle_stats(
-                res, self._shuffle_spec, w,
-                wall_s=exchange_wall,
-                count_wall_s=count_wall,
-                backend=batch_backend,
-                # per-replica routing of the split keys (host twin of the
-                # fused kernels' pick — exact, no extra device pass); only
-                # computed while splits are installed, and only for the
-                # stateless pick — the least-load tiebreak reads a load
-                # vector the host twin doesn't see
-                replica_rows=(split_replica_rows(self.drm.partitioner, keys, w, valid)
-                              if self.drm.split_keys
-                              and not self.drm.config.split_least_load else None),
+        with _span("dr.observe"):
+            # everything the decision section reads below comes out of the
+            # start phase (res is ShuffleStart when overlapped, ShuffleResult
+            # serially — the control fields are shared)
+            self._hidden_since = time.perf_counter() if overlap else None
+
+            # telemetry: signals gathered during normal work (no extra
+            # passes).  shipped is the backend's measured traffic (per
+            # worker, averaged), padded what the spec provisioned, occupied
+            # the rows actually live in the lanes (backend-independent — the
+            # BackendPolicy's signal; under dense shipped == padded while
+            # occupied tracks the real load).
+            with safe_point():
+                stats = shuffle_stats(
+                    res, self._shuffle_spec, w,
+                    wall_s=exchange_wall,
+                    count_wall_s=count_wall,
+                    backend=batch_backend,
+                    # per-replica routing of the split keys (host twin of the
+                    # fused kernels' pick — exact, no extra device pass); only
+                    # computed while splits are installed, and only for the
+                    # stateless pick — the least-load tiebreak reads a load
+                    # vector the host twin doesn't see
+                    replica_rows=(split_replica_rows(self.drm.partitioner, keys, w, valid)
+                                  if self.drm.split_keys
+                                  and not self.drm.config.split_least_load else None),
+                )
+                # every fetch below reads a start-phase output the loads sync
+                # already forced — no new device work blocks here
+                shuffle_shipped = int(host_fetch(stats.rows))
+                overflow_i = int(host_fetch(res.overflow))
+                self.telemetry.record_exchange(stats)
+                self.telemetry.record_overflow(shuffle=overflow_i)
+                self.telemetry.record_batch(float(loads.sum()))
+                # fault evidence: drain the seam's per-lane report (straggle
+                # seconds, retries) into ordinary telemetry — the lane-health
+                # layer's input.  Plans are keyed by original lane id; the
+                # report re-maps onto current positions.  A plain transport
+                # has no report; a never-firing plan drains empty — both
+                # leave the telemetry bit-identical to a no-faults run.
+                drain = getattr(self.exchange_backend, "drain_report", None)
+                if drain is not None:
+                    for orig, rec in drain().items():
+                        if orig in self._lane_ids:
+                            self.telemetry.record_fault(
+                                self._lane_ids.index(orig),
+                                straggle_s=rec.get("straggle_s", 0.0),
+                                retries=rec.get("retries", 0))
+
+                # DRM: ingest DRW histograms at the safe point
+                self.drm.observe(host_fetch(res.hist_keys), host_fetch(res.hist_counts),
+                                 total_records=float(loads.sum()))
+        with _span("dr.decide"):
+            # the policy stack, at the safe point
+            at_checkpoint = (len(self.metrics) + 1) % self.checkpoint_interval == 0
+            requested = None
+            if at_checkpoint and self._pending_resize is not None:
+                requested = self._pending_resize
+                self._pending_resize = None
+            signals = self.telemetry.snapshot(
+                loads=loads,
+                num_workers=w,
+                # reading the live count would sync the in-flight merge chain
+                # — overlapped batches report the count as of the last drain
+                # (no policy keys on exact state rows; the migration planner
+                # reads the real keys after the pre-action drain below)
+                state_rows=self._last_state_rows if overlap else self._state_rows(),
+                at_safe_point=at_checkpoint,
             )
-            # every fetch below reads a start-phase output the loads sync
-            # already forced — no new device work blocks here
-            shuffle_shipped = int(host_fetch(stats.rows))
-            overflow_i = int(host_fetch(res.overflow))
-            self.telemetry.record_exchange(stats)
-            self.telemetry.record_overflow(shuffle=overflow_i)
-            self.telemetry.record_batch(float(loads.sum()))
-            # fault evidence: drain the seam's per-lane report (straggle
-            # seconds, retries) into ordinary telemetry — the lane-health
-            # layer's input.  Plans are keyed by original lane id; the
-            # report re-maps onto current positions.  A plain transport has
-            # no report; a never-firing plan drains empty — both leave the
-            # telemetry bit-identical to a no-faults run.
-            drain = getattr(self.exchange_backend, "drain_report", None)
-            if drain is not None:
-                for orig, rec in drain().items():
-                    if orig in self._lane_ids:
-                        self.telemetry.record_fault(
-                            self._lane_ids.index(orig),
-                            straggle_s=rec.get("straggle_s", 0.0),
-                            retries=rec.get("retries", 0))
-
-            # DRM: ingest DRW histograms + run the policy stack at the safe point
-            self.drm.observe(host_fetch(res.hist_keys), host_fetch(res.hist_counts),
-                             total_records=float(loads.sum()))
-        at_checkpoint = (len(self.metrics) + 1) % self.checkpoint_interval == 0
-        requested = None
-        if at_checkpoint and self._pending_resize is not None:
-            requested = self._pending_resize
-            self._pending_resize = None
-        signals = self.telemetry.snapshot(
-            loads=loads,
-            num_workers=w,
-            # reading the live count would sync the in-flight merge chain —
-            # overlapped batches report the count as of the last drain (no
-            # policy keys on exact state rows; the migration planner reads
-            # the real keys after the pre-action drain below)
-            state_rows=self._last_state_rows if overlap else self._state_rows(),
-            at_safe_point=at_checkpoint,
-        )
-        action = self.drm.evaluate(signals, requested_resize=requested,
-                                   policies_enabled=self.dr_enabled)
+            action = self.drm.evaluate(signals, requested_resize=requested,
+                                       policies_enabled=self.dr_enabled)
 
         # execute the action (state only moves here, at the safe point).
         # Any taken action drains *both* in-flight stages first: the
@@ -730,73 +814,75 @@ class StreamingJob:
             self._apply_recover()
         # a taken Split needs no execution here: the DRM stamped the replica
         # table and the very next batch's route kernels fan the key out
-        with safe_point():  # migrations only fire at safe points
-            if mig_rows:
-                self.telemetry.record_exchange(migrate_stats(
-                    shipped_rows=mig_shipped * w,  # helper re-divides per worker
-                    buffer_rows=mig_rows,
-                    moved_rows=mig_moved,
-                    overflow=mig_overflow,
-                    num_workers=w,
-                    shipped_rows_by_class=mig_by_class,
+        with _span("stream.account"):
+            with safe_point():  # migrations only fire at safe points
+                if mig_rows:
+                    self.telemetry.record_exchange(migrate_stats(
+                        shipped_rows=mig_shipped * w,  # helper re-divides per worker
+                        buffer_rows=mig_rows,
+                        moved_rows=mig_moved,
+                        overflow=mig_overflow,
+                        num_workers=w,
+                        shipped_rows_by_class=mig_by_class,
+                    ))
+                    self.telemetry.record_overflow(migration=mig_overflow)
+
+                # per-class shipped rows (shuffle + migration, per worker) for
+                # the locality benches; zeros when the job carries no topology
+                by_class = np.zeros(DISTANCE_CLASSES, np.int64)
+                if stats.rows_by_class is not None:
+                    by_class += np.asarray(host_fetch(stats.rows_by_class), np.int64)
+                if mig_by_class is not None:
+                    by_class += np.asarray(mig_by_class, np.int64) // w
+
+            m = BatchMetrics(
+                batch=len(self.metrics),
+                imbalance=signals.imbalance,
+                worker_imbalance=signals.worker_imbalance,
+                # a backend switch is taken but moves no state — it must not
+                # count as a repartition (consumers divide migration rows by
+                # this flag's sum)
+                repartitioned=action.taken and action.moves_state,
+                relative_migration=rel_mig,
+                overflow=overflow_i + mig_overflow,
+                # overlapped: the count as of the last drain (exact state rows
+                # would sync the in-flight merge; serial keeps today's numbers)
+                state_rows=(self._last_state_rows if overlap else
+                            (signals.state_rows if isinstance(action, NoOp)
+                             else self._state_rows())),
+                wall_time_s=time.perf_counter() - t0,
+                reason=action.reason,
+                migration_rows=mig_rows,
+                resized=isinstance(action, Resize),
+                num_partitions=self.num_partitions,
+                migration_plan_rows=plan_rows,
+                action=action.kind,
+                shipped_rows=shuffle_shipped + mig_shipped,
+                padded_rows=self._shuffle_spec.rows + mig_rows,
+                backend=batch_backend,
+                exchange_wall_s=exchange_wall,
+                overlapped=overlap,
+                pipelined=pipelined,
+                overlap_fraction=signals.overlap_fraction,
+                split_keys=len(self.drm.split_keys),
+                shipped_rows_by_class=tuple(int(x) for x in by_class),
+                lanes=self.num_workers,
+                transport=(batch_backend if batch_backend != "ragged" else
+                           "ragged/native" if native_ragged(self.mesh)
+                           else "ragged/masked-dense"),
+                route_path=self._route_path,
+            )
+            # the host wall since the count sync ran under this batch's (or
+            # the migration's) in-flight ship — that's the latency the overlap
+            # hid.  Recorded at batch end, so it lands in the *next* telemetry
+            # window.
+            if self._inflight is not None and self._hidden_since is not None:
+                self.telemetry.record_exchange(ExchangeStats(
+                    rows=0,
+                    hidden_wall_s=time.perf_counter() - self._hidden_since,
                 ))
-                self.telemetry.record_overflow(migration=mig_overflow)
-
-            # per-class shipped rows (shuffle + migration, per worker) for
-            # the locality benches; zeros when the job carries no topology
-            by_class = np.zeros(DISTANCE_CLASSES, np.int64)
-            if stats.rows_by_class is not None:
-                by_class += np.asarray(host_fetch(stats.rows_by_class), np.int64)
-            if mig_by_class is not None:
-                by_class += np.asarray(mig_by_class, np.int64) // w
-
-        m = BatchMetrics(
-            batch=len(self.metrics),
-            imbalance=signals.imbalance,
-            worker_imbalance=signals.worker_imbalance,
-            # a backend switch is taken but moves no state — it must not
-            # count as a repartition (consumers divide migration rows by
-            # this flag's sum)
-            repartitioned=action.taken and action.moves_state,
-            relative_migration=rel_mig,
-            overflow=overflow_i + mig_overflow,
-            # overlapped: the count as of the last drain (exact state rows
-            # would sync the in-flight merge; serial keeps today's numbers)
-            state_rows=(self._last_state_rows if overlap else
-                        (signals.state_rows if isinstance(action, NoOp)
-                         else self._state_rows())),
-            wall_time_s=time.perf_counter() - t0,
-            reason=action.reason,
-            migration_rows=mig_rows,
-            resized=isinstance(action, Resize),
-            num_partitions=self.num_partitions,
-            migration_plan_rows=plan_rows,
-            action=action.kind,
-            shipped_rows=shuffle_shipped + mig_shipped,
-            padded_rows=self._shuffle_spec.rows + mig_rows,
-            backend=batch_backend,
-            exchange_wall_s=exchange_wall,
-            overlapped=overlap,
-            pipelined=pipelined,
-            overlap_fraction=signals.overlap_fraction,
-            split_keys=len(self.drm.split_keys),
-            shipped_rows_by_class=tuple(int(x) for x in by_class),
-            lanes=self.num_workers,
-            transport=(batch_backend if batch_backend != "ragged" else
-                       "ragged/native" if native_ragged(self.mesh)
-                       else "ragged/masked-dense"),
-            route_path=self._route_path,
-        )
-        # the host wall since the count sync ran under this batch's (or the
-        # migration's) in-flight ship — that's the latency the overlap hid.
-        # Recorded at batch end, so it lands in the *next* telemetry window.
-        if self._inflight is not None and self._hidden_since is not None:
-            self.telemetry.record_exchange(ExchangeStats(
-                rows=0,
-                hidden_wall_s=time.perf_counter() - self._hidden_since,
-            ))
-        self._hidden_since = None
-        self.metrics.append(m)
+            self._hidden_since = None
+            self.metrics.append(m)
         return m
 
     def _state_rows(self) -> int:
@@ -830,16 +916,17 @@ class StreamingJob:
         transport (the same rebuild contract as an elastic resize).  A
         fault seam stays armed across the switch: the wrapper re-points
         its inner transport instead of being replaced."""
-        new = self.drm.exchange_backend
-        if (isinstance(self.exchange_backend, FaultyBackend)
-                and not isinstance(new, FaultyBackend)):
-            self.exchange_backend.inner = resolve_backend(new)
-            self.drm.exchange_backend = self.exchange_backend
-        else:
-            self.exchange_backend = new
-        self._shuffle = None
-        self._shuffle_sig = None
-        self._migrate_steps.clear()
+        with _span("dr.switch"):
+            new = self.drm.exchange_backend
+            if (isinstance(self.exchange_backend, FaultyBackend)
+                    and not isinstance(new, FaultyBackend)):
+                self.exchange_backend.inner = resolve_backend(new)
+                self.drm.exchange_backend = self.exchange_backend
+            else:
+                self.exchange_backend = new
+            self._shuffle = None
+            self._shuffle_sig = None
+            self._migrate_steps.clear()
 
     # -- failure domains: lane removal / re-admission / recovery ---------
     def _set_workers(self, devices: list) -> None:
@@ -864,38 +951,40 @@ class StreamingJob:
         fetch the state (the pre-action drain already completed), remove
         the lane from the collective, and fold its rows onto the
         survivors."""
-        with safe_point():
-            sk = np.asarray(host_fetch(self._sk))
-            sv = np.asarray(host_fetch(self._sv))
-        devices = list(self.mesh.devices.flat)
-        device = devices.pop(lane)
-        orig = self._lane_ids.pop(lane)
-        if park:
-            self._parked.append((orig, device))
-        backend = self.exchange_backend
-        if isinstance(backend, FaultyBackend):
-            (backend.note_quarantined if park else backend.note_evicted)(orig)
-        self._set_workers(devices)
-        self._adopt_state(sk, sv)
+        with _span("dr.lane"):
+            with safe_point():
+                sk = np.asarray(host_fetch(self._sk))
+                sv = np.asarray(host_fetch(self._sv))
+            devices = list(self.mesh.devices.flat)
+            device = devices.pop(lane)
+            orig = self._lane_ids.pop(lane)
+            if park:
+                self._parked.append((orig, device))
+            backend = self.exchange_backend
+            if isinstance(backend, FaultyBackend):
+                (backend.note_quarantined if park else backend.note_evicted)(orig)
+            self._set_workers(devices)
+            self._adopt_state(sk, sv)
 
     def _apply_recover(self) -> None:
         """Execute a Recover at a safe point: re-admit the oldest parked
         device and spread the state back over the grown collective."""
-        if not self._parked:
-            # a restored ledger can outlive the physical parked list (the
-            # snapshot predated the quarantine): reconcile and decline
-            self.drm.quarantined.clear()
-            return
-        with safe_point():
-            sk = np.asarray(host_fetch(self._sk))
-            sv = np.asarray(host_fetch(self._sv))
-        orig, device = self._parked.pop(0)
-        self._lane_ids.append(orig)
-        backend = self.exchange_backend
-        if isinstance(backend, FaultyBackend):
-            backend.note_recovered(orig)
-        self._set_workers(list(self.mesh.devices.flat) + [device])
-        self._adopt_state(sk, sv)
+        with _span("dr.recover"):
+            if not self._parked:
+                # a restored ledger can outlive the physical parked list (the
+                # snapshot predated the quarantine): reconcile and decline
+                self.drm.quarantined.clear()
+                return
+            with safe_point():
+                sk = np.asarray(host_fetch(self._sk))
+                sv = np.asarray(host_fetch(self._sv))
+            orig, device = self._parked.pop(0)
+            self._lane_ids.append(orig)
+            backend = self.exchange_backend
+            if isinstance(backend, FaultyBackend):
+                backend.note_recovered(orig)
+            self._set_workers(list(self.mesh.devices.flat) + [device])
+            self._adopt_state(sk, sv)
 
     def _adopt_state(self, sk: np.ndarray, sv: np.ndarray) -> None:
         """Redistribute host-side state tables onto the *current* worker
@@ -936,59 +1025,61 @@ class StreamingJob:
         restarts in place instead), restore the last auto-snapshot onto
         the surviving topology, and record the forced eviction.  The
         caller replays the gap and retries the lost batch."""
-        try:
-            self._drain_inflight()  # quiesce survivors (state is discarded
-            #                         below, but the device queue must empty)
-        except (WorkerLostError, TransientExchangeError):
-            # only the fault seam's own errors mean "that stage is gone";
-            # a compile or runtime error of the device work propagates
-            self._inflight = None
-            self._hidden_since = None
-        self._discard_staged()
-        backend = self.exchange_backend
-        kind = "evict"
-        if self.num_workers > 1 and loss.lane in self._lane_ids:
-            lane = self._lane_ids.index(loss.lane)
-            devices = list(self.mesh.devices.flat)
-            devices.pop(lane)
-            self._lane_ids.pop(lane)
-            self._set_workers(devices)
-            if isinstance(backend, FaultyBackend):
-                backend.note_evicted(loss.lane)
-        else:
-            kind = "restart"  # single worker (or already-removed lane):
-            #                   restore + replay in place.  The restarted
-            #                   lane stays fault-eligible — only the
-            #                   standing death clears
-            if isinstance(backend, FaultyBackend):
-                backend.note_restarted(loss.lane)
-        snap = self._auto_snap
-        assert snap is not None, "recovery requires snapshot_interval > 0"
-        self.restore(snap, _keep_recovery_log=True)
-        # the restored DRM predates the loss: log the forced eviction so
-        # the decision trail carries the failure, and reconcile its
-        # quarantine ledger with the physically parked devices
-        self.drm.note_lost(loss.lane, reason=str(loss))
-        while len(self.drm.quarantined) > len(self._parked):
-            self.drm.quarantined.pop()
-        while len(self.drm.quarantined) < len(self._parked):
-            self.drm.quarantined.append((-1, self.drm.batches_seen))
-        return kind
+        with _span("dr.recover"):
+            try:
+                self._drain_inflight()  # quiesce survivors (state is discarded
+                #                         below, but the device queue must empty)
+            except (WorkerLostError, TransientExchangeError):
+                # only the fault seam's own errors mean "that stage is gone";
+                # a compile or runtime error of the device work propagates
+                self._inflight = None
+                self._hidden_since = None
+            self._discard_staged()
+            backend = self.exchange_backend
+            kind = "evict"
+            if self.num_workers > 1 and loss.lane in self._lane_ids:
+                lane = self._lane_ids.index(loss.lane)
+                devices = list(self.mesh.devices.flat)
+                devices.pop(lane)
+                self._lane_ids.pop(lane)
+                self._set_workers(devices)
+                if isinstance(backend, FaultyBackend):
+                    backend.note_evicted(loss.lane)
+            else:
+                kind = "restart"  # single worker (or already-removed lane):
+                #                   restore + replay in place.  The restarted
+                #                   lane stays fault-eligible — only the
+                #                   standing death clears
+                if isinstance(backend, FaultyBackend):
+                    backend.note_restarted(loss.lane)
+            snap = self._auto_snap
+            assert snap is not None, "recovery requires snapshot_interval > 0"
+            self.restore(snap, _keep_recovery_log=True)
+            # the restored DRM predates the loss: log the forced eviction so
+            # the decision trail carries the failure, and reconcile its
+            # quarantine ledger with the physically parked devices
+            self.drm.note_lost(loss.lane, reason=str(loss))
+            while len(self.drm.quarantined) > len(self._parked):
+                self.drm.quarantined.pop()
+            while len(self.drm.quarantined) < len(self._parked):
+                self.drm.quarantined.append((-1, self.drm.batches_seen))
+            return kind
 
     def _apply_resize(self, n: int):
         """Execute a resize at a safe point: re-plan cross-size, migrate
         state through freshly sized exchange lanes, rebuild the step cache."""
-        old = self.drm.partitioner
-        self.drm.replan_resize(n)
-        stats = self._migrate_state(old)
-        self.num_partitions = n
-        # the shuffle step's lane count / loads vector followed the old
-        # topology; _build re-derives the spec on the next batch, and the
-        # least-load vector is re-seeded at the new width
-        self._shuffle = None
-        self._shuffle_sig = None
-        self._part_loads = None
-        return stats
+        with _span("dr.resize"):
+            old = self.drm.partitioner
+            self.drm.replan_resize(n)
+            stats = self._migrate_state(old)
+            self.num_partitions = n
+            # the shuffle step's lane count / loads vector followed the old
+            # topology; _build re-derives the spec on the next batch, and the
+            # least-load vector is re-seeded at the new width
+            self._shuffle = None
+            self._shuffle_sig = None
+            self._part_loads = None
+            return stats
 
     def _migrate_state(self, old_part: Partitioner, *,
                        full_lanes: bool = False):
@@ -1011,14 +1102,23 @@ class StreamingJob:
         ships every one of them back to its key's home — undersized lanes
         would silently drop the partials being merged.
         """
-        with safe_point():  # migrations are safe points: the plan reads state
-            sk = host_fetch(self.state_keys).reshape(-1)
-        live = sk[sk != KEY_SENTINEL].astype(np.int64)
-        plan = plan_migration(old_part, self.drm.partitioner, live)
-        if full_lanes or self.drm.split_keys:
-            plan_rows = self.state_capacity
-        else:
-            plan_rows = migration_capacity(plan, num_workers=self.num_workers)
+        with _span("dr.migrate"):
+            with _span("dr.migrate.fetch"):
+                with safe_point():  # migrations are safe points: the plan reads state
+                    sk = host_fetch(self.state_keys).reshape(-1)
+            with _span("dr.migrate.plan"):
+                live = sk[sk != KEY_SENTINEL].astype(np.int64)
+                plan = plan_migration(old_part, self.drm.partitioner, live)
+                if full_lanes or self.drm.split_keys:
+                    plan_rows = self.state_capacity
+                else:
+                    plan_rows = migration_capacity(plan, num_workers=self.num_workers)
+            with _span("dr.migrate.start"):
+                return self._start_migration(plan_rows)
+
+    def _start_migration(self, plan_rows: int):
+        """Enqueue the migrate step sized for ``plan_rows`` and read its
+        control outputs (``_migrate_state``'s return value)."""
         migrate, lane_cap = self._migrate_step(plan_rows)
         tables = self.drm.partitioner.tables()
         if self._overlap_active():

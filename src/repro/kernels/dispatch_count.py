@@ -97,5 +97,6 @@ def dispatch_count(
             jax.ShapeDtypeStruct((np_, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="dispatch_count",  # the op's name in a device trace
     )(dest2d, valid2d)
     return slot.reshape(-1)[:n], counts[:num_parts, 0]

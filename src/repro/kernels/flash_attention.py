@@ -107,4 +107,5 @@ def flash_attention_tpu(
             pltpu.VMEM((p, bq), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_tpu",  # the op's name in a device trace
     )(q, k, v)

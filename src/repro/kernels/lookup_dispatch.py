@@ -120,5 +120,6 @@ def lookup_dispatch(
             jax.ShapeDtypeStruct((lp, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="lookup_dispatch",  # the op's name in a device trace
     )(keys2d, valid2d, *tables)
     return part.reshape(-1)[:n], slot.reshape(-1)[:n], counts[:num_lanes, 0]

@@ -155,5 +155,6 @@ def partition_apply(
         out_specs=row_spec(),
         out_shape=jax.ShapeDtypeStruct(keys2d.shape, jnp.int32),
         interpret=interpret,
+        name="partition_apply",  # the op's name in a device trace
     )(keys2d, hk, hp, host)
     return out.reshape(-1)[:n]

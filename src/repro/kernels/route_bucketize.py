@@ -197,6 +197,7 @@ def route_bucketize(
             jax.ShapeDtypeStruct((lp, 1), jnp.int32),
         ] + [buf] * 5 + [jax.ShapeDtypeStruct((d, lp, cap_p), jnp.float32)],
         interpret=interpret,
+        name="route_bucketize",  # the op's name in a device trace
     )(keys2d, valid2d, vals3d, *tables)
     part, slot, counts, *bufs = out
     bufs = [b[:num_lanes] for b in bufs[:5]] + [bufs[5][:, :num_lanes]]

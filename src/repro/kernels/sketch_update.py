@@ -68,4 +68,5 @@ def sketch_update(
         out_specs=pl.BlockSpec((depth, width), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((depth, width), jnp.float32),
         interpret=interpret,
+        name="sketch_update",  # the op's name in a device trace
     )(keys2d, valid2d)

@@ -12,6 +12,7 @@ cannot be described the tests skip.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +59,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, *shapes):
-    """Compile ``fn`` for the described chip; return its HLO text."""
+def _compile(fn, *shapes, kernel: str):
+    """Compile ``fn`` for the described chip; return its HLO text.  The
+    kernel's op carries its name (``%lookup_dispatch.1``): the device trace
+    and the benchmark's readers find it by that name."""
     text = jax.jit(fn).lower(*shapes).compile().as_text()
     assert "tpu_custom_call" in text  # the Pallas kernel, compiled
+    assert re.search(rf"%{kernel}(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"", text)
     return text
 
 
@@ -73,7 +77,8 @@ def test_partition_apply_compiles(one_chip):
     keys, hk, hp, h2p = _shapes(one_chip, ((N,), jnp.int32), ((HEAVY,), jnp.int32),
                                 ((HEAVY,), jnp.int32), ((HOSTS,), jnp.int32))
     _compile(lambda k, a, b, c: partition_apply(k, a, b, c, num_hosts=HOSTS,
-                                                interpret=False), keys, hk, hp, h2p)
+                                                interpret=False), keys, hk, hp, h2p,
+             kernel="partition_apply")
 
 
 @pytest.mark.parametrize("lanes", [1, 4])
@@ -84,7 +89,7 @@ def test_lookup_dispatch_compiles(one_chip, lanes):
         ((HEAVY,), jnp.int32), ((HOSTS,), jnp.int32), ((HEAVY,), jnp.int32))
     _compile(lambda k, v, a, b, c, r: lookup_dispatch(
         k, v, a, b, c, r, num_hosts=HOSTS, num_lanes=lanes, num_partitions=PARTS,
-        interpret=False), keys, valid, hk, hp, h2p, hr)
+        interpret=False), keys, valid, hk, hp, h2p, hr, kernel="lookup_dispatch")
 
 
 def test_route_bucketize_compiles(one_chip):
@@ -94,19 +99,19 @@ def test_route_bucketize_compiles(one_chip):
         ((HEAVY,), jnp.int32), ((HEAVY,), jnp.int32), ((HOSTS,), jnp.int32))
     _compile(lambda k, v, w, a, b, c: route_bucketize(
         k, v, w, a, b, c, num_hosts=HOSTS, num_lanes=MAX_LANES, capacity=MAX_CAPACITY,
-        interpret=False), keys, valid, vals, hk, hp, h2p)
+        interpret=False), keys, valid, vals, hk, hp, h2p, kernel="route_bucketize")
 
 
 def test_dispatch_count_compiles(one_chip):
     dest, valid = _shapes(one_chip, ((N,), jnp.int32), ((N,), jnp.bool_))
     _compile(lambda d, v: dispatch_count(d, v, num_parts=PARTS, interpret=False),
-             dest, valid)
+             dest, valid, kernel="dispatch_count")
 
 
 def test_sketch_update_compiles(one_chip):
     keys, valid = _shapes(one_chip, ((N,), jnp.int32), ((N,), jnp.bool_))
     _compile(lambda k, v: sketch_update(k, v, depth=4, width=2048, interpret=False),
-             keys, valid)
+             keys, valid, kernel="sketch_update")
 
 
 def test_ragged_transport_is_native_on_a_tpu_mesh(topo, monkeypatch):
