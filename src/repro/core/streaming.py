@@ -23,8 +23,9 @@ repartitions with the *same* backend's sizing rule.  Migration lanes are
 sized from the host-side plan (``plan_migration`` + ``migration_capacity``):
 the all-to-all ships the planned peak transfer x slack instead of
 ``W * state_capacity`` rows.  Lane capacities are rounded up to powers of
-two so repeated repartitions reuse a handful of jitted migrate steps
-instead of recompiling per plan.
+two, and across workers to at least a sixteenth of the table, so repeated
+repartitions reuse a handful of jitted migrate steps instead of recompiling
+per plan.
 
 **Elastic resize** is the same mechanism one level up: changing the *number*
 of partitions (the job's logical worker count) instead of their contents.
@@ -264,6 +265,22 @@ class RecoveryStats:
     replayed: int
     workers: int
     wall_s: float = 0.0
+
+
+def migrate_lane_capacity(plan_rows: int, state_capacity: int, num_workers: int) -> int:
+    """Rows a migration lane holds for a plan that needs ``plan_rows``.
+
+    The next power of two, capped at the full state table, so the jit cache
+    stays small across repartitions.  Across workers a lane holds at least a
+    sixteenth of the table: the plans' sizes wander with the key drift, each
+    size is programs of its own that take seconds to compile, and every plan
+    below the floor shares one.  On one worker no row ships, so its lanes
+    stay 8.
+    """
+    cap = 8 if num_workers == 1 else max(8, state_capacity // 16)
+    while cap < min(plan_rows, state_capacity):
+        cap *= 2
+    return min(cap, state_capacity)
 
 
 def _default_mesh(axis: str = "data") -> Mesh:
@@ -519,17 +536,12 @@ class StreamingJob:
         )
 
     def _migrate_step(self, lane_capacity: int):
-        """Jitted migrate step with lanes >= ``lane_capacity`` rows.
-
-        Capacities are rounded up to the next power of two (capped at the
-        full state table) so the jit cache stays small across repartitions.
-        The step routes at worker granularity, so the same cache serves
-        plain repartitions *and* cross-size resize migrations.
+        """Jitted migrate step with lanes >= ``lane_capacity`` rows, sized
+        by :func:`migrate_lane_capacity`.  The step routes at worker
+        granularity, so the same cache serves plain repartitions *and*
+        cross-size resize migrations.
         """
-        cap = 8
-        while cap < min(lane_capacity, self.state_capacity):
-            cap *= 2
-        cap = min(cap, self.state_capacity)
+        cap = migrate_lane_capacity(lane_capacity, self.state_capacity, self.num_workers)
         if cap not in self._migrate_steps:
             self._migrate_steps[cap] = make_migrate_step(
                 self.mesh,

@@ -11,7 +11,7 @@ from repro.core.hashing import KEY_SENTINEL
 from repro.core.replay import BatchJob
 from repro.core.shuffle import make_shuffle_step
 from repro.core.state import empty_state, merge_into
-from repro.core.streaming import StreamingJob
+from repro.core.streaming import StreamingJob, migrate_lane_capacity
 from repro.data.generators import drifting_zipf, zipf_keys
 
 
@@ -321,3 +321,15 @@ def test_restore_legacy_snapshot_minimal():
                              "drm_last_health_action", "drm_backend_streak",
                              "drm_last_backend_switch"])
     assert job.drm.lane_health is None
+
+
+@pytest.mark.parametrize("plan_rows, workers, want", [
+    # four workers, a 2^20-row table: every plan up to a sixteenth of the
+    # table shares one lane size, larger plans round up to powers of two
+    (1, 4, 1 << 16), (4096, 4, 1 << 16), (40_000, 4, 1 << 16),
+    (1 << 16, 4, 1 << 16), ((1 << 16) + 1, 4, 1 << 17), (10**9, 4, 1 << 20),
+    # one worker ships nothing: lanes stay as small as the plan allows
+    (8, 1, 8), (100, 1, 128), (10**9, 1, 1 << 20),
+])
+def test_migrate_lane_capacity_floors_cross_worker_lanes(plan_rows, workers, want):
+    assert migrate_lane_capacity(plan_rows, 1 << 20, workers) == want
