@@ -21,6 +21,7 @@ __all__ = [
     "MigrationPlan",
     "plan_migration",
     "migration_capacity",
+    "lane_rows",
     "exchange_lane_cost",
     "fold_to_workers",
 ]
@@ -109,6 +110,12 @@ def plan_migration(
     )
 
 
+def lane_rows(peak: float, slack: float = 1.25) -> int:
+    """Rows of an all-to-all lane that carries at most ``peak`` rows:
+    ``peak`` x ``slack``, rounded up to a multiple of 8, and at least 8."""
+    return max(int(np.ceil(peak * slack / 8.0) * 8), 8)
+
+
 def migration_capacity(
     plan: MigrationPlan,
     row_bytes: float = 1.0,
@@ -123,9 +130,9 @@ def migration_capacity(
     With ``num_workers`` the [N, N] partition-level transfer matrix is first
     folded to worker granularity (partition p lives on worker ``p % W``) and
     same-worker moves are dropped — they never cross the exchange.  This is
-    the lane size ``repro.core.shuffle.make_migrate_step`` wants: the
-    exchanged buffer shrinks from ``W * state_capacity`` rows to the planned
-    peak transfer x slack.
+    the lane size a migration's all-to-all needs.  The streaming job takes
+    the same peak from the route counts on the device instead of a plan;
+    ``exchange_lane_cost`` prices candidate plans by the same peak.
     """
     transfer = plan.transfer
     if transfer.size == 0:
@@ -133,9 +140,7 @@ def migration_capacity(
     if num_workers is not None:
         transfer = fold_to_workers(transfer, num_workers)
         np.fill_diagonal(transfer, 0.0)  # same-worker moves don't ship
-    peak = float(transfer.max()) / max(row_bytes, 1e-12)
-    cap = int(np.ceil(peak * slack / 8.0) * 8)
-    return max(cap, 8)
+    return lane_rows(float(transfer.max()) / max(row_bytes, 1e-12), slack)
 
 
 def exchange_lane_cost(

@@ -26,16 +26,17 @@ construction.
 Partitions may outnumber workers (over-partitioning, paper Fig. 5);
 ``worker = partition % W``.
 
-State migration (``make_migrate_step``) is the *same* exchange with lanes
-sized by the planner: ``repro.core.migration.migration_capacity`` bounds the
-per-lane rows to the planned peak transfer x slack, so a repartition ships a
-buffer proportional to what actually moves instead of ``W * state_capacity``
-rows.  The migrate step routes with the same fused ``route_dispatch`` pass
-the shuffle uses (worker granularity), so its bucketize reuses the dispatch
-counts instead of recomputing them.  Both steps report the backend's
-measured ``shipped_rows`` (globally summed) next to the spec's padded
-provision, so the control plane sees what the transport moved, not just
-what it reserved.
+State migration (``make_migrate_step``) is the *same* exchange, routed
+with the same fused ``route_dispatch`` pass the shuffle uses (worker
+granularity), so its bucketize reuses the dispatch counts instead of
+recomputing them.  Its lanes are sized to what moves instead of
+``W * state_capacity`` rows: ``make_migrate_route`` routes first, in a
+program of its own, and counts the rows each worker sends to each other
+worker; the host sizes the lanes from those ``[W, W]`` counts (the peak x
+slack, ``repro.core.migration.lane_rows``) and the sized step ships from
+the route.  Both steps report the backend's measured ``shipped_rows``
+(globally summed) next to the spec's padded provision, so the control
+plane sees what the transport moved, not just what it reserved.
 """
 from __future__ import annotations
 
@@ -70,6 +71,8 @@ __all__ = [
     "ShuffleResult",
     "ShuffleStart",
     "make_shuffle_step",
+    "MigrateRoute",
+    "make_migrate_route",
     "make_migrate_step",
     "shuffle_stats",
     "migrate_stats",
@@ -333,6 +336,77 @@ def make_shuffle_step(
     return step
 
 
+class MigrateRoute(NamedTuple):
+    """The first phase of a state migration, on the device, stacked
+    ``[W, ...]`` per worker: where each state row goes and how many rows
+    each worker sends to each other worker."""
+
+    dest: jax.Array    # int32[W, S]  destination worker; the row's own worker if it stays
+    slot: jax.Array    # int32[W, S]  rank of the row within its destination lane
+    counts: jax.Array  # int32[W, W]  rows worker i sends to worker j (diagonal 0)
+    moved: jax.Array   # int32[]      rows that change worker, all workers
+    total: jax.Array   # int32[]      live state rows, all workers
+
+
+def _route_state(new_tables, state_keys, *, num_hosts: int, seed: int,
+                 num_workers: int, axis: str) -> MigrateRoute:
+    """One worker's half of a migration route (unstacked): its rows'
+    destinations and lane slots under ``new_tables``, and its row of the
+    ``[W, W]`` counts."""
+    me = jax.lax.axis_index(axis)
+    valid = state_keys != KEY_SENTINEL
+    # home routing on purpose (no num_partitions): a migration is where
+    # a split key's scattered partials converge — every replica's rows
+    # ship to the key's home partition, whose merge_into sums them.
+    # Routing state by replica pick would scatter it instead.
+    part, slot, counts = route_dispatch(
+        PartitionerTables(*new_tables), state_keys, valid,
+        num_hosts=num_hosts, seed=seed, num_lanes=num_workers,
+    )
+    dest = jnp.where(valid, part % num_workers, me)
+    # the fused route ranked *all* valid rows; rows on lane `me` stay put,
+    # so their lane count is zeroed — on every other lane the slots/counts
+    # coincide with ranking the moving rows alone
+    counts = counts.at[me].set(0)
+    moved = jax.lax.psum(jnp.sum(dest != me), axis)
+    total = jax.lax.psum(jnp.sum(valid), axis)
+    return MigrateRoute(dest, slot, counts, moved, total)
+
+
+def make_migrate_route(mesh: Mesh, *, num_hosts: int, seed: int = 0, axis: str = "data"):
+    """Jitted first phase of an operator-state migration, for lanes that
+    must be sized from what moves: ``route(new_tables, state_keys) ->
+    MigrateRoute``.
+
+    Every worker routes its stored keys under the new partitioner with the
+    fused ``route_dispatch`` pass the shuffle uses, at worker granularity
+    (``lookup % W``), so one route serves any partition count.  The counts
+    are taken where the rows are: row ``i`` of ``counts`` is what worker
+    ``i``'s table sends, which is what the all-to-all ships, so lanes sized
+    to its largest entry cannot overflow.  Nothing here depends on the
+    lane size; a step of :func:`make_migrate_step` takes the route in place
+    of the tables and ships from it.  The state is read, not donated.
+    """
+    num_workers = mesh.shape[axis]
+
+    # jit_migrate_start in a device trace, like the sized start after it
+    def migrate_start(new_tables, state_keys):
+        r = _route_state(new_tables, state_keys[0], num_hosts=num_hosts, seed=seed,
+                         num_workers=num_workers, axis=axis)
+        return MigrateRoute(r.dest[None], r.slot[None], r.counts[None], r.moved, r.total)
+
+    jroute = jax.jit(shard_map(
+        migrate_start, mesh=mesh, in_specs=((P(), P(), P(), P()), P(axis)),
+        out_specs=MigrateRoute(P(axis), P(axis), P(axis), P(), P()),
+        check_vma=False,
+    ))
+
+    def route(new_tables: PartitionerTables, state_keys) -> MigrateRoute:
+        return jroute(tuple(new_tables), state_keys)
+
+    return route
+
+
 def make_migrate_step(
     mesh: Mesh,
     *,
@@ -348,27 +422,31 @@ def make_migrate_step(
     """Jitted operator-state migration for a partitioner swap.
 
     Each worker re-evaluates the new partitioner on its stored keys and
-    ships rows whose worker changed through the exchange plane.  Routing
-    rides the same fused ``route_dispatch`` pass as the shuffle (worker
-    granularity), so the bucketize reuses the dispatch slots/counts instead
-    of recomputing them; lane ``me`` never ships (its rows stay put), so
-    its count is zeroed before they reach the exchange.
-    ``lane_capacity`` bounds the per-(src, dst) rows of the all-to-all —
-    pass ``migration_capacity(plan, num_workers=W)`` to size the exchange to
-    the planned peak transfer x slack instead of the full state table
+    ships rows whose worker changed through the exchange plane.  Every
+    call takes, first, where the rows go: either the new partitioner's
+    tables, and the step routes in its own program, or the
+    :class:`MigrateRoute` that :func:`make_migrate_route` computed, and the
+    step starts from it.  The route is the part that does not depend on the
+    lane size; the second form lets the host size the lanes between the two
+    phases from the route's ``[W, W]`` counts (the largest entry x slack,
+    ``repro.core.migration.lane_rows``).  Either way the bucketize reuses
+    the route's slots and counts instead of recomputing them, and lane
+    ``me`` never ships (its rows stay put).
+    ``lane_capacity`` bounds the per-(src, dst) rows of the all-to-all
     (defaults to ``state_capacity``, the correctness-first upper bound).
     ``spec`` overrides the derived :class:`ExchangeSpec` entirely (the
     elastic-resize path re-derives the shuffle's spec); ``backend`` selects
-    the transport.  The migrate step routes at *worker* granularity
-    (``lookup % W``), so one step serves any partition count — a resize
-    migration reuses the same jit cache.
+    the transport.  Routing is at *worker* granularity (``lookup % W``), so
+    one step serves any partition count — a resize migration reuses the
+    same jit cache.
 
-    Returns the fused step (kept state + received rows + relative-migration
-    metric + overflow + per-lane overflow + globally shipped rows) with
-    ``.start`` / ``.finish`` halves attached: ``start`` keeps every control
-    output and the kept state local (the ship stays pending), ``finish``
-    ships the moving rows — the overlapped driver leaves it in flight
-    across the safe point.
+    Returns the fused step (kept state + received rows + moved and live
+    rows + overflow + per-lane overflow + globally shipped rows + shipped
+    rows by distance class) with ``.start`` / ``.finish`` halves attached:
+    ``start`` keeps every control output and the kept state local (the
+    ship stays pending), ``finish`` ships the moving rows — the overlapped
+    driver leaves it in flight across the safe point.  Both donate the
+    state tables, which a separate route only read.
     """
     num_workers = mesh.shape[axis]
     if spec is None:
@@ -377,47 +455,32 @@ def make_migrate_step(
                             topology=topology)
     ex = make_exchange(spec, backend)
     fills = (KEY_SENTINEL, 0)
+    route_spec = MigrateRoute(P(axis), P(axis), P(axis), P(), P())
+    tables_spec = (P(), P(), P(), P())
 
-    def _start_core(new_tables, state_keys, state_vals, bufs):
-        # state tables arrive stacked [1, S] / [1, S, D] per shard
+    def _start_core(where, state_keys, state_vals, bufs):
+        # the state tables (and a route) arrive stacked [1, ...] per shard
         state_keys, state_vals = state_keys[0], state_vals[0]
-        new_tables = PartitionerTables(*new_tables)
-        me = jax.lax.axis_index(axis)
-        valid = state_keys != KEY_SENTINEL
-        # home routing on purpose (no num_partitions): a migration is where
-        # a split key's scattered partials converge — every replica's rows
-        # ship to the key's home partition, whose merge_into sums them.
-        # Routing state by replica pick would scatter it instead.
-        part, slot, counts = route_dispatch(
-            new_tables, state_keys, valid,
-            num_hosts=num_hosts, seed=seed, num_lanes=num_workers,
-        )
-        dest = jnp.where(valid, part % num_workers, me)
-        moving = valid & (dest != me)
-        # the fused route ranked *all* valid rows; rows on lane `me` stay
-        # put (they are not `moving`), so their lane count is zeroed — on
-        # every other lane valid == moving and the slots/counts coincide
-        # with ranking the moving rows alone
-        counts = counts.at[me].set(0)
-        moved_w = jnp.sum(moving)
-        total_w = jax.lax.psum(jnp.sum(valid), axis)
-
+        if isinstance(where, MigrateRoute):
+            r = MigrateRoute(where.dest[0], where.slot[0], where.counts[0],
+                             where.moved, where.total)
+        else:
+            r = _route_state(where, state_keys, num_hosts=num_hosts, seed=seed,
+                             num_workers=num_workers, axis=axis)
+        moving = r.dest != jax.lax.axis_index(axis)
         buffers = ex.bucketize(
-            jnp.where(moving, dest, me),
+            r.dest,
             moving,
             [
                 Payload(jnp.where(moving, state_keys, KEY_SENTINEL), KEY_SENTINEL),
                 Payload(state_vals, 0),
             ],
-            slot=slot,
-            counts=counts,
+            slot=r.slot,
+            counts=r.counts,
             buffers=None if bufs is None else (bufs[0][0], tuple(b[0] for b in bufs[1])),
         )
         started = ex.start_from(buffers).buffers
-
         kept_keys = jnp.where(moving, KEY_SENTINEL, state_keys)
-        kept_valid = valid & ~moving
-        moved_total = jax.lax.psum(moved_w, axis)
         overflow = jax.lax.psum(started.send.overflow, axis)
         lane_overflow = jax.lax.psum(started.send.lane_overflow, axis)
         shipped = jax.lax.psum(started.shipped_rows, axis)
@@ -425,54 +488,26 @@ def make_migrate_step(
         if by_class is None:  # flat spec: no topology, keep zeros
             by_class = jnp.zeros(DISTANCE_CLASSES, jnp.int32)
         by_class = jax.lax.psum(by_class, axis)
-        return (
-            _pack_pending(started),
-            kept_keys[None],
-            state_vals[None],
-            kept_valid[None],
-            moved_total,
-            total_w,
-            overflow,
-            lane_overflow,
-            shipped,
-            by_class,
-        )
+        return (_pack_pending(started), kept_keys[None], state_vals[None],
+                r.moved, r.total, overflow, lane_overflow, shipped, by_class)
 
     # programs jit_migrate_start, jit_migrate_finish, jit_migrate_step in a
     # device trace, apart from the shuffle's
-    def migrate_start(new_tables, state_keys, state_vals, bufs):
-        return _start_core(new_tables, state_keys, state_vals, bufs)
+    def migrate_start(where, state_keys, state_vals, bufs):
+        return _start_core(where, state_keys, state_vals, bufs)
 
     def migrate_finish(pending):
         res = ex.finish(PendingExchange(_unpack_pending(pending, fills)))
         rva, (rk, rv) = res.unpack()
         return rk[None], rv[None], rva[None]
 
-    def migrate_step(new_tables, state_keys, state_vals):
-        pending, kk, vv, kva, moved, total, ov, lov, shipped, by = _start_core(
-            new_tables, state_keys, state_vals, None
-        )
+    def migrate_step(where, state_keys, state_vals):
+        pending, kk, vv, *control = _start_core(where, state_keys, state_vals, None)
         rk, rv, rva = migrate_finish(pending)
-        return kk, vv, kva, rk, rv, rva, moved, total, ov, lov, shipped, by
+        return (kk, vv, rk, rv, rva, *control)
 
-    in_specs = ((P(), P(), P(), P()), P(axis), P(axis))
     bufs_spec = (P(axis), (P(axis), P(axis)))
-    mapped = shard_map(
-        migrate_step, mesh=mesh, in_specs=in_specs,
-        out_specs=(P(axis),) * 6 + (P(), P(), P(), P(), P(), P()),
-        check_vma=False,
-    )
-    start_mapped = shard_map(
-        migrate_start, mesh=mesh, in_specs=in_specs + (bufs_spec,),
-        out_specs=(P(axis),) * 4 + (P(), P(), P(), P(), P(), P()),
-        check_vma=False,
-    )
-    finish_mapped = shard_map(
-        migrate_finish, mesh=mesh, in_specs=(P(axis),),
-        out_specs=(P(axis), P(axis), P(axis)),
-        check_vma=False,
-    )
-
+    control = (P(),) * 6
     # donate the state tables: the kept/received outputs alias them, so the
     # exchange compaction doesn't double-allocate the state; the recycled
     # send-buffer set (arg 3 of start) is donated and rewritten in place.
@@ -480,9 +515,21 @@ def make_migrate_step(
     # pool (CPU: no donation at all).
     donate = () if jax.default_backend() == "cpu" else (1, 2)
     start_donate = () if jax.default_backend() == "cpu" else (1, 2, 3)
-    jmig = jax.jit(mapped, donate_argnums=donate)
-    jstart = jax.jit(start_mapped, donate_argnums=start_donate)
-    jfinish = jax.jit(finish_mapped)
+    jmig, jstart = {}, {}  # keyed by whether the call brings a MigrateRoute
+    for routed, where_spec in ((False, tables_spec), (True, route_spec)):
+        in_specs = (where_spec, P(axis), P(axis))
+        jmig[routed] = jax.jit(shard_map(
+            migrate_step, mesh=mesh, in_specs=in_specs,
+            out_specs=(P(axis),) * 5 + control, check_vma=False,
+        ), donate_argnums=donate)
+        jstart[routed] = jax.jit(shard_map(
+            migrate_start, mesh=mesh, in_specs=in_specs + (bufs_spec,),
+            out_specs=(P(axis),) * 3 + control, check_vma=False,
+        ), donate_argnums=start_donate)
+    jfinish = jax.jit(shard_map(
+        migrate_finish, mesh=mesh, in_specs=(P(axis),),
+        out_specs=(P(axis), P(axis), P(axis)), check_vma=False,
+    ))
 
     recycled: list = []  # drained send-buffer sets, ping-pong pool (<= 2)
     buf_sharding = _pool_sharding(mesh, axis)
@@ -495,11 +542,16 @@ def make_migrate_step(
              jnp.zeros(shape + state_vals.shape[2:], state_vals.dtype)),
         ), buf_sharding)
 
-    def migrate(new_tables, state_keys, state_vals):
-        maybe_inject(ex.backend, "migrate")  # host boundary: faults fire here
-        return jmig(tuple(new_tables), state_keys, state_vals)
+    def _where(where):
+        routed = isinstance(where, MigrateRoute)
+        return routed, (where if routed else tuple(where))
 
-    def start(new_tables, state_keys, state_vals):
+    def migrate(where, state_keys, state_vals):
+        maybe_inject(ex.backend, "migrate")  # host boundary: faults fire here
+        routed, where = _where(where)
+        return jmig[routed](where, state_keys, state_vals)
+
+    def start(where, state_keys, state_vals):
         maybe_inject(ex.backend, "migrate")
         bufs = recycled.pop() if recycled else None
         if bufs is not None and (bufs[1][1].shape[3:] != state_vals.shape[2:]
@@ -507,7 +559,8 @@ def make_migrate_step(
             bufs = None  # payload width changed: the set cannot be reused
         if bufs is None:
             bufs = _fresh_bufs(state_vals)
-        return jstart(tuple(new_tables), state_keys, state_vals, bufs)
+        routed, where = _where(where)
+        return jstart[routed](where, state_keys, state_vals, bufs)
 
     def finish(pending: _Pending):
         out = jfinish(pending)

@@ -19,13 +19,14 @@ Both the shuffle and the migration ride the unified exchange plane
 (``repro.exchange``) on the transport ``exchange_backend`` selects — the
 dense capacity-padded all-to-all or the ragged count-first one; results are
 bit-identical, only the traffic differs, and the DRM prices candidate
-repartitions with the *same* backend's sizing rule.  Migration lanes are
-sized from the host-side plan (``plan_migration`` + ``migration_capacity``):
-the all-to-all ships the planned peak transfer x slack instead of
+repartitions with the *same* backend's sizing rule.  Across workers a
+migration routes the state on the device first; the host reads only the
+``[W, W]`` counts of rows each worker sends to each other worker and sizes
+the lanes to the peak x slack, so the all-to-all ships that instead of
 ``W * state_capacity`` rows.  Lane capacities are rounded up to powers of
 two, and across workers to at least a sixteenth of the table, so repeated
 repartitions reuse a handful of jitted migrate steps instead of recompiling
-per plan.
+per size.
 
 **Elastic resize** is the same mechanism one level up: changing the *number*
 of partitions (the job's logical worker count) instead of their contents.
@@ -107,10 +108,10 @@ batch and carries its index; its children cover it with no holes:
   re-plan and the policy stack);
 * ``stream.drain`` — complete the in-flight finish + merge, wherever that
   happens;
-* ``dr.migrate`` — a migration, split into ``dr.migrate.fetch`` (the full
-  state to the host), ``dr.migrate.plan`` (``plan_migration`` and the lane
-  size) and ``dr.migrate.start`` (the step, its enqueue, the control
-  fetches);
+* ``dr.migrate`` — a migration, split into ``dr.migrate.fetch`` (enqueue
+  the route and fetch its ``[W, W]`` counts; empty on one worker and for
+  full lanes), ``dr.migrate.plan`` (the lane size) and
+  ``dr.migrate.start`` (the step, its enqueue, the control fetches);
 * ``stream.account`` — the rest: migration telemetry and ``BatchMetrics``.
 
 ``dr.resize``, ``dr.switch``, ``dr.lane`` (quarantine, evict) and
@@ -122,7 +123,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Iterable
+from typing import Iterable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -153,7 +154,7 @@ from repro.control import (
 )
 from repro.core.drm import DRConfig, DRMaster
 from repro.core.hashing import DEFAULT_NUM_HOSTS, KEY_SENTINEL
-from repro.core.migration import migration_capacity, plan_migration
+from repro.core.migration import lane_rows
 from repro.core.partitioner import (
     Partitioner,
     heavy_capacity_for,
@@ -161,6 +162,7 @@ from repro.core.partitioner import (
     uniform_partitioner,
 )
 from repro.core.shuffle import (
+    make_migrate_route,
     make_migrate_step,
     make_shuffle_step,
     migrate_stats,
@@ -221,7 +223,10 @@ class BatchMetrics:
     migration_rows: int = 0     # rows of all-to-all buffer a repartition exchanged
     resized: bool = False       # an elastic resize fired at this safe point
     num_partitions: int = 0     # topology after this batch (post-resize)
-    migration_plan_rows: int = 0  # migration_capacity() of the plan (pre-pow2)
+    migration_plan_rows: int = 0  # rows a migration lane needs (pre-pow2)
+    migration_peak_rows: int = 0  # largest (src, dst) worker count the device
+                                # measured for the migration; 0 on one worker
+                                # and on full-lane migrations
     action: str = "noop"        # control-plane action kind this safe point took
     shipped_rows: int = 0       # rows the backend moved this batch (per worker)
     padded_rows: int = 0        # rows the specs provisioned (per worker)
@@ -267,8 +272,22 @@ class RecoveryStats:
     wall_s: float = 0.0
 
 
+class _Migration(NamedTuple):
+    """What one state migration did, for ``BatchMetrics`` and telemetry
+    (all zeros when the safe point moved no state)."""
+
+    relative: float = 0.0   # moved rows / live rows
+    overflow: int = 0       # rows dropped for lane capacity
+    buffer_rows: int = 0    # rows received per worker (W x lane capacity)
+    plan_rows: int = 0      # rows a lane needs, before pow2 rounding
+    peak_rows: int = 0      # largest (src, dst) count the device measured
+    shipped: int = 0        # rows the backend moved, per worker
+    moved: int = 0          # rows that changed worker, all workers
+    by_class: np.ndarray | None = None  # shipped rows by distance class, summed
+
+
 def migrate_lane_capacity(plan_rows: int, state_capacity: int, num_workers: int) -> int:
-    """Rows a migration lane holds for a plan that needs ``plan_rows``.
+    """Rows a migration lane holds for a migration that needs ``plan_rows``.
 
     The next power of two, capped at the full state table, so the jit cache
     stays small across repartitions.  Across workers a lane holds at least a
@@ -359,6 +378,7 @@ class StreamingJob:
         self._shuffle_sig = None  # (capacity, num_partitions) the step was built for
         self._shuffle_spec: ExchangeSpec | None = None  # for exchange-row accounting
         self._migrate_steps: dict[int, object] = {}  # lane capacity -> jitted step
+        self._migrate_route = None  # the migration's route phase (jitted, lazily)
         self._pending_resize: int | None = None
         # per-worker keyed state, stacked [W, S] / [W, S, D] and sharded
         # over ``data``: one worker's table per device
@@ -791,24 +811,18 @@ class StreamingJob:
         if action.taken:
             self._drain_inflight()
             self._discard_staged()
-        (rel_mig, mig_overflow, mig_rows, plan_rows, mig_shipped, mig_moved,
-         mig_by_class) = 0.0, 0, 0, 0, 0, 0, None
+        mig = _Migration()
         if isinstance(action, Resize):
-            (rel_mig, mig_overflow, mig_rows, plan_rows, mig_shipped,
-             mig_moved, mig_by_class) = self._apply_resize(action.target)
+            mig = self._apply_resize(action.target)
         elif isinstance(action, Repartition):
-            (rel_mig, mig_overflow, mig_rows, plan_rows, mig_shipped,
-             mig_moved, mig_by_class) = self._migrate_state(action.prev)
+            mig = self._migrate_state()
         elif isinstance(action, Unsplit):
             # combiner-side merge: the DRM already removed the key from the
             # replica table; a home-routed migration off the still-split
             # partitioner pulls every replica's partial aggregate back to
-            # the key's home, where merge_into sums them.  The home diff is
-            # empty (homes never changed) so the plan can't size the lanes —
-            # full_lanes provisions for the off-home partials it can't see.
-            (rel_mig, mig_overflow, mig_rows, plan_rows, mig_shipped,
-             mig_moved, mig_by_class) = self._migrate_state(
-                action.prev, full_lanes=True)
+            # the key's home, where merge_into sums them.  full_lanes
+            # provisions for every partial, the whole table.
+            mig = self._migrate_state(full_lanes=True)
         elif isinstance(action, SwitchBackend):
             # the DRM already installed the new transport (note_backend_switch);
             # the job adopts it and rebuilds its jitted steps, exactly like a
@@ -828,24 +842,24 @@ class StreamingJob:
         # table and the very next batch's route kernels fan the key out
         with _span("stream.account"):
             with safe_point():  # migrations only fire at safe points
-                if mig_rows:
+                if mig.buffer_rows:
                     self.telemetry.record_exchange(migrate_stats(
-                        shipped_rows=mig_shipped * w,  # helper re-divides per worker
-                        buffer_rows=mig_rows,
-                        moved_rows=mig_moved,
-                        overflow=mig_overflow,
+                        shipped_rows=mig.shipped * w,  # helper re-divides per worker
+                        buffer_rows=mig.buffer_rows,
+                        moved_rows=mig.moved,
+                        overflow=mig.overflow,
                         num_workers=w,
-                        shipped_rows_by_class=mig_by_class,
+                        shipped_rows_by_class=mig.by_class,
                     ))
-                    self.telemetry.record_overflow(migration=mig_overflow)
+                    self.telemetry.record_overflow(migration=mig.overflow)
 
                 # per-class shipped rows (shuffle + migration, per worker) for
                 # the locality benches; zeros when the job carries no topology
                 by_class = np.zeros(DISTANCE_CLASSES, np.int64)
                 if stats.rows_by_class is not None:
                     by_class += np.asarray(host_fetch(stats.rows_by_class), np.int64)
-                if mig_by_class is not None:
-                    by_class += np.asarray(mig_by_class, np.int64) // w
+                if mig.by_class is not None:
+                    by_class += np.asarray(mig.by_class, np.int64) // w
 
             m = BatchMetrics(
                 batch=len(self.metrics),
@@ -855,8 +869,8 @@ class StreamingJob:
                 # count as a repartition (consumers divide migration rows by
                 # this flag's sum)
                 repartitioned=action.taken and action.moves_state,
-                relative_migration=rel_mig,
-                overflow=overflow_i + mig_overflow,
+                relative_migration=mig.relative,
+                overflow=overflow_i + mig.overflow,
                 # overlapped: the count as of the last drain (exact state rows
                 # would sync the in-flight merge; serial keeps today's numbers)
                 state_rows=(self._last_state_rows if overlap else
@@ -864,13 +878,14 @@ class StreamingJob:
                              else self._state_rows())),
                 wall_time_s=time.perf_counter() - t0,
                 reason=action.reason,
-                migration_rows=mig_rows,
+                migration_rows=mig.buffer_rows,
                 resized=isinstance(action, Resize),
                 num_partitions=self.num_partitions,
-                migration_plan_rows=plan_rows,
+                migration_plan_rows=mig.plan_rows,
+                migration_peak_rows=mig.peak_rows,
                 action=action.kind,
-                shipped_rows=shuffle_shipped + mig_shipped,
-                padded_rows=self._shuffle_spec.rows + mig_rows,
+                shipped_rows=shuffle_shipped + mig.shipped,
+                padded_rows=self._shuffle_spec.rows + mig.buffer_rows,
                 backend=batch_backend,
                 exchange_wall_s=exchange_wall,
                 overlapped=overlap,
@@ -953,6 +968,7 @@ class StreamingJob:
         self._shuffle = None
         self._shuffle_sig = None
         self._migrate_steps.clear()
+        self._migrate_route = None
         self._part_loads = None
         self._inflight = None
         self._hidden_since = None
@@ -1081,9 +1097,8 @@ class StreamingJob:
         """Execute a resize at a safe point: re-plan cross-size, migrate
         state through freshly sized exchange lanes, rebuild the step cache."""
         with _span("dr.resize"):
-            old = self.drm.partitioner
             self.drm.replan_resize(n)
-            stats = self._migrate_state(old)
+            stats = self._migrate_state()
             self.num_partitions = n
             # the shuffle step's lane count / loads vector followed the old
             # topology; _build re-derives the spec on the next batch, and the
@@ -1093,60 +1108,60 @@ class StreamingJob:
             self._part_loads = None
             return stats
 
-    def _migrate_state(self, old_part: Partitioner, *,
-                       full_lanes: bool = False):
+    def _migrate_state(self, *, full_lanes: bool = False) -> _Migration:
         """Ship keyed state to where ``self.drm.partitioner`` now maps it.
 
-        Plans on the driver (``plan_migration`` diffs the partitioners over
-        the live keys — cross-size safe), sizes the exchange lanes from the
-        plan (``migration_capacity``), and folds received rows back into the
-        local state tables.  Returns ``(relative_migration, overflow,
-        buffer_rows, planned_lane_rows, shipped_rows, moved_rows,
-        shipped_rows_by_class)`` — ``buffer_rows`` is the per-worker
-        provision, ``shipped_rows`` what the backend measured moving,
-        ``moved_rows`` the rows that actually crossed workers (the occupancy
-        side of the telemetry), ``shipped_rows_by_class`` the globally
-        summed per-distance-class split (all zeros on a flat spec).
-
-        ``full_lanes`` (and any installed split key) forces full-state
-        lane provisioning: split partial aggregates live *off home*, so the
-        home-diff plan cannot see them, but the home-routed migrate step
-        ships every one of them back to its key's home — undersized lanes
-        would silently drop the partials being merged.
+        Sizes the exchange lanes from what moves, with no key table and no
+        host plan: across workers, a route program finds each row's
+        destination worker and counts the rows every worker sends to every
+        other worker; the host fetches only that ``[W, W]`` matrix and
+        sizes the lanes to its largest entry x slack
+        (:func:`~repro.core.migration.lane_rows`); the sized step ships
+        from the route, and the received rows fold back into the local
+        state tables.  The counts are taken where the rows are, so they are
+        what the all-to-all ships and no lane can overflow — cross-size
+        (resize) migrations included.  Where no count is needed the step
+        routes in its own start program and the host fetches nothing: one
+        worker has no pair to count (lanes of 8 rows), and ``full_lanes``
+        (and any installed split key) provisions the whole table, because
+        split partial aggregates live *off home* and the home-routed
+        migrate step ships every one of them back to its key's home.
         """
+        full = full_lanes or bool(self.drm.split_keys)
+        where = self.drm.partitioner.tables()
+        peak = 0
         with _span("dr.migrate"):
             with _span("dr.migrate.fetch"):
-                with safe_point():  # migrations are safe points: the plan reads state
-                    sk = host_fetch(self.state_keys).reshape(-1)
+                if self.num_workers > 1 and not full:
+                    if self._migrate_route is None:
+                        self._migrate_route = make_migrate_route(
+                            self.mesh, num_hosts=self.drm.partitioner.num_hosts,
+                            seed=self.seed)
+                    where = self._migrate_route(where, self.state_keys)
+                    with safe_point():  # migrations are safe points
+                        peak = int(np.max(host_fetch(where.counts)))
             with _span("dr.migrate.plan"):
-                live = sk[sk != KEY_SENTINEL].astype(np.int64)
-                plan = plan_migration(old_part, self.drm.partitioner, live)
-                if full_lanes or self.drm.split_keys:
-                    plan_rows = self.state_capacity
-                else:
-                    plan_rows = migration_capacity(plan, num_workers=self.num_workers)
+                plan_rows = self.state_capacity if full else lane_rows(peak)
             with _span("dr.migrate.start"):
-                return self._start_migration(plan_rows)
+                return self._start_migration(where, plan_rows, peak)
 
-    def _start_migration(self, plan_rows: int):
-        """Enqueue the migrate step sized for ``plan_rows`` and read its
-        control outputs (``_migrate_state``'s return value)."""
+    def _start_migration(self, where, plan_rows: int, peak: int) -> _Migration:
+        """Enqueue the migrate step sized for ``plan_rows`` — from the new
+        partitioner's tables or from a :class:`MigrateRoute` — and read its
+        control outputs."""
         migrate, lane_cap = self._migrate_step(plan_rows)
-        tables = self.drm.partitioner.tables()
         if self._overlap_active():
             # split migrate: the count phase (and every control output the
             # metrics need) blocks below; the row ship + merge stays in
             # flight across the safe point and drains under the next
             # batch's host work — bit-identical to the fused step, which
             # is the two phases traced back to back
-            (pending, kk, vv, kv_valid, moved, total,
-             mig_ov, mig_lane_ov, mig_shipped, mig_by) = migrate.start(
-                tables, self._sk, self._sv)
-            kept_keys = jnp.where(kv_valid, kk, KEY_SENTINEL)
+            (pending, kk, vv, moved, total, mig_ov, mig_lane_ov, mig_shipped,
+             mig_by) = migrate.start(where, self._sk, self._sv)
             # interim state = kept rows only; the pending merge adds the
             # received rows (external readers drain first, so they never
             # observe the interim)
-            self._sk, self._sv = kept_keys, vv
+            self._sk, self._sv = kk, vv
             self._hidden_since = time.perf_counter()
 
             def _fin_migrate(fin=migrate.finish, pending=pending):
@@ -1155,22 +1170,18 @@ class StreamingJob:
 
             self._inflight = _fin_migrate
         else:
-            out = migrate(tables, self._sk, self._sv)
-            (kk, vv, kv_valid, rk, rv, rva, moved, total,
-             mig_ov, mig_lane_ov, mig_shipped, mig_by) = out
-            kept_keys = jnp.where(kv_valid, kk, KEY_SENTINEL)
-            self._sk, self._sv = self._merge(kept_keys, vv, rk, rv, rva)
-        # every control output below left the migrate start phase; fetching
-        # them at this safe point blocks on work already forced (the ship
-        # itself stays in flight on the overlap path)
+            (kk, vv, rk, rv, rva, moved, total, mig_ov, mig_lane_ov, mig_shipped,
+             mig_by) = migrate(where, self._sk, self._sv)
+            self._sk, self._sv = self._merge(kk, vv, rk, rv, rva)
+        # every control output below left the migrate start phase;
+        # fetching them at this safe point blocks on work already forced
+        # (the ship itself stays in flight on the overlap path)
         with safe_point():
             moved_i = int(host_fetch(moved))
             total_i = int(host_fetch(total))
             mig_by_np = np.asarray(host_fetch(mig_by), np.int64)
             mig_shipped_i = int(host_fetch(mig_shipped))
             mig_ov_i = int(host_fetch(mig_ov))
-        rel_mig = float(moved_i) / max(float(total_i), 1e-9)
-        mig_rows = self.num_workers * lane_cap  # rows received per worker
         # rows/wall are recorded by process_batch (one call per migration);
         # the hot-lane vector is only available here, so it rides a
         # zero-row record into the same telemetry window (device array —
@@ -1178,8 +1189,16 @@ class StreamingJob:
         self.telemetry.record_exchange(ExchangeStats(
             rows=0, lane_overflow=mig_lane_ov
         ))
-        return (rel_mig, mig_ov_i, mig_rows, plan_rows,
-                mig_shipped_i // self.num_workers, moved_i, mig_by_np)
+        return _Migration(
+            relative=float(moved_i) / max(float(total_i), 1e-9),
+            overflow=mig_ov_i,
+            buffer_rows=self.num_workers * lane_cap,  # rows received per worker
+            plan_rows=plan_rows,
+            peak_rows=peak,
+            shipped=mig_shipped_i // self.num_workers,
+            moved=moved_i,
+            by_class=mig_by_np,
+        )
 
     # ------------------------------------------------------------------
     def run(self, batches: Iterable[np.ndarray]) -> list[BatchMetrics]:
@@ -1265,6 +1284,7 @@ class StreamingJob:
         self._shuffle = None
         self._shuffle_sig = None
         self._migrate_steps.clear()
+        self._migrate_route = None
         self._pending_resize = None
         if not _keep_recovery_log:
             # an external restore starts a fresh failure epoch: the old

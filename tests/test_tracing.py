@@ -20,6 +20,7 @@ from repro import compat
 from repro.core.drm import DRConfig
 from repro.core.streaming import SPANS, StreamingJob
 from repro.exchange import FaultPlan, FaultyBackend, LaneFault
+from repro.exchange.spec import DISTANCE_CLASSES
 
 BENCH = Path(__file__).resolve().parents[1] / "chipbench"
 STATE, PARTS, EVENTS = 1 << 12, 8, 1 << 10  # chipbench/tests/test_run.py's sizes
@@ -125,16 +126,22 @@ def test_action_spans(tmp_path):
 
 def test_put_and_fetch_bytes_follow_the_shapes():
     job = _job()
-    ms = job.run(_zipf_batches(3))
+    still = _job(imbalance_trigger=1e9)  # the same batches, never repartitioned
+    batches = _zipf_batches(3)
+    ms, base = job.run(batches), still.run(batches)
     for m in ms:  # int32 keys, float32 values (payload_dim 1), bool valid flags
         assert m.put_bytes == EVENTS * (4 + 4 + 1)
-    moved = [m for m in ms if m.repartitioned]
-    assert moved
-    for m in moved:  # the migration plan reads the whole key table
-        assert m.fetch_bytes >= STATE * 4
-    for m in ms:
-        if not m.repartitioned:
-            assert 0 < m.fetch_bytes < STATE * 4
+    assert any(m.repartitioned for m in ms)
+    assert not any(m.repartitioned for m in base)
+    w = job.num_workers
+    # a migration fetches no key table: at most the [W, W] route counts,
+    # plus control outputs — the pre-action drain's live-row count, the
+    # moved, total, shipped and overflow scalars, the shipped rows by
+    # distance class, and (folded a batch later) the [W] lane overflow
+    control = 4 * (1 + 4 + DISTANCE_CLASSES + w)
+    for m, b in zip(ms, base):
+        assert 0 < m.fetch_bytes < STATE * 4
+        assert m.fetch_bytes - b.fetch_bytes <= 4 * w * w + control
 
 
 def test_counters_add_no_sync():
