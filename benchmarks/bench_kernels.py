@@ -88,7 +88,7 @@ def run(n: int = 8192):
         f"{n} records, {lanes} lanes (slots+counts from the route pass)"))
 
     # double-buffered send sets: reset+scatter into a recycled [L, cap] set
-    # (the depth-2 pipeline's ping-pong pool, donated so XLA rewrites it in
+    # (the streaming driver's ping-pong pool, donated so XLA rewrites it in
     # place) vs. materializing the set fresh every batch.  Values must be
     # bit-identical — reuse is an allocation optimization, not a semantic
     # one.

@@ -10,12 +10,9 @@ on these power-law profiles, and the two backends must produce *exactly*
 the same keyed-state counts — any mismatch raises, failing the bench run
 (the CI bench-smoke gate).
 
-The split-phase pipeline gets its own columns: the blocking exchange wall
-per batch and the drained end-to-end run wall, overlapped driver vs.
-serial, on the skewed profiles (``fig6/exchange_step_wall_ms`` /
-``fig6/overlap_run_wall_ms`` with a ``dense/overlap`` vs. ``dense/serial``
-column), gated on the run wall: overlap <= serial * 1.25 — hiding the row
-ship behind host work must never cost end-to-end time.
+The driver's sync audit gets its own row: a steady-state run must perform
+no blocking device->host transfer between safe points
+(``fig6/host_syncs_per_batch``, gated at 0).
 
 Also measures the elastic-resize cost (rows shipped + wall time for a
 grow 4->8 and a shrink 8->4, next to the plain migration rows) and the
@@ -170,7 +167,7 @@ def run(batches: int = 6, batch_size: int = 16_384):
         dense_wall = sum(d for d, _ in wall_pairs)
         ragged_wall = sum(r for _, r in wall_pairs)
         assert ragged_wall <= dense_wall * 1.25, (ragged_wall, dense_wall)
-    rows.extend(_overlap_cost(batches, batch_size, state_capacity))
+    rows.extend(_sync_free(batches, batch_size, state_capacity))
     rows.extend(_resize_cost(4, 8, batch_size, state_capacity))
     rows.extend(_resize_cost(8, 4, batch_size, state_capacity))
     rows.extend(_nonstationary(batches, batch_size, state_capacity))
@@ -182,140 +179,8 @@ def run(batches: int = 6, batch_size: int = 16_384):
     return rows
 
 
-def _overlap_cost(batches: int, batch_size: int, state_capacity: int):
-    """Latency hiding from the split-phase pipeline: the same skewed stream
-    through the serial driver (blocks on the whole exchange every batch),
-    the overlapped one (blocks on the count phase only; the row ship drains
-    behind the control plane's host work), and the depth-2 one (additionally
-    routes batch N+1 behind batch N's ship, ping-ponging two persistent
-    send-buffer sets).
-
-    Emits the blocking exchange wall per batch under all three modes
-    (reporting: where each driver pays — the serial one inside the batch
-    that acts, the pipelined ones spread over the following count syncs)
-    and gates on the *end-to-end* run wall, drained: overlap <= serial *
-    1.25 and depth2 <= overlap * 1.10, aggregated over the skewed profiles.
-    The first three batches run outside the timed window — they pay the jit
-    (batch 0) and the one-time recompiles when the state and the recycled
-    send buffers first arrive with committed shardings (batches 1-2: the
-    ping-pong pool only fills at the first drain), and the serial and
-    split-phase drivers compile different programs, so including them gates
-    compiler wall, not pipeline wall.  The scenario sizes its own stream
-    (>= 8 batches) so the timed window exists even at the smoke profile.  A small absolute slack keeps the
-    ratio gates meaningful when the timed window is milliseconds (the smoke
-    profile).  Work is conserved, so per-batch blocking wall just moves
-    between modes; the run wall is what latency hiding must actually
-    improve (the slack absorbs shared-CI timer noise).  The depth-2 hidden share of the ship
-    wall must not regress either: mean ``overlap_fraction`` >= depth-1's
-    (small absolute slack for the timer).  All runs must take identical
-    control decisions — pipelining is a scheduling change, not a semantic
-    one — and the ragged transport must agree too: a depth-2 ragged run is
-    held to the serial ragged trajectory and to bit-identical keyed state."""
-    import jax
-
-    rows = []
-    walls = {"serial": 0.0, "overlap": 0.0, "depth2": 0.0}
-    fracs: dict[str, list[float]] = {"overlap": [], "depth2": []}
-    n = max(batches, 8)  # warmup eats 3 batches; keep a real timed window
-    for exp in (1.3, 1.6):
-        stream = list(drifting_zipf(n, batch_size, num_keys=5_000,
-                                    exponent=exp, drift_every=100, seed=int(exp * 11)))
-        jobs = {}
-        for mode, (overlap, depth) in (("serial", (False, 1)),
-                                       ("overlap", (True, 1)),
-                                       ("depth2", (True, 2))):
-            job = StreamingJob(
-                num_partitions=8,
-                state_capacity=state_capacity,
-                dr=DRConfig(imbalance_trigger=1.1, migration_cost_weight=0.2,
-                            overlap_exchange=overlap, pipeline_depth=depth),
-            )
-            ms = job.run(stream[:3])  # untimed: pays the jit + recompiles
-            jax.block_until_ready(job.state_keys)
-            t0 = time.perf_counter()
-            ms += job.run(stream[3:])
-            jax.block_until_ready(job.state_keys)  # drain the pipeline
-            run_wall = time.perf_counter() - t0
-            jobs[mode] = (job, ms)
-            walls[mode] += run_wall
-            if mode in fracs:
-                fracs[mode].extend(m.overlap_fraction for m in ms[1:])
-            rows.append((f"fig6/exchange_step_wall_ms/exp={exp}",
-                         float(np.mean([m.exchange_wall_s for m in ms[1:]])) * 1e3,
-                         "blocking exchange wall per batch", f"dense/{mode}"))
-            rows.append((f"fig6/overlap_run_wall_ms/exp={exp}", run_wall * 1e3,
-                         f"end-to-end drained, {n - 3} timed batches",
-                         f"dense/{mode}"))
-        if len(stream) > 4:
-            # the smoke profile is too short to guarantee a staged batch
-            # survives its predecessor's safe point (actions drop the
-            # stage); _sync_free gates engagement on the calm profile
-            assert any(m.pipelined for m in jobs["depth2"][1]), "depth-2 never staged"
-        acts = {mode: [(m.action, m.reason, m.overflow, m.shipped_rows)
-                       for m in ms] for mode, (_, ms) in jobs.items()}
-        if not (acts["serial"] == acts["overlap"] == acts["depth2"]):
-            raise AssertionError(f"pipelining changed the trajectory at exp={exp}: {acts}")
-        # bit-identity: the depth-2 run's keyed state vs. the serial answer
-        sample = np.unique(np.concatenate(stream))[::64]
-        for key in sample:
-            got = {mode: job.state_count(int(key)) for mode, (job, _) in jobs.items()}
-            if len(set(got.values())) != 1:
-                raise AssertionError(f"depth-2 count mismatch at key={int(key)}: {got}")
-    rows.append(("fig6/overlap_run_wall_ratio",
-                 walls["overlap"] / max(walls["serial"], 1e-12),
-                 "overlapped run wall / serial (lower = more hidden)"))
-    rows.append(("fig6/depth2_run_wall_ratio",
-                 walls["depth2"] / max(walls["overlap"], 1e-12),
-                 "depth-2 run wall / depth-1 (gate: <= 1.10)"))
-    assert walls["overlap"] <= walls["serial"] * 1.25 + 0.05, walls
-    assert walls["depth2"] <= walls["overlap"] * 1.10 + 0.05, walls
-    f1 = float(np.mean(fracs["overlap"]))
-    f2 = float(np.mean(fracs["depth2"]))
-    rows.append(("fig6/overlap_fraction/depth1", f1,
-                 "mean hidden/(hidden+ship) wall share, depth-1"))
-    rows.append(("fig6/overlap_fraction/depth2", f2,
-                 "mean hidden/(hidden+ship) wall share, depth-2 (gate: >= depth-1)"))
-    assert f2 >= f1 - 0.05, (f2, f1)  # slack: sub-ms timer on shared CI
-    rows.extend(_ragged_depth2(batches, batch_size, state_capacity))
-    rows.extend(_sync_free(batches, batch_size, state_capacity))
-    return rows
-
-
-def _ragged_depth2(batches: int, batch_size: int, state_capacity: int):
-    """The depth-2 pipeline over the count-first transport: same trajectory
-    and bit-identical keyed state as the serial ragged run (the transport
-    and the pipeline depth are independent axes; both backends honor the
-    persistent buffer seam)."""
-    stream = list(drifting_zipf(batches, batch_size, num_keys=5_000,
-                                exponent=1.6, drift_every=100, seed=23))
-    jobs = {}
-    for mode, (overlap, depth) in (("serial", (False, 1)), ("depth2", (True, 2))):
-        job = StreamingJob(
-            num_partitions=8,
-            state_capacity=state_capacity,
-            dr=DRConfig(imbalance_trigger=1.1, migration_cost_weight=0.2,
-                        overlap_exchange=overlap, pipeline_depth=depth),
-            exchange_backend="ragged",
-        )
-        job.drm.exchange_backend = resolve_backend("dense")  # pin pricing
-        jobs[mode] = (job, job.run(stream))
-    acts = {mode: [(m.action, m.reason, m.overflow, m.shipped_rows)
-                   for m in ms] for mode, (_, ms) in jobs.items()}
-    if acts["serial"] != acts["depth2"]:
-        raise AssertionError(f"ragged depth-2 changed the trajectory: {acts}")
-    sample = np.unique(np.concatenate(stream))[::64]
-    for key in sample:
-        got = {mode: job.state_count(int(key)) for mode, (job, _) in jobs.items()}
-        if len(set(got.values())) != 1:
-            raise AssertionError(f"ragged depth-2 count mismatch key={int(key)}: {got}")
-    shipped = sum(m.shipped_rows for m in jobs["depth2"][1])
-    return [("fig6/depth2_ragged_shipped_rows", shipped,
-             f"rows shipped, ragged transport under the depth-2 driver "
-             f"({batches} batches)")]
-
-
 def _sync_free(batches: int, batch_size: int, state_capacity: int):
-    """The CI sync-audit gate: a steady-state depth-2 run (triggers parked,
+    """The CI sync-audit gate: a steady-state run (triggers parked,
     every safe point a noop) must perform *zero* audited host transfers
     between safe points — every device->host fetch in the driver goes
     through ``compat.host_fetch`` inside a declared ``safe_point`` region,
@@ -328,18 +193,17 @@ def _sync_free(batches: int, batch_size: int, state_capacity: int):
     job = StreamingJob(
         num_partitions=8,
         state_capacity=state_capacity,
-        dr=DRConfig(imbalance_trigger=1e9, pipeline_depth=2),
+        dr=DRConfig(imbalance_trigger=1e9),
     )
-    job.run(stream[:2])  # warmup: compile + fill the pipeline
+    job.run(stream[:2])  # warmup: compile + put a finish in flight
     compat.reset_host_sync_count()
     ms = job.run(stream[2:])
     syncs = compat.host_sync_count()
     assert syncs == 0, f"{syncs} host syncs outside safe points"
     assert all(m.action == "noop" for m in ms)
-    assert all(m.pipelined for m in ms[1:])
     return [("fig6/host_syncs_per_batch", syncs / max(len(ms), 1),
              f"audited transfers outside safe points over {len(ms)} steady "
-             "depth-2 batches (gate: 0)")]
+             "batches (gate: 0)")]
 
 
 def _decision_rows(tag: str, job: StreamingJob):
@@ -755,50 +619,29 @@ def _failure(batches: int, batch_size: int):
 
 def _fault_free_identity(batches: int, batch_size: int, state_capacity: int):
     """An installed, never-firing FaultPlan must be bit-identical to no
-    seam at all — serial, depth-1 and depth-2 drivers alike (the seam
-    fires at the host boundary; the traced program is untouched).  Runs
-    in-process on the single-device mesh; the 8-shard version gates in
-    tests/test_distributed.py."""
+    seam at all (the seam fires at the host boundary; the traced program is
+    untouched).  Runs in-process on the single-device mesh; the 8-shard
+    version gates in tests/test_distributed.py."""
     from repro.exchange import FaultPlan, FaultyBackend
 
     stream = [zipf_keys(batch_size, num_keys=2_000, exponent=1.3, seed=s)
               for s in range(max(batches, 4))]
-    rows = []
-    modes = {
-        "serial": dict(dr=dict(pipeline_depth=1), env="1"),
-        "depth1": dict(dr=dict(pipeline_depth=1), env=None),
-        "depth2": dict(dr=dict(pipeline_depth=2), env=None),
-    }
-    for mode, spec in modes.items():
-        prev = os.environ.get("REPRO_DISABLE_OVERLAP")
-        if spec["env"] is not None:
-            os.environ["REPRO_DISABLE_OVERLAP"] = spec["env"]
-        try:
-            acts = {}
-            for tag, backend in (("plain", "dense"),
-                                 ("seamed", FaultyBackend("dense",
-                                                          FaultPlan()))):
-                job = StreamingJob(
-                    num_partitions=8, state_capacity=state_capacity,
-                    dr=DRConfig(imbalance_trigger=1.1,
-                                migration_cost_weight=0.2, **spec["dr"]),
-                    exchange_backend=backend,
-                )
-                ms = job.run(stream)
-                acts[tag] = ([(m.action, m.reason, m.overflow,
-                               m.shipped_rows) for m in ms],
-                             float(np.asarray(job.state_vals).sum()))
-            assert acts["plain"] == acts["seamed"], (mode, acts)
-        finally:
-            if spec["env"] is not None:
-                if prev is None:
-                    os.environ.pop("REPRO_DISABLE_OVERLAP", None)
-                else:
-                    os.environ["REPRO_DISABLE_OVERLAP"] = prev
-        rows.append((f"fig6/fault_free_identity/{mode}", 1,
-                     "never-firing FaultPlan bit-identical to no seam "
-                     "(trajectory + state mass)"))
-    return rows
+    acts = {}
+    for tag, backend in (("plain", "dense"),
+                         ("seamed", FaultyBackend("dense", FaultPlan()))):
+        job = StreamingJob(
+            num_partitions=8, state_capacity=state_capacity,
+            dr=DRConfig(imbalance_trigger=1.1, migration_cost_weight=0.2),
+            exchange_backend=backend,
+        )
+        ms = job.run(stream)
+        acts[tag] = ([(m.action, m.reason, m.overflow, m.shipped_rows)
+                      for m in ms],
+                     float(np.asarray(job.state_vals).sum()))
+    assert acts["plain"] == acts["seamed"], acts
+    return [("fig6/fault_free_identity", 1,
+             "never-firing FaultPlan bit-identical to no seam "
+             "(trajectory + state mass)")]
 
 
 def _resize_cost(base_n: int, target_n: int, batch_size: int, state_capacity: int):

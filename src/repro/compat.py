@@ -13,22 +13,16 @@
   contiguously from slot 0, so lane ``i``'s live rows start at row
   ``i * capacity`` of the flattened send buffer.
 
-Runtime escape hatches (environment variables):
+Runtime escape hatch (environment variable):
 
 * ``REPRO_DISABLE_NATIVE_RAGGED=1`` — force the masked-dense ragged
   transport even on TPU (see :func:`native_ragged`).
-* ``REPRO_DISABLE_OVERLAP=1`` — force the streaming driver's serial
-  exchange path even when ``DRConfig.overlap_exchange`` is on (see
-  :func:`overlap_enabled`): batch N+1's route/count phase no longer issues
-  before batch N's row ship drains.  The two paths are bit-identical — the
-  serial step *is* the split-phase pipeline run back to back — so this is a
-  debugging/benching lever, not a correctness switch.
 
 Host-sync instrumentation (``host_fetch`` / ``safe_point`` /
 ``host_sync_count``) also lives here: the streaming driver routes its
 device->host conversions through :func:`host_fetch`, which counts fetches
 of device arrays performed outside a ``with safe_point():`` region.  The
-counter is how benches prove the depth-2 pipeline's "zero blocking
+counter is how benches prove the streaming driver's "zero blocking
 transfers between safe points" contract.  ``host_fetch_bytes`` adds up the
 bytes every fetch moved, inside safe points or not.
 """
@@ -44,7 +38,6 @@ import numpy as np
 __all__ = [
     "ragged_all_to_all",
     "native_ragged",
-    "overlap_enabled",
     "host_fetch",
     "host_fetch_bytes",
     "host_sync_count",
@@ -60,7 +53,7 @@ __all__ = [
 # the steady-state loop goes through :func:`host_fetch`; fetches of device
 # arrays outside a ``with safe_point():`` region increment
 # ``host_sync_count``.  Benches and tests read the counter to prove the
-# depth-2 pipeline performs zero blocking transfers between safe points —
+# streaming driver performs zero blocking transfers between safe points —
 # a nonzero delta on a no-action batch pinpoints a leaked sync.
 
 _sync_state = {"count": 0, "depth": 0, "bytes": 0}
@@ -104,19 +97,6 @@ def host_fetch(x):
         if _sync_state["depth"] == 0:
             _sync_state["count"] += 1
     return np.asarray(x)
-
-
-def overlap_enabled() -> bool:
-    """True unless ``REPRO_DISABLE_OVERLAP`` forces the serial exchange path.
-
-    The streaming driver overlaps batch N+1's start phase with batch N's
-    in-flight row ship when this *and* ``DRConfig.overlap_exchange`` hold;
-    the env var is the bench/debug escape hatch for A/B-ing the two
-    bit-identical paths on one build.  (``0``/``false``/unset leave the
-    overlap on.)
-    """
-    disabled = os.environ.get("REPRO_DISABLE_OVERLAP", "")
-    return disabled.lower() in ("", "0", "false")
 
 
 def native_ragged(mesh=None) -> bool:

@@ -156,10 +156,9 @@ class Signals:
     @property
     def overlap_fraction(self) -> float:
         """Share of the exchange's ship wall the split-phase pipeline hid
-        behind host work this window: ``hidden / (hidden + ship)``.  0.0 for
-        a serial window (nothing hidden) and when no phase walls were
-        recorded at all — the serial path records only the fused
-        ``exchange_wall_s``, so existing consumers are untouched."""
+        behind host work this window: ``hidden / (hidden + ship)``.  0.0
+        when no phase walls were recorded (a record with only the whole
+        ``exchange_wall_s`` carries none)."""
         total = self.exchange_hidden_wall_s + self.exchange_ship_wall_s
         if total <= 0.0:
             return 0.0

@@ -64,28 +64,9 @@ __all__ = ["DRConfig", "DRMaster", "DRDecision"]
 class DRConfig:
     """Control-plane configuration for the DR module (one frozen record).
 
-    Most fields tune one policy each (see the inline comments); the
-    exchange-pipeline knobs interact and deserve spelling out:
+    Most fields tune one policy each (see the inline comments); one
+    deserves spelling out:
 
-    * ``overlap_exchange`` (default on) — the streaming driver issues batch
-      N+1's route/count phase before batch N's row ship drains (pipeline
-      depth 1 of latency hiding).  Bit-identical to the serial driver by
-      construction.
-    * ``pipeline_depth`` — ``1`` keeps the ship-behind-host-work overlap;
-      ``2`` additionally pre-routes batch N+1 (route -> bucketize -> start)
-      before batch N's decision section runs, so the device pipeline holds
-      two in-flight stages and the per-batch start sync costs ~nothing.
-      Any taken control action first drains *both* stages and replays the
-      pre-routed batch under the new partitioner, so trajectories stay
-      bit-identical to serial.  Values outside ``{1, 2}`` raise
-      ``ValueError`` at construction.  Depth 2 engages only in
-      ``StreamingJob.run`` (the driver needs one batch of lookahead);
-      direct ``process_batch`` calls degrade gracefully to depth 1.
-    * ``REPRO_DISABLE_OVERLAP=1`` (environment) — forces the serial
-      exchange path regardless of ``overlap_exchange`` *and*
-      ``pipeline_depth``, in ``StreamingJob`` and ``DRScheduler`` both.
-      The bench/debug escape hatch for A/B-ing the bit-identical paths on
-      one build; ``0`` / ``false`` / unset leave the overlap on.
     * ``split_least_load`` — replica pick for split hot keys: off (default)
       every route uses the stateless fmix32 offset (TPU Pallas kernel
       eligible); on, the jnp route twin picks the lower-loaded of two
@@ -138,15 +119,6 @@ class DRConfig:
                                      # zone that stops split/unsplit churn)
     split_patience: int = 2          # consecutive safe points before acting
     split_cooldown: int = 0          # min safe points between split actions
-    # -- split-phase exchange overlap --------------------------------------
-    overlap_exchange: bool = True    # issue batch N+1's route/count phase
-                                     # before batch N's row ship drains
-                                     # (bit-identical to serial; env escape
-                                     # hatch: REPRO_DISABLE_OVERLAP=1)
-    pipeline_depth: int = 1          # 1 = ship-behind-host-work overlap;
-                                     # 2 = additionally pre-route batch N+1
-                                     # before batch N's decision section
-                                     # (see the class docstring)
     split_least_load: bool = False   # two-choice least-load replica pick
                                      # for split hot keys (jnp route twin;
                                      # statically gates the Pallas kernel
@@ -172,11 +144,6 @@ class DRConfig:
                                      # (0 = never re-admit)
 
     def __post_init__(self):
-        if self.pipeline_depth not in (1, 2):
-            raise ValueError(
-                f"pipeline_depth must be 1 (ship-behind-host-work overlap) or "
-                f"2 (batch-ahead route), got {self.pipeline_depth!r}"
-            )
         # knob relationships are validated unconditionally — a config whose
         # dead zones are inverted is wrong even while its feature flag is
         # off (it used to fail silently the day the flag turned on)

@@ -12,16 +12,14 @@ entirely on the unified exchange plane (``repro.exchange``):
 3. the DRW hook emits the local top-k histogram + global per-partition loads
    (a ``psum`` — reusing normal DDPS communication, as the paper requires).
 
-The step is **split-phase**: the factories below expose a fused serial step
-(exactly the historical call) *plus* ``.start`` / ``.finish`` halves built
-from the same per-worker locals.  ``start`` runs route + bucketize + the
+The step is **split-phase**: the factories below build two programs,
+``.start`` and ``.finish``.  ``start`` runs route + bucketize + the
 transport's control phase and returns every control-plane output (loads,
 histograms, overflow, shipped rows) with the un-shipped buffers as an
-opaque pending value; ``finish`` ships the rows.  Because the serial step
-is literally ``finish_local(start_local(...))`` traced into one program,
-the overlapped driver (``repro.core.streaming``) that holds ``finish`` in
-flight across a batch boundary is bit-identical to the serial one by
-construction.
+opaque pending value; ``finish`` ships the rows.  The streaming driver
+(``repro.core.streaming``) holds ``finish`` in flight across a batch
+boundary; the factory's own return value runs ``finish(start(...))`` on
+the host, for callers that want one exchange whole.
 
 Partitions may outnumber workers (over-partitioning, paper Fig. 5);
 ``worker = partition % W``.
@@ -166,16 +164,14 @@ def make_shuffle_step(
 ):
     """Build the jitted shuffle step for a fixed mesh/capacity/topology.
 
-    Returns the fused serial step (the historical call: ``step(tables,
-    keys, vals, valid) -> ShuffleResult``) with two extra callables attached
-    for the overlapped driver:
+    Returns ``step(tables, keys, vals, valid) -> ShuffleResult``, which
+    runs the two jitted phases back to back on the host, with the phases
+    attached for the streaming driver:
 
     * ``step.start(tables, keys, vals, valid) -> (pending, ShuffleStart)``
     * ``step.finish(pending) -> (keys, values, valid, part)`` stacked [W, ...]
 
-    The serial step traces ``finish_local(start_local(...))`` into one
-    program, so ``start`` + ``finish`` is bit-identical to it by
-    construction.  An elastic resize rebuilds the step: ``num_partitions``
+    An elastic resize rebuilds the step: ``num_partitions``
     fixes the loads vector width, so the new topology needs a new closure
     (the migrate step does *not* — it routes at worker granularity, see
     :func:`make_migrate_step`).  ``backend`` selects the exchange transport
@@ -185,8 +181,9 @@ def make_shuffle_step(
     ``finish`` recycles each drained pending's buffer set into a two-set
     ping-pong pool and the next ``start`` scatters into a recycled set
     (donated, so XLA rewrites it in place) instead of allocating fresh —
-    at pipeline depth 2 one set is still in flight while the other is
-    being filled.  Values are bit-identical to the fresh path.
+    the driver enqueues batch N's start before batch N-1's finish, so one
+    set is still in flight while the other is being filled.  Values are
+    bit-identical to the fresh path.
 
     ``least_load=True`` (static) switches the split-key replica pick to
     the two-choice least-load tiebreak: ``step``/``step.start`` accept a
@@ -237,7 +234,7 @@ def make_shuffle_step(
         return _pack_pending(started), start
 
     # the mapped functions' names become the programs' names in a device
-    # trace: jit_shuffle_start, jit_shuffle_finish, jit_shuffle_step
+    # trace: jit_shuffle_start, jit_shuffle_finish
     def shuffle_start(tables, keys, vals, valid, bufs, part_loads):
         return _start_core(tables, keys, vals, valid, bufs, part_loads)
 
@@ -246,16 +243,6 @@ def make_shuffle_step(
         rva, (rk, rv, rp) = res.unpack()
         return rk[None], rv[None], rva[None], rp[None]
 
-    def shuffle_step(tables, keys, vals, valid, part_loads):
-        # the fused serial step's send buffers never cross the jit boundary,
-        # so there is nothing to recycle — fresh transient buffers (bufs
-        # None) keep the trace identical to the pre-reuse step
-        pending, start = _start_core(tables, keys, vals, valid, None, part_loads)
-        rk, rv, rva, rp = shuffle_finish(pending)
-        return (rk, rv, rva, rp, start.loads, start.hist_keys, start.hist_counts,
-                start.overflow, start.lane_overflow, start.shipped_rows,
-                start.shipped_rows_by_class)
-
     in_specs = (
         (P(), P(), P(), P()),  # partitioner tables replicated
         P(axis),  # keys sharded over workers
@@ -263,12 +250,6 @@ def make_shuffle_step(
         P(axis),
     )
     bufs_spec = (P(axis), (P(axis), P(axis), P(axis)))
-    mapped = shard_map(
-        shuffle_step, mesh=mesh, in_specs=in_specs + (P(),),
-        out_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(axis), P(axis),
-                   P(), P(), P(), P()),
-        check_vma=False,
-    )
     start_mapped = shard_map(
         shuffle_start, mesh=mesh, in_specs=in_specs + (bufs_spec, P()),
         out_specs=(P(axis), ShuffleStart(P(), P(axis), P(axis), P(), P(), P(), P())),
@@ -286,9 +267,7 @@ def make_shuffle_step(
     # finish phase must NOT donate: each drained pending's buffers re-enter
     # the ping-pong pool, so they have to survive the ship.  (CPU has no
     # donation — skip the warning.)
-    donate = () if jax.default_backend() == "cpu" else (1, 2, 3)
     start_donate = () if jax.default_backend() == "cpu" else (1, 2, 3, 4)
-    jstep = jax.jit(mapped, donate_argnums=donate)
     jstart = jax.jit(start_mapped, donate_argnums=start_donate)
     jfinish = jax.jit(finish_mapped)
 
@@ -304,12 +283,6 @@ def make_shuffle_step(
              jnp.zeros(shape + vals.shape[1:], vals.dtype),
              jnp.zeros(shape, jnp.int32)),
         ), buf_sharding)
-
-    def step(tables: PartitionerTables, keys, vals, valid,
-             part_loads=None) -> ShuffleResult:
-        maybe_inject(ex.backend, "shuffle")  # host boundary: faults fire here
-        pl = zero_loads if part_loads is None else part_loads
-        return ShuffleResult(*jstep(tuple(tables), keys, vals, valid, pl))
 
     def start(tables: PartitionerTables, keys, vals, valid, part_loads=None):
         maybe_inject(ex.backend, "shuffle")
@@ -327,9 +300,14 @@ def make_shuffle_step(
         if len(recycled) < 2:
             # the drained pending's buffers become the next idle set — two
             # sets bound the pool because at most two exchanges are in
-            # flight (pipeline depth 2)
+            # flight (batch N's start is enqueued before N-1's finish)
             recycled.append((pending.valid, pending.payloads))
         return out
+
+    def step(tables: PartitionerTables, keys, vals, valid,
+             part_loads=None) -> ShuffleResult:
+        pending, started = start(tables, keys, vals, valid, part_loads)
+        return ShuffleResult(*finish(pending), *started)
 
     step.start = start
     step.finish = finish
@@ -440,13 +418,14 @@ def make_migrate_step(
     one step serves any partition count — a resize migration reuses the
     same jit cache.
 
-    Returns the fused step (kept state + received rows + moved and live
-    rows + overflow + per-lane overflow + globally shipped rows + shipped
-    rows by distance class) with ``.start`` / ``.finish`` halves attached:
+    Returns ``migrate(where, state_keys, state_vals)``, which runs the two
+    jitted phases back to back on the host (kept state + received rows +
+    moved and live rows + overflow + per-lane overflow + globally shipped
+    rows + shipped rows by distance class), with the phases attached:
     ``start`` keeps every control output and the kept state local (the
-    ship stays pending), ``finish`` ships the moving rows — the overlapped
-    driver leaves it in flight across the safe point.  Both donate the
-    state tables, which a separate route only read.
+    ship stays pending), ``finish`` ships the moving rows — the streaming
+    driver leaves it in flight across the safe point.  ``start`` donates
+    the state tables, which a separate route only read.
     """
     num_workers = mesh.shape[axis]
     if spec is None:
@@ -491,8 +470,8 @@ def make_migrate_step(
         return (_pack_pending(started), kept_keys[None], state_vals[None],
                 r.moved, r.total, overflow, lane_overflow, shipped, by_class)
 
-    # programs jit_migrate_start, jit_migrate_finish, jit_migrate_step in a
-    # device trace, apart from the shuffle's
+    # programs jit_migrate_start and jit_migrate_finish in a device trace,
+    # apart from the shuffle's
     def migrate_start(where, state_keys, state_vals, bufs):
         return _start_core(where, state_keys, state_vals, bufs)
 
@@ -501,27 +480,17 @@ def make_migrate_step(
         rva, (rk, rv) = res.unpack()
         return rk[None], rv[None], rva[None]
 
-    def migrate_step(where, state_keys, state_vals):
-        pending, kk, vv, *control = _start_core(where, state_keys, state_vals, None)
-        rk, rv, rva = migrate_finish(pending)
-        return (kk, vv, rk, rv, rva, *control)
-
     bufs_spec = (P(axis), (P(axis), P(axis)))
     control = (P(),) * 6
-    # donate the state tables: the kept/received outputs alias them, so the
+    # donate the state tables: the kept outputs alias them, so the
     # exchange compaction doesn't double-allocate the state; the recycled
     # send-buffer set (arg 3 of start) is donated and rewritten in place.
     # finish keeps its pending alive — drained sets re-enter the ping-pong
     # pool (CPU: no donation at all).
-    donate = () if jax.default_backend() == "cpu" else (1, 2)
     start_donate = () if jax.default_backend() == "cpu" else (1, 2, 3)
-    jmig, jstart = {}, {}  # keyed by whether the call brings a MigrateRoute
+    jstart = {}  # keyed by whether the call brings a MigrateRoute
     for routed, where_spec in ((False, tables_spec), (True, route_spec)):
         in_specs = (where_spec, P(axis), P(axis))
-        jmig[routed] = jax.jit(shard_map(
-            migrate_step, mesh=mesh, in_specs=in_specs,
-            out_specs=(P(axis),) * 5 + control, check_vma=False,
-        ), donate_argnums=donate)
         jstart[routed] = jax.jit(shard_map(
             migrate_start, mesh=mesh, in_specs=in_specs + (bufs_spec,),
             out_specs=(P(axis),) * 3 + control, check_vma=False,
@@ -546,11 +515,6 @@ def make_migrate_step(
         routed = isinstance(where, MigrateRoute)
         return routed, (where if routed else tuple(where))
 
-    def migrate(where, state_keys, state_vals):
-        maybe_inject(ex.backend, "migrate")  # host boundary: faults fire here
-        routed, where = _where(where)
-        return jmig[routed](where, state_keys, state_vals)
-
     def start(where, state_keys, state_vals):
         maybe_inject(ex.backend, "migrate")
         bufs = recycled.pop() if recycled else None
@@ -567,6 +531,10 @@ def make_migrate_step(
         if len(recycled) < 2:
             recycled.append((pending.valid, pending.payloads))
         return out
+
+    def migrate(where, state_keys, state_vals):
+        pending, kept_keys, kept_vals, *control = start(where, state_keys, state_vals)
+        return (kept_keys, kept_vals, *finish(pending), *control)
 
     migrate.start = start
     migrate.finish = finish
@@ -593,10 +561,10 @@ def shuffle_stats(
     """:class:`ExchangeStats` for one shuffle step.
 
     ``ShuffleResult`` and ``ShuffleStart`` share every control field this
-    reads (loads / overflow / lane_overflow / shipped_rows), so the serial
-    and overlapped drivers construct identical records.  Rows are per worker
-    (the globally-psummed counters divided by ``num_workers``); ``padded``
-    is the spec's per-worker provision.
+    reads (loads / overflow / lane_overflow / shipped_rows), so either
+    constructs the same record.  Rows are per worker (the globally-psummed
+    counters divided by ``num_workers``); ``padded`` is the spec's
+    per-worker provision.
 
     Sync-free: device inputs stay device-side — the per-worker arithmetic
     runs as (async) jnp ops and the record carries device scalars, which

@@ -48,41 +48,25 @@ like a resize rebuilds them for a new lane count, the switch lands in the
 ``DecisionLog``/``BatchMetrics``, and snapshots carry the active backend so
 a restore resumes on the switched transport.
 
-**Latency-hiding overlap** (``DRConfig.overlap_exchange``, on by default;
-``REPRO_DISABLE_OVERLAP=1`` forces serial): the shuffle step is
-split-phase (``repro.core.shuffle``), and every control-plane input —
-loads, DRW histograms, overflow, shipped rows — comes out of the *start*
-phase (route + bucketize + the transport's count phase).  The driver
-therefore enqueues batch N's start, enqueues batch N-1's in-flight row
-ship + state merge behind it, and blocks only on batch N's start outputs:
-the host-side decision section (telemetry, sketch update, policy stack)
-runs while the device ships batch N-1's rows.  Because devices execute
-their queue in order and the serial step is literally the two phases
-traced back to back, the overlapped trajectory is bit-identical to the
-serial one — same actions, same state, same overflow.  State only
-materializes at *drains*: before any taken action (a migration must see
-the previous batch merged), at ``snapshot``/``state_count``/direct state
-reads, all of which complete the in-flight finish first.  A repartition's
-own row ship is likewise left in flight across the safe point — only its
-count phase blocks.  Per-phase walls land in telemetry
-(``Signals.exchange_count_wall_s`` / ``exchange_ship_wall_s`` /
-``exchange_hidden_wall_s`` -> ``overlap_fraction``); the hidden wall of a
-batch is recorded when the batch ends, so it lands one window late.
-
-**Depth-2 pipeline** (``DRConfig.pipeline_depth = 2``; overlap must be
-active): ``run`` gives the driver one batch of lookahead, and
-``process_batch`` enqueues the *next* batch's route + bucketize + count
-phase right after this batch's count sync — behind the in-flight ship —
-so at steady state two stages live on the device queue: batch N's ship +
-merge and batch N+1's start.  The send buffers ping-pong between two
-persistent sets (``repro.core.shuffle``), so the pipeline re-fills
-buffers in place instead of allocating per batch.  The staged start
-routes with today's partitioner; when the safe point takes an action
-(resize / repartition / split / backend switch) the driver drains both
-in-flight stages, discards the staged start, and the pre-routed batch
-replays under the new partitioner when it arrives — trajectories stay
-bit-identical to the serial driver.  ``REPRO_DISABLE_OVERLAP=1`` forces
-serial whatever the configured depth.
+**The schedule.**  Every batch runs split-phase (``repro.core.shuffle``):
+the shuffle's *start* program (route + bucketize + the transport's count
+phase) yields every control-plane input — loads, DRW histograms, overflow,
+shipped rows — and its *finish* program ships the rows, which the merge
+folds into the keyed state.  The driver enqueues batch N's start, enqueues
+batch N-1's finish + merge behind it, and blocks only on batch N's start
+outputs: the host-side decision section (telemetry, sketch update, policy
+stack) runs while the device ships and merges batch N-1.  Two exchanges
+are thus in flight at once, and their send buffers alternate between two
+recycled sets.  State only materializes at *drains*: before any taken
+action (a migration must see the previous batch merged, and a backend
+switch rebuilds the programs the in-flight finish came from), and at
+``snapshot``/``state_count``/direct state reads, all of which complete
+the in-flight finish first.  A migration is split the same way: its count
+phase blocks, its row ship + merge stays in flight across the safe point.
+Per-phase walls land in telemetry (``Signals.exchange_count_wall_s`` /
+``exchange_ship_wall_s`` / ``exchange_hidden_wall_s`` ->
+``overlap_fraction``); the hidden wall of a batch is recorded when the
+batch ends, so it lands one window late.
 
 **Host-sync discipline**: every device->host read in the driver routes
 through :func:`repro.compat.host_fetch` inside a
@@ -99,8 +83,8 @@ stretch in which the device waits on the host belongs to one phase.  ``stream.ba
 batch and carries its index; its children cover it with no holes:
 
 * ``stream.feed`` — pad and cast the batch, build the step, put the keys,
-  values and valid flags, and enqueue the start (or take the staged one);
-  also the depth-2 lookahead's puts and start;
+  values and valid flags, enqueue the start, then the previous batch's
+  finish + merge;
 * ``stream.count_sync`` — wait for the start phase's loads;
 * ``dr.observe`` — exchange stats, telemetry records, the DRW histogram
   fetch and ``DRMaster.observe``;
@@ -137,12 +121,10 @@ from repro.compat import (
     host_fetch,
     host_fetch_bytes,
     native_ragged,
-    overlap_enabled,
     safe_point,
 )
 from repro.control import (
     Evict,
-    NoOp,
     Quarantine,
     Recover,
     Repartition,
@@ -231,15 +213,12 @@ class BatchMetrics:
     shipped_rows: int = 0       # rows the backend moved this batch (per worker)
     padded_rows: int = 0        # rows the specs provisioned (per worker)
     backend: str = "dense"      # exchange backend the batch ran on
-    exchange_wall_s: float = 0.0  # wall blocking on the shuffle exchange path
-                                  # (overlapped batches: the count phase only
-                                  # — the ship is hidden behind host work)
-    overlapped: bool = False    # the batch ran the split-phase pipeline
-    pipelined: bool = False     # the batch consumed a depth-2 staged start
-                                # (its route ran behind the previous ship)
+    exchange_wall_s: float = 0.0  # wall blocking on the shuffle exchange
+                                  # path: the count phase only — the ship
+                                  # is hidden behind host work
     overlap_fraction: float = 0.0  # hidden / (hidden + ship) wall this
                                 # window (lags one batch: the hidden wall is
-                                # only known at batch end); 0.0 when serial
+                                # only known at batch end)
     split_keys: int = 0         # hot keys replicated after this safe point
     shipped_rows_by_class: tuple = (0, 0, 0)  # shipped_rows split by lane
                                 # distance class (self / intra-host /
@@ -385,19 +364,13 @@ class StreamingJob:
         sk, sv = empty_state(state_capacity, payload_dim)
         self._sk = self._shard(jnp.tile(sk[None], (self.num_workers, 1)))
         self._sv = self._shard(jnp.tile(sv[None], (self.num_workers, 1, 1)))
-        # split-phase overlap: the previous batch's in-flight finish+merge
+        # split-phase schedule: the previous batch's in-flight finish+merge
         # (a callable that enqueues it), the host wall start of the section
         # a pending ship is hiding behind, and the state-row count as of the
         # last drain (reading it live would sync the in-flight merge chain)
         self._inflight = None
         self._hidden_since: float | None = None
         self._last_state_rows = 0
-        # depth-2 pipeline (``DRConfig.pipeline_depth == 2``): ``run`` parks
-        # the lookahead batch here, ``process_batch`` stages its start behind
-        # the current ship, and a taken action discards the staged route so
-        # the batch replays under the new partitioner
-        self._next_batch: np.ndarray | None = None
-        self._staged: tuple | None = None  # (src, partitioner, step, pending, ShuffleStart)
         # least-load split routing (``DRConfig.split_least_load``): the
         # previous batch's measured per-partition loads, fed to the route at
         # safe points; None until the first batch lands (and after a resize
@@ -441,67 +414,6 @@ class StreamingJob:
     @state_vals.setter
     def state_vals(self, v):
         self._sv = v
-
-    def _overlap_active(self) -> bool:
-        return self.drm.config.overlap_exchange and overlap_enabled()
-
-    def _depth2_active(self) -> bool:
-        # the env kill switch wins over the configured depth too: serial
-        # means serial, whatever the pipeline was asked to do
-        return self._overlap_active() and self.drm.config.pipeline_depth >= 2
-
-    def _discard_staged(self) -> None:
-        """Drop the staged lookahead start (its device work completes in the
-        background; the outputs are never read).  The popped send-buffer set
-        is lost to the ping-pong pool — the next start allocates fresh and
-        the pool refills from drained pendings."""
-        self._staged = None
-
-    def _take_staged(self, raw_keys, has_values: bool):
-        """Claim the staged start if it still routes ``raw_keys`` correctly.
-
-        Valid only when it was staged for this exact batch (object identity
-        — ``run`` hands the same array back), no caller-supplied values
-        (staging assumes the implicit all-ones payload), and the partitioner
-        *and* jitted step are the very objects the staged route used — a
-        taken action swaps the partitioner, a resize / backend switch
-        rebuilds the step, so staleness cannot slip through.  An invalid
-        stage is discarded; the caller re-routes fresh (the replay)."""
-        st, self._staged = self._staged, None
-        if st is None:
-            return None
-        src, part, step, pending, res = st
-        if (not has_values and src is raw_keys
-                and part is self.drm.partitioner and step is self._shuffle):
-            return pending, res
-        return None
-
-    def _stage_next(self, raw: np.ndarray) -> None:
-        """Enqueue the lookahead batch's route + bucketize + count phase
-        behind the current in-flight ship (pipeline depth 2).
-
-        Routes with *today's* partitioner: if the safe point this overlaps
-        takes an action, :meth:`_take_staged` rejects the stage and the
-        batch re-routes under the new partitioner.  Skipped when the
-        lookahead's capacity signature differs from the live step's — the
-        rebuild must not race the batch still using it (that boundary runs
-        at depth 1)."""
-        n = len(raw)
-        w = self.num_workers
-        total = int(np.ceil(n / w)) * w
-        cap = int(np.ceil(self.capacity_factor * total / w / 8.0) * 8)
-        if (cap, self.num_partitions) != self._shuffle_sig:
-            return
-        k = np.concatenate(
-            [raw, np.full(total - n, KEY_SENTINEL, np.int64)]).astype(np.int32)
-        v = np.ones((len(k), self.payload_dim), np.float32)
-        shuffle = self._shuffle
-        pending, res = shuffle.start(
-            self.drm.partitioner.tables(), self._shard(k), self._shard(v),
-            self._shard(k != KEY_SENTINEL),
-            self._part_loads,
-        )
-        self._staged = (raw, self.drm.partitioner, shuffle, pending, res)
 
     def _consume_inflight(self) -> None:
         """Enqueue the pending finish + merge (no sync)."""
@@ -651,10 +563,7 @@ class StreamingJob:
     def _batch(self, keys: np.ndarray, values: np.ndarray | None) -> BatchMetrics:
         t0 = time.perf_counter()
         w = self.num_workers
-        overlap = self._overlap_active()
         with _span("stream.feed"):
-            raw_keys = keys
-            has_values = values is not None
             n = len(keys)
             local_n = int(np.ceil(n / w))
             pad = local_n * w - n
@@ -668,71 +577,39 @@ class StreamingJob:
             valid = keys != KEY_SENTINEL
             self._build(local_n * w)
             batch_backend = self.exchange_backend.name  # the transport this batch rode
-            pipelined = False
 
             t_ex = time.perf_counter()
-            if overlap:
-                # split-phase pipeline: enqueue this batch's start (unless the
-                # depth-2 lookahead already staged it last batch), then the
-                # previous batch's ship + merge behind it, and block only on
-                # the start outputs — devices drain their queue in order, so
-                # the loads sync below waits for the count phase, not the
-                # ship, which runs while the host works through the decision
-                # section
-                shuffle = self._shuffle
-                staged = self._take_staged(raw_keys, has_values)
-                if staged is not None:
-                    pending, res = staged
-                    pipelined = True
-                else:
-                    pending, res = shuffle.start(
-                        self.drm.partitioner.tables(), self._shard(keys),
-                        self._shard(values), self._shard(valid),
-                        self._part_loads,
-                    )
-                self._consume_inflight()
+            # enqueue this batch's start, then the previous batch's ship +
+            # merge behind it, and block only on the start outputs — devices
+            # drain their queue in order, so the loads sync below waits for
+            # the count phase, not the ship, which runs while the host works
+            # through the decision section
+            shuffle = self._shuffle
+            pending, res = shuffle.start(
+                self.drm.partitioner.tables(), self._shard(keys),
+                self._shard(values), self._shard(valid),
+                self._part_loads,
+            )
+            self._consume_inflight()
 
-                def _fin_shuffle(fin=shuffle.finish, pending=pending):
-                    rk, rv, rva, _rp = fin(pending)
-                    self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
+            def _fin_shuffle(fin=shuffle.finish, pending=pending):
+                rk, rv, rva, _rp = fin(pending)
+                self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
 
-                self._inflight = _fin_shuffle
-            else:
-                self._discard_staged()  # overlap turned off mid-stream: re-route
-                self._drain_inflight()
-                res = self._shuffle(
-                    self.drm.partitioner.tables(), self._shard(keys),
-                    self._shard(values), self._shard(valid),
-                    self._part_loads,
-                )
-                # stateful reduce: fold received records into per-worker state
-                self._sk, self._sv = self._merge(
-                    self._sk, self._sv, res.keys, res.values, res.valid
-                )
+            self._inflight = _fin_shuffle
         with _span("stream.count_sync"):
-            # overlapped, this forces the start phase only; serially, the
-            # batch's whole device work
+            # forces the start phase only
             with safe_point():
                 loads = host_fetch(res.loads)
             exchange_wall = time.perf_counter() - t_ex
-            count_wall = exchange_wall if overlap else None
-            # the route reads the *previous* batch's measured loads (identical
-            # in serial / depth-1 / depth-2: all route batch N+1 on batch N's
-            # vector, set here before any lookahead stages)
+            # the next batch's route reads this batch's measured loads
             if self.drm.config.split_least_load:
                 self._part_loads = jnp.asarray(loads, jnp.float32)
-        # depth-2: enqueue the lookahead batch's start now, behind this
-        # batch's in-flight ship — its route + bucketize + count phase run
-        # on the device while the host works through the decision section
-        if self._next_batch is not None and self._depth2_active():
-            with _span("stream.feed"):
-                self._stage_next(self._next_batch)
 
         with _span("dr.observe"):
             # everything the decision section reads below comes out of the
-            # start phase (res is ShuffleStart when overlapped, ShuffleResult
-            # serially — the control fields are shared)
-            self._hidden_since = time.perf_counter() if overlap else None
+            # start phase
+            self._hidden_since = time.perf_counter()
 
             # telemetry: signals gathered during normal work (no extra
             # passes).  shipped is the backend's measured traffic (per
@@ -744,7 +621,7 @@ class StreamingJob:
                 stats = shuffle_stats(
                     res, self._shuffle_spec, w,
                     wall_s=exchange_wall,
-                    count_wall_s=count_wall,
+                    count_wall_s=exchange_wall,
                     backend=batch_backend,
                     # per-replica routing of the split keys (host twin of the
                     # fused kernels' pick — exact, no extra device pass); only
@@ -791,26 +668,21 @@ class StreamingJob:
                 loads=loads,
                 num_workers=w,
                 # reading the live count would sync the in-flight merge chain
-                # — overlapped batches report the count as of the last drain
-                # (no policy keys on exact state rows; the migration planner
-                # reads the real keys after the pre-action drain below)
-                state_rows=self._last_state_rows if overlap else self._state_rows(),
+                # — the count as of the last drain (no policy keys on exact
+                # state rows; a migration routes the real keys after the
+                # pre-action drain below)
+                state_rows=self._last_state_rows,
                 at_safe_point=at_checkpoint,
             )
             action = self.drm.evaluate(signals, requested_resize=requested,
                                        policies_enabled=self.dr_enabled)
 
         # execute the action (state only moves here, at the safe point).
-        # Any taken action drains *both* in-flight stages first: the
-        # pending finish completes — a migration must see this batch's rows
-        # merged (bit-identical to the serial trajectory), and a backend
-        # switch rebuilds the steps the in-flight finish came from — and
-        # the depth-2 staged start is discarded, because its route used the
-        # partitioner this action replaces: the pre-routed batch replays
-        # under the new one when it arrives, exactly as serial would run it.
+        # Any taken action drains the in-flight finish first: a migration
+        # must see this batch's rows merged, and a backend switch rebuilds
+        # the steps the in-flight finish came from.
         if action.taken:
             self._drain_inflight()
-            self._discard_staged()
         mig = _Migration()
         if isinstance(action, Resize):
             mig = self._apply_resize(action.target)
@@ -871,11 +743,9 @@ class StreamingJob:
                 repartitioned=action.taken and action.moves_state,
                 relative_migration=mig.relative,
                 overflow=overflow_i + mig.overflow,
-                # overlapped: the count as of the last drain (exact state rows
-                # would sync the in-flight merge; serial keeps today's numbers)
-                state_rows=(self._last_state_rows if overlap else
-                            (signals.state_rows if isinstance(action, NoOp)
-                             else self._state_rows())),
+                # the count as of the last drain (exact state rows would sync
+                # the in-flight merge)
+                state_rows=self._last_state_rows,
                 wall_time_s=time.perf_counter() - t0,
                 reason=action.reason,
                 migration_rows=mig.buffer_rows,
@@ -888,8 +758,6 @@ class StreamingJob:
                 padded_rows=self._shuffle_spec.rows + mig.buffer_rows,
                 backend=batch_backend,
                 exchange_wall_s=exchange_wall,
-                overlapped=overlap,
-                pipelined=pipelined,
                 overlap_fraction=signals.overlap_fraction,
                 split_keys=len(self.drm.split_keys),
                 shipped_rows_by_class=tuple(int(x) for x in by_class),
@@ -959,9 +827,9 @@ class StreamingJob:
     def _set_workers(self, devices: list) -> None:
         """Rebuild the mesh over ``devices`` and drop everything keyed to
         the old topology: jitted step caches (their shard_maps bound the old
-        mesh), the in-flight/staged pipeline stages, and the least-load
-        vector.  The partitioner is untouched — partitions re-fold onto the
-        new worker count through the modulo placement."""
+        mesh), the in-flight finish, and the least-load vector.  The
+        partitioner is untouched — partitions re-fold onto the new worker
+        count through the modulo placement."""
         self.mesh = Mesh(np.asarray(devices), ("data",))
         self.num_workers = len(devices)
         self._merge = _make_merge(self.mesh)
@@ -972,7 +840,6 @@ class StreamingJob:
         self._part_loads = None
         self._inflight = None
         self._hidden_since = None
-        self._staged = None
 
     def _apply_lane_removal(self, lane: int, *, park: bool) -> None:
         """Execute a Quarantine (``park=True``) or Evict at a safe point:
@@ -1062,7 +929,6 @@ class StreamingJob:
                 # a compile or runtime error of the device work propagates
                 self._inflight = None
                 self._hidden_since = None
-            self._discard_staged()
             backend = self.exchange_backend
             kind = "evict"
             if self.num_workers > 1 and loss.lane in self._lane_ids:
@@ -1150,32 +1016,25 @@ class StreamingJob:
         partitioner's tables or from a :class:`MigrateRoute` — and read its
         control outputs."""
         migrate, lane_cap = self._migrate_step(plan_rows)
-        if self._overlap_active():
-            # split migrate: the count phase (and every control output the
-            # metrics need) blocks below; the row ship + merge stays in
-            # flight across the safe point and drains under the next
-            # batch's host work — bit-identical to the fused step, which
-            # is the two phases traced back to back
-            (pending, kk, vv, moved, total, mig_ov, mig_lane_ov, mig_shipped,
-             mig_by) = migrate.start(where, self._sk, self._sv)
-            # interim state = kept rows only; the pending merge adds the
-            # received rows (external readers drain first, so they never
-            # observe the interim)
-            self._sk, self._sv = kk, vv
-            self._hidden_since = time.perf_counter()
+        # the count phase (and every control output the metrics need)
+        # blocks below; the row ship + merge stays in flight across the
+        # safe point and drains under the next batch's host work
+        (pending, kk, vv, moved, total, mig_ov, mig_lane_ov, mig_shipped,
+         mig_by) = migrate.start(where, self._sk, self._sv)
+        # interim state = kept rows only; the pending merge adds the
+        # received rows (external readers drain first, so they never
+        # observe the interim)
+        self._sk, self._sv = kk, vv
+        self._hidden_since = time.perf_counter()
 
-            def _fin_migrate(fin=migrate.finish, pending=pending):
-                rk, rv, rva = fin(pending)
-                self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
+        def _fin_migrate(fin=migrate.finish, pending=pending):
+            rk, rv, rva = fin(pending)
+            self._sk, self._sv = self._merge(self._sk, self._sv, rk, rv, rva)
 
-            self._inflight = _fin_migrate
-        else:
-            (kk, vv, rk, rv, rva, moved, total, mig_ov, mig_lane_ov, mig_shipped,
-             mig_by) = migrate(where, self._sk, self._sv)
-            self._sk, self._sv = self._merge(kk, vv, rk, rv, rva)
+        self._inflight = _fin_migrate
         # every control output below left the migrate start phase;
         # fetching them at this safe point blocks on work already forced
-        # (the ship itself stays in flight on the overlap path)
+        # (the ship itself stays in flight)
         with safe_point():
             moved_i = int(host_fetch(moved))
             total_i = int(host_fetch(total))
@@ -1202,19 +1061,7 @@ class StreamingJob:
 
     # ------------------------------------------------------------------
     def run(self, batches: Iterable[np.ndarray]) -> list[BatchMetrics]:
-        # depth-2 needs one batch of lookahead: park batch N+1 where
-        # process_batch can stage its start behind batch N's ship.  The
-        # check re-runs per batch so a mid-stream env/config flip degrades
-        # to depth 1 instead of staging work nobody will claim.
-        out: list[BatchMetrics] = []
-        seq = list(batches)
-        for i, b in enumerate(seq):
-            self._next_batch = (seq[i + 1]
-                                if self._depth2_active() and i + 1 < len(seq)
-                                else None)
-            out.append(self.process_batch(b))
-        self._next_batch = None
-        return out
+        return [self.process_batch(b) for b in batches]
 
     # -- state inspection ----------------------------------------------
     def state_count(self, key: int) -> float:
@@ -1234,11 +1081,9 @@ class StreamingJob:
 
     def restore(self, snap: dict, *, _keep_recovery_log: bool = False) -> None:
         # any in-flight finish belongs to the state being replaced: discard,
-        # along with any staged lookahead start (its route used the replaced
-        # partitioner) and the least-load vector (measured pre-restore)
+        # along with the least-load vector (measured pre-restore)
         self._inflight = None
         self._hidden_since = None
-        self._staged = None
         self._part_loads = None
         drm_snap = {k[4:]: v for k, v in snap.items() if k.startswith("drm_")}
         self.drm = DRMaster.restore(drm_snap, self.drm.config)

@@ -17,14 +17,13 @@ fire at the *host* boundary: the driver-level step wrappers in
 exchange start, and the wrapper's :meth:`FaultyBackend.inject` consults the
 plan for that tick.  Because the traced verbs delegate to the inner
 backend verbatim, an installed-but-never-firing plan is bit-identical to
-no plan at all — serial, depth-1 and depth-2 drivers alike — by
-construction, not by luck.
+no plan at all by construction, not by luck.
 
 **Determinism.**  Ticks count host-issued exchange starts (one per shuffle
-or migrate start; the serial fused step counts as its start).  For a fixed
-config and input stream the start sequence is a pure function of the
-decision trajectory, so the same plan (same seed) reproduces the same
-faults at the same points — the chaos tests' seed-determinism contract.
+or migrate start).  For a fixed config and input stream the start sequence
+is a pure function of the decision trajectory, so the same plan (same
+seed) reproduces the same faults at the same points — the chaos tests'
+seed-determinism contract.
 
 **Lane identity.**  Plan lanes are *original* lane ids (the mesh positions
 at job construction).  Quarantine/evict/recover renumber the live lanes;

@@ -361,9 +361,8 @@ class Exchange:
         slot: jax.Array | None = None,
         counts: jax.Array | None = None,
     ) -> ExchangeResult:
-        # the fused call IS the split-phase pipeline run back to back —
-        # bit-identity between the serial and overlapped drivers holds by
-        # construction, not by parallel implementations
+        # the fused call IS the two phases run back to back: one
+        # implementation, whether a caller holds the finish in flight or not
         return self.finish(self.start(lane, valid, payloads, slot=slot, counts=counts))
 
 

@@ -25,7 +25,6 @@ import dataclasses
 
 import numpy as np
 
-from repro.compat import overlap_enabled
 from repro.control import Repartition, Resize, SwitchBackend, Telemetry
 from repro.core.drm import DRConfig, DRMaster
 from repro.core.hashing import DEFAULT_NUM_HOSTS
@@ -120,19 +119,17 @@ class DRScheduler:
             # and holds dense; real lane accounting would need bufferized
             # KV migration (ROADMAP open item).
             pass
-        overlapped = self.overlap_active()
         if moved_sessions:
             # session (KV-cache) moves are this consumer's exchange traffic;
-            # modeled 1 row per session, unpadded.  Under effective overlap
-            # the move wall counts as hidden behind decision work (the
-            # streaming driver's attribution); serial — env kill switch or
-            # config — books nothing as hidden.
+            # modeled 1 row per session, unpadded.  The move wall counts as
+            # hidden behind decision work (the streaming driver's
+            # attribution).
             self.telemetry.record_exchange(ExchangeStats(
                 rows=moved_sessions,
                 padded_rows=moved_sessions,
                 occupied_rows=moved_sessions,
                 backend=self.drm.exchange_backend.name,
-                count_wall_s=0.0 if overlapped else None,
+                count_wall_s=0.0,
             ))
         return {
             # a backend switch moves no sessions: taken, but not a repartition
@@ -143,22 +140,7 @@ class DRScheduler:
             "moved_sessions": moved_sessions,
             "reason": action.reason,
             "backend": self.drm.exchange_backend.name,
-            # effective overlap at this decision point: the env kill switch
-            # (REPRO_DISABLE_OVERLAP) wins over DRConfig.overlap_exchange
-            "overlapped": overlapped,
         }
-
-    def overlap_active(self) -> bool:
-        """Whether this scheduler treats exchange traffic as overlapped.
-
-        Same precedence as the streaming driver: ``REPRO_DISABLE_OVERLAP=1``
-        wins over ``DRConfig.overlap_exchange`` (and over any configured
-        ``pipeline_depth``) — the env kill switch means serial everywhere,
-        not just in jobs that happen to own a device pipeline.  Session
-        moves here are modeled, so the flag only steers how their exchange
-        records are attributed (and lets operators confirm the kill switch
-        reached every consumer via the checkpoint schema)."""
-        return self.drm.config.overlap_exchange and overlap_enabled()
 
     def imbalance(self) -> float:
         loads = np.array([r.queued_tokens for r in self.replicas])

@@ -627,7 +627,7 @@ def test_scheduler_checkpoint_uniform_schema():
                                        shrink_trigger=1.02, resize_patience=1,
                                        imbalance_trigger=1e9))
     keys = ["repartitioned", "resized", "num_replicas", "imbalance",
-            "moved_sessions", "reason", "backend", "overlapped"]
+            "moved_sessions", "reason", "backend"]
     results = []
     for _ in range(2):
         window = []
@@ -712,27 +712,3 @@ def test_batchmetrics_carries_action_kind():
     job.resize(8)
     m2 = job.process_batch(rng.integers(0, 500, 1024))
     assert m2.action == "resize" and m2.resized
-
-
-def test_scheduler_env_kill_switch_beats_overlap_config(monkeypatch):
-    """REPRO_DISABLE_OVERLAP wins over DRConfig.overlap_exchange in the
-    serving scheduler too: the checkpoint schema reports the *effective*
-    overlap so operators can confirm the kill switch reached every
-    consumer, not just the streaming driver."""
-    monkeypatch.delenv("REPRO_DISABLE_OVERLAP", raising=False)
-    sched = DRScheduler(4, dr=DRConfig(lam=4.0, imbalance_trigger=1.25,
-                                       overlap_exchange=True,
-                                       pipeline_depth=2))
-    assert sched.overlap_active()
-    rng = np.random.default_rng(0)
-    r = sched.checkpoint(rng.integers(0, 100, 64))
-    assert r["overlapped"] is True
-    monkeypatch.setenv("REPRO_DISABLE_OVERLAP", "1")
-    assert not sched.overlap_active()  # env wins, no reconstruction needed
-    r = sched.checkpoint(rng.integers(0, 100, 64))
-    assert r["overlapped"] is False
-
-
-def test_scheduler_rejects_invalid_pipeline_depth():
-    with pytest.raises(ValueError, match="pipeline_depth"):
-        DRScheduler(4, dr=DRConfig(pipeline_depth=4))

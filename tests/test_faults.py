@@ -108,19 +108,6 @@ def test_never_firing_plan_is_bit_identical():
     assert seamed.exchange_backend.retries == 0
 
 
-@pytest.mark.parametrize("depth", [1, 2])
-def test_never_firing_identity_pipelined(depth):
-    batches = _batches()
-    cfg = DRConfig(pipeline_depth=depth)
-    ref = StreamingJob(dr=cfg)
-    ms_ref = ref.run(batches)
-    seamed = StreamingJob(dr=cfg,
-                          exchange_backend=FaultyBackend("dense", FaultPlan()))
-    ms_seam = seamed.run(batches)
-    assert _trajectory(ms_ref) == _trajectory(ms_seam)
-    assert _counts(ref) == _counts(seamed)
-
-
 def test_transient_faults_retry_to_zero_loss():
     batches = _batches()
     ref = StreamingJob(dr=DRConfig(imbalance_trigger=1e9))

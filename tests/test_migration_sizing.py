@@ -7,10 +7,11 @@ host devices, across KIP re-plans from Zipf histograms and one resize
 4 -> 8, each migration must size its lanes exactly as the host plan over
 the same state would (``migration_capacity(plan_migration(...))``), report
 that plan's peak worker-to-worker transfer, drop no row, and leave the
-table bit for bit as the fused migrate step (route and ship in one
+table bit for bit as the migrate step called whole (routed in its start
 program, full lanes) leaves it: each worker holds exactly the live keys the
 new partitioner homes on it, summed, sorted and packed (the layout
-``merge_into`` writes).  The serial and overlapped drivers agree.
+``merge_into`` writes).  The counts after the last batch equal
+``np.bincount`` over every event fed.
 
 Runs in a subprocess because the device count must be fixed before jax
 starts (the main test process keeps the default 1 CPU device).
@@ -52,13 +53,11 @@ SCRIPT = textwrap.dedent(
             out_k[w, :len(u)], out_v[w, :len(u)] = u, s
         return out_k, out_v
 
-    def run(overlap):
+    def run():
         job = StreamingJob(
             mesh=jax.make_mesh((W,), ("data",)), num_partitions=4, state_capacity=STATE,
-            dr=DRConfig(imbalance_trigger=1.05, migration_cost_weight=0.0,
-                        overlap_exchange=overlap),
+            dr=DRConfig(imbalance_trigger=1.05, migration_cost_weight=0.0),
         )
-        assert job._overlap_active() == overlap
         prev, expect = [job.drm.partitioner], []
         migrate_state = job._migrate_state
 
@@ -70,12 +69,12 @@ SCRIPT = textwrap.dedent(
             plan = plan_migration(prev[0], new, sk[sk != KEY_SENTINEL].astype(np.int64))
             folded = fold_to_workers(plan.transfer, W)
             np.fill_diagonal(folded, 0.0)
-            fused, _ = job._migrate_step(STATE)  # the tables in, not a route
-            kk, vv, rk, rv, rva = fused(new.tables(), job._sk, job._sv)[:5]
-            fused_k, fused_v = job._merge(kk, vv, rk, rv, rva)
+            whole, _ = job._migrate_step(STATE)  # the tables in, not a route
+            kk, vv, rk, rv, rva = whole(new.tables(), job._sk, job._sv)[:5]
+            whole_k, whole_v = job._merge(kk, vv, rk, rv, rva)
             expect.append((migration_capacity(plan, num_workers=W), int(folded.max()),
                            homed_tables(sk, sv, new),
-                           (np.asarray(fused_k), np.asarray(fused_v))))
+                           (np.asarray(whole_k), np.asarray(whole_v))))
             return migrate_state(**kw)
 
         job._migrate_state = spy
@@ -94,14 +93,14 @@ SCRIPT = textwrap.dedent(
             assert m.fetch_bytes < STATE * 4, m.fetch_bytes
             if not m.repartitioned:
                 continue
-            cap, peak, (want_k, want_v), (fused_k, fused_v) = expect.pop(0)
+            cap, peak, (want_k, want_v), (whole_k, whole_v) = expect.pop(0)
             assert m.migration_plan_rows == cap, (i, m.migration_plan_rows, cap)
             assert m.migration_peak_rows == peak, (i, m.migration_peak_rows, peak)
             got_k, got_v = np.asarray(job.state_keys), np.asarray(job.state_vals)
             assert np.array_equal(got_k, want_k), i
             assert np.array_equal(got_v.view(np.int32), want_v.view(np.int32)), i
-            assert np.array_equal(got_k, fused_k), i
-            assert np.array_equal(got_v.view(np.int32), fused_v.view(np.int32)), i
+            assert np.array_equal(got_k, whole_k), i
+            assert np.array_equal(got_v.view(np.int32), whole_v.view(np.int32)), i
         assert not expect
         assert ms[3].resized and ms[3].num_partitions == 8, ms[3]
         assert sum(m.repartitioned for m in ms) >= 4, [m.reason for m in ms]
@@ -115,17 +114,13 @@ SCRIPT = textwrap.dedent(
             assert job._migrate_route is None
         # exact counts over everything fed
         sk, sv = np.asarray(job.state_keys), np.asarray(job.state_vals)
-        keys, counts = np.unique(np.concatenate(batches), return_counts=True)
+        want = np.bincount(np.concatenate(batches))
         live = sk != KEY_SENTINEL
-        got = dict(zip(sk[live].tolist(), sv[live][:, 0].tolist()))
-        assert got == dict(zip(keys.tolist(), counts.astype(float).tolist()))
-        return ms, sk, sv
+        got = np.zeros(len(want))
+        np.add.at(got, sk[live], sv[live][:, 0])
+        assert np.array_equal(got, want.astype(float))
 
-    ms_o, sk_o, sv_o = run(overlap=True)
-    ms_s, sk_s, sv_s = run(overlap=False)
-    assert [(m.migration_plan_rows, m.migration_peak_rows, m.migration_rows) for m in ms_o] \\
-        == [(m.migration_plan_rows, m.migration_peak_rows, m.migration_rows) for m in ms_s]
-    assert np.array_equal(sk_o, sk_s) and np.array_equal(sv_o, sv_s)
+    run()
     print("MIGRATION-SIZING-OK")
     """
 )
