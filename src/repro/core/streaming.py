@@ -24,9 +24,11 @@ migration routes the state on the device first; the host reads only the
 ``[W, W]`` counts of rows each worker sends to each other worker and sizes
 the lanes to the peak x slack, so the all-to-all ships that instead of
 ``W * state_capacity`` rows.  Lane capacities are rounded up to powers of
-two, and across workers to at least a sixteenth of the table, so repeated
-repartitions reuse a handful of jitted migrate steps instead of recompiling
-per size.
+two, and to at least a sixteenth of the table, so repeated repartitions
+reuse a handful of jitted migrate steps instead of recompiling per size.
+On one worker every row's destination is the worker it sits on, so a
+migration is the identity and none runs: a repartition there swaps the
+partitioner, which the next batch's shuffle routes under.
 
 **Elastic resize** is the same mechanism one level up: changing the *number*
 of partitions (the job's logical worker count) instead of their contents.
@@ -93,9 +95,10 @@ batch and carries its index; its children cover it with no holes:
 * ``stream.drain`` — complete the in-flight finish + merge, wherever that
   happens;
 * ``dr.migrate`` — a migration, split into ``dr.migrate.fetch`` (enqueue
-  the route and fetch its ``[W, W]`` counts; empty on one worker and for
-  full lanes), ``dr.migrate.plan`` (the lane size) and
-  ``dr.migrate.start`` (the step, its enqueue, the control fetches);
+  the route and fetch its ``[W, W]`` counts; empty for full lanes),
+  ``dr.migrate.plan`` (the lane size) and ``dr.migrate.start`` (the step,
+  its enqueue, the control fetches); on one worker, where no migration
+  runs, it holds none of them;
 * ``stream.account`` — the rest: migration telemetry and ``BatchMetrics``.
 
 ``dr.resize``, ``dr.switch``, ``dr.lane`` (quarantine, evict) and
@@ -265,17 +268,16 @@ class _Migration(NamedTuple):
     by_class: np.ndarray | None = None  # shipped rows by distance class, summed
 
 
-def migrate_lane_capacity(plan_rows: int, state_capacity: int, num_workers: int) -> int:
+def migrate_lane_capacity(plan_rows: int, state_capacity: int) -> int:
     """Rows a migration lane holds for a migration that needs ``plan_rows``.
 
     The next power of two, capped at the full state table, so the jit cache
-    stays small across repartitions.  Across workers a lane holds at least a
-    sixteenth of the table: the plans' sizes wander with the key drift, each
-    size is programs of its own that take seconds to compile, and every plan
-    below the floor shares one.  On one worker no row ships, so its lanes
-    stay 8.
+    stays small across repartitions.  A lane holds at least a sixteenth of
+    the table: the plans' sizes wander with the key drift, each size is
+    programs of its own that take seconds to compile, and every plan below
+    the floor shares one.
     """
-    cap = 8 if num_workers == 1 else max(8, state_capacity // 16)
+    cap = max(8, state_capacity // 16)
     while cap < min(plan_rows, state_capacity):
         cap *= 2
     return min(cap, state_capacity)
@@ -473,7 +475,7 @@ class StreamingJob:
         granularity, so the same cache serves plain repartitions *and*
         cross-size resize migrations.
         """
-        cap = migrate_lane_capacity(lane_capacity, self.state_capacity, self.num_workers)
+        cap = migrate_lane_capacity(lane_capacity, self.state_capacity)
         if cap not in self._migrate_steps:
             self._migrate_steps[cap] = make_migrate_step(
                 self.mesh,
@@ -680,7 +682,9 @@ class StreamingJob:
         # execute the action (state only moves here, at the safe point).
         # Any taken action drains the in-flight finish first: a migration
         # must see this batch's rows merged, and a backend switch rebuilds
-        # the steps the in-flight finish came from.
+        # the steps the in-flight finish came from.  On one worker a
+        # repartition, a resize or an unsplit is a table swap: the DRM has
+        # installed the new partitioner and no state moves.
         if action.taken:
             self._drain_inflight()
         mig = _Migration()
@@ -986,19 +990,25 @@ class StreamingJob:
         from the route, and the received rows fold back into the local
         state tables.  The counts are taken where the rows are, so they are
         what the all-to-all ships and no lane can overflow — cross-size
-        (resize) migrations included.  Where no count is needed the step
-        routes in its own start program and the host fetches nothing: one
-        worker has no pair to count (lanes of 8 rows), and ``full_lanes``
-        (and any installed split key) provisions the whole table, because
+        (resize) migrations included.  ``full_lanes`` (and any installed
+        split key) needs no count: it provisions the whole table, because
         split partial aggregates live *off home* and the home-routed
-        migrate step ships every one of them back to its key's home.
+        migrate step ships every one of them back to its key's home; the
+        step routes in its own start program and the host fetches nothing.
+
+        On one worker a migration is the identity — every row's destination
+        is the worker it sits on, a split key's replicas included, which
+        the merge has already summed into one row — so it enqueues nothing
+        and returns an empty :class:`_Migration`.
         """
-        full = full_lanes or bool(self.drm.split_keys)
-        where = self.drm.partitioner.tables()
-        peak = 0
         with _span("dr.migrate"):
+            if self.num_workers == 1:
+                return _Migration()
+            full = full_lanes or bool(self.drm.split_keys)
+            where = self.drm.partitioner.tables()
+            peak = 0
             with _span("dr.migrate.fetch"):
-                if self.num_workers > 1 and not full:
+                if not full:
                     if self._migrate_route is None:
                         self._migrate_route = make_migrate_route(
                             self.mesh, num_hosts=self.drm.partitioner.num_hosts,

@@ -10,18 +10,19 @@ import pytest
 
 from repro.core.drm import DRConfig, DRMaster
 from repro.core.partitioner import uniform_partitioner
-from repro.core.streaming import StreamingJob
+from repro.core.streaming import StreamingJob, migrate_lane_capacity
 from repro.data.generators import zipf_keys
 from repro.exchange import ExchangeSpec
 from repro.serve.scheduler import DRScheduler
 
 
-def _pow2_lanes(plan_rows: int, state_capacity: int) -> int:
-    """The lane capacity StreamingJob actually jits for a planned size."""
-    cap = 8
-    while cap < min(plan_rows, state_capacity):
-        cap *= 2
-    return min(cap, state_capacity)
+def _migration_rows(job: StreamingJob, plan_rows: int) -> int:
+    """Rows a resize's migration provisions: none on one worker, where a
+    resize swaps the partitioner and ships nothing; else a lane of the
+    capacity the job jits for the plan from every worker."""
+    if job.num_workers == 1:
+        return 0
+    return job.num_workers * migrate_lane_capacity(plan_rows, job.state_capacity)
 
 
 def _assert_counts_exact(job: StreamingJob, batches) -> None:
@@ -101,7 +102,8 @@ def test_note_resize_counts_as_safe_point_decision():
 def test_grow_and_shrink_preserve_counts_with_bounded_rows():
     """Skewed keys grow 4->8 at a checkpoint tick; counts stay bit-exact
     across grow and the shrink back; shipped rows are bounded by the
-    cross-size plan's migration_capacity (pow2-rounded lanes)."""
+    cross-size plan's migration_capacity (pow2-rounded lanes), and are none
+    on one worker."""
     job = StreamingJob(
         num_partitions=4,
         state_capacity=8192,
@@ -119,7 +121,7 @@ def test_grow_and_shrink_preserve_counts_with_bounded_rows():
     assert (g.batch + 1) % 2 == 0  # fired exactly at a checkpoint tick
     # lanes sized from the cross-size plan, nothing dropped
     assert g.overflow == 0
-    assert g.migration_rows == job.num_workers * _pow2_lanes(g.migration_plan_rows, 8192)
+    assert g.migration_rows == _migration_rows(job, g.migration_plan_rows)
     assert job.num_partitions == 8
     _assert_counts_exact(job, batches)
 
@@ -130,7 +132,7 @@ def test_grow_and_shrink_preserve_counts_with_bounded_rows():
     s = [m for m in ms2 if m.resized][0]
     assert s.reason == "resize 8->4" and job.num_partitions == 4
     assert s.overflow == 0
-    assert s.migration_rows == job.num_workers * _pow2_lanes(s.migration_plan_rows, 8192)
+    assert s.migration_rows == _migration_rows(job, s.migration_plan_rows)
     _assert_counts_exact(job, batches + more)
 
 

@@ -9,7 +9,7 @@ from repro.core import Histogram, kip_update, uniform_partitioner
 from repro.core.drm import DRConfig, DRMaster
 from repro.core.hashing import KEY_SENTINEL
 from repro.core.replay import BatchJob
-from repro.core.shuffle import make_shuffle_step
+from repro.core.shuffle import make_migrate_step, make_shuffle_step
 from repro.core.state import empty_state, merge_into
 from repro.core.streaming import StreamingJob, migrate_lane_capacity
 from repro.data.generators import drifting_zipf, zipf_keys
@@ -210,6 +210,47 @@ def test_dr_idle_on_uniform_stream():
     assert not any(m.repartitioned for m in ms)
 
 
+@pytest.mark.parametrize("resize", [False, True], ids=["repartition", "resize-4to8"])
+def test_one_worker_migration_is_a_table_swap(resize):
+    """On one worker a repartition (and a resize 4 -> 8) swaps the
+    partitioner and runs no migration: no migrate step or route is built,
+    nothing ships or overflows, and the migrate step called whole on each
+    repartitioned batch's state under the new tables would have kept every
+    row bit for bit and received none.  The counts stay exact."""
+    state = 2048
+    job = StreamingJob(mesh=_mesh1(), num_partitions=4, state_capacity=state,
+                       dr=DRConfig(imbalance_trigger=1.05, migration_cost_weight=0.0))
+    whole = make_migrate_step(job.mesh, state_capacity=state,
+                              num_hosts=job.drm.partitioner.num_hosts, seed=job.seed)
+    batches = list(drifting_zipf(7, 2048, num_keys=600, exponent=1.3,
+                                 drift_every=2, seed=1))
+    ms = []
+    for i, b in enumerate(batches):
+        if resize and i == 3:
+            job.resize(8)
+        m = job.process_batch(b)
+        ms.append(m)
+        if not m.repartitioned:
+            continue
+        assert (m.migration_rows, m.relative_migration, m.overflow) == (0, 0.0, 0), m
+        sk, sv = np.asarray(job.state_keys), np.asarray(job.state_vals)
+        kk, kv, _, _, rva = whole(job.drm.partitioner.tables(), job.state_keys,
+                                  job.state_vals)[:5]
+        assert np.array_equal(np.asarray(kk), sk), i
+        assert np.array_equal(np.asarray(kv).view(np.int32), sv.view(np.int32)), i
+        assert not np.asarray(rva).any(), i
+    assert sum(m.repartitioned for m in ms) >= 4, [m.reason for m in ms]
+    if resize:
+        assert ms[3].resized and ms[3].num_partitions == 8, ms[3]
+    assert job._migrate_steps == {} and job._migrate_route is None
+    # exact counts over everything fed
+    fed, inv = np.unique(np.concatenate(batches), return_inverse=True)
+    sk, sv = np.asarray(job.state_keys)[0], np.asarray(job.state_vals)[0]
+    live = sk != KEY_SENTINEL
+    assert np.array_equal(sk[live], fed)
+    assert np.array_equal(sv[live][:, 0], np.bincount(inv).astype(np.float32))
+
+
 def test_checkpoint_restore_resumes():
     job = StreamingJob(num_partitions=4, state_capacity=4096,
                        dr=DRConfig(imbalance_trigger=1.05, migration_cost_weight=0.0))
@@ -323,13 +364,15 @@ def test_restore_legacy_snapshot_minimal():
     assert job.drm.lane_health is None
 
 
-@pytest.mark.parametrize("plan_rows, workers, want", [
-    # four workers, a 2^20-row table: every plan up to a sixteenth of the
-    # table shares one lane size, larger plans round up to powers of two
-    (1, 4, 1 << 16), (4096, 4, 1 << 16), (40_000, 4, 1 << 16),
-    (1 << 16, 4, 1 << 16), ((1 << 16) + 1, 4, 1 << 17), (10**9, 4, 1 << 20),
-    # one worker ships nothing: lanes stay as small as the plan allows
-    (8, 1, 8), (100, 1, 128), (10**9, 1, 1 << 20),
+@pytest.mark.parametrize("plan_rows, state_capacity, want", [
+    # a 2^20-row table: every plan up to a sixteenth of the table shares
+    # one lane size, larger plans round up to powers of two
+    (1, 1 << 20, 1 << 16), (4096, 1 << 20, 1 << 16), (40_000, 1 << 20, 1 << 16),
+    (1 << 16, 1 << 20, 1 << 16), ((1 << 16) + 1, 1 << 20, 1 << 17),
+    (10**9, 1 << 20, 1 << 20),
+    # small tables: the floor never drops below 8 rows, and a lane never
+    # outgrows the table
+    (1, 64, 8), (100, 1024, 128), (10**9, 4096, 4096),
 ])
-def test_migrate_lane_capacity_floors_cross_worker_lanes(plan_rows, workers, want):
-    assert migrate_lane_capacity(plan_rows, 1 << 20, workers) == want
+def test_migrate_lane_capacity_floors_cross_worker_lanes(plan_rows, state_capacity, want):
+    assert migrate_lane_capacity(plan_rows, state_capacity) == want

@@ -24,8 +24,9 @@ from repro.exchange.spec import DISTANCE_CLASSES
 
 BENCH = Path(__file__).resolve().parents[1] / "chipbench"
 STATE, PARTS, EVENTS = 1 << 12, 8, 1 << 10  # chipbench/tests/test_run.py's sizes
-#: spans a one-worker job cannot write: lane removal needs two workers
-NEEDS_TWO_WORKERS = {"dr.lane"}
+#: spans a one-worker job cannot write: lane removal needs two workers, and
+#: on one worker a migration is the identity, so ``dr.migrate`` holds no phase
+NEEDS_TWO_WORKERS = {"dr.lane", "dr.migrate.fetch", "dr.migrate.plan", "dr.migrate.start"}
 
 
 def _load(name: str):
